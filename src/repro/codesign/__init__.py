@@ -11,7 +11,8 @@ cost models. Now:
     registers: its mapping ``Problem``, ``Constraints``, a ``decode`` that
     reads the C1 temporal tile out of a Union mapping, a ``legalize``
     repair that turns any candidate into a valid BlockSpec, safe defaults,
-    and the shared :data:`DEFAULT_VMEM_BUDGET` convention.
+    and the shared :data:`DEFAULT_VMEM_BUDGET`, counted the way the TPU
+    compiler allocates VMEM (:func:`pipeline_vmem_bytes`).
   * :func:`plan` (``planner.py``) is the single search path all kernels
     tile through: it drives the existing ``union_opt`` /
     ``EvaluationEngine`` machinery and caches finished plans in a
@@ -29,9 +30,13 @@ cost models. Now:
 
 from repro.codesign.space import (  # noqa: F401
     DEFAULT_VMEM_BUDGET,
+    PLAN_OPERAND_BYTES,
+    SCOPED_VMEM_LIMIT,
     KernelSpace,
     all_spaces,
+    fit_tiles,
     get_space,
+    pipeline_vmem_bytes,
     register_space,
     repair_tile,
     round_up,

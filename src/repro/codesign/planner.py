@@ -46,7 +46,7 @@ log = logging.getLogger("repro.codesign")
 
 #: bump when decode/legalize/key semantics change: cached plans from older
 #: planner revisions are then keyed apart and re-searched, never misread.
-PLANNER_VERSION = 1
+PLANNER_VERSION = 2
 
 #: The EXPECTED ways a mapping search can fail: ``union_opt`` raises
 #: RuntimeError when the mapper finds no legal mapping within its budget

@@ -25,10 +25,9 @@ BlockConfig:
                                uses to benchmark the emitted kernel.
 
 The **VMEM budget convention** is unified here: every space defaults to
-:data:`DEFAULT_VMEM_BUDGET` (8 MiB -- half of the chip's 16 MiB usable
-VMEM, leaving room for double buffering), replacing the three divergent
-per-kernel conventions (flash_attention's inline ``8 MiB``, ssd_scan's
-``vmem_budget`` kwarg, matmul's implicit ``tpu_chip()`` default).
+:data:`DEFAULT_VMEM_BUDGET`, and every ``legalize`` holds the VMEM the
+TPU compiler actually allocates for the kernel
+(:func:`pipeline_vmem_bytes`) within it.
 
 ``BlockConfig`` is a plain ``Tuple[int, ...]`` in ``decode_dims`` order --
 it is stored in plan caches and calibration tables, so it stays a
@@ -47,13 +46,29 @@ from repro.core.problem import Problem
 
 BlockConfig = Tuple[int, ...]
 
-#: The one VMEM tile budget every kernel space plans under by default:
-#: half of the chip's 16 MiB usable VMEM, so a double-buffered pipeline
-#: (the Pallas default) fits two tiles. Kernel-specific overrides go
-#: through ``KernelSpace.vmem_budget`` or the ``vmem_budget=`` parameter
-#: of :func:`repro.codesign.planner.plan` -- never through inline
-#: literals.
-DEFAULT_VMEM_BUDGET = 8 * (1 << 20)
+#: The TPU v5e compiler's default scoped VMEM limit for one kernel: a
+#: pallas_call whose pipeline allocates more is refused at compile time.
+SCOPED_VMEM_LIMIT = 16 * (1 << 20)
+
+#: The one VMEM budget every kernel space plans under by default, counted
+#: the way the compiler allocates (:func:`pipeline_vmem_bytes`, bf16
+#: operands). It is half of :data:`SCOPED_VMEM_LIMIT`: the same tiles with
+#: float32 operands at most double the footprint and still compile, and
+#: the compiler's own internal scratch (score blocks, temporaries) has
+#: room. Kernel-specific overrides go through ``KernelSpace.vmem_budget``
+#: or the ``vmem_budget=`` parameter of :func:`repro.codesign.planner.plan`
+#: -- never through inline literals.
+DEFAULT_VMEM_BUDGET = SCOPED_VMEM_LIMIT // 2
+
+#: Bytes per operand element the planner counts: the models run in bf16.
+PLAN_OPERAND_BYTES = 2
+
+
+def pipeline_vmem_bytes(blocks: Sequence[int], scratch: Sequence[int] = ()) -> int:
+    """VMEM the TPU compiler allocates for one pallas_call's pipeline:
+    every in and out block twice (the grid double-buffers them) plus each
+    scratch buffer once. Both arguments are byte counts."""
+    return 2 * sum(int(b) for b in blocks) + sum(int(s) for s in scratch)
 
 
 def round_up(x: int, m: int) -> int:
@@ -81,6 +96,36 @@ def repair_tile(
     while d > 1 and dim % d != 0:
         d //= 2
     return max(int(d), 1)
+
+
+def fit_tiles(
+    tiles: Sequence[int],
+    dims: Sequence[int],
+    footprint,
+    budget: int,
+    *,
+    min_tile: int = 128,
+) -> BlockConfig:
+    """Shrink divisor ``tiles`` of ``dims`` until ``footprint(tiles)``
+    (bytes) fits ``budget``. Each step moves the largest tile (the later
+    one on ties) to the next smaller ``min_tile``-multiple divisor of its
+    dim, so the result stays legal and MXU-aligned. Raises ValueError when
+    no tile can shrink further and the footprint still does not fit."""
+    tiles = [int(t) for t in tiles]
+    while footprint(tuple(tiles)) > budget:
+        pick = None
+        for i, (t, d) in enumerate(zip(tiles, dims)):
+            smaller = [c for c in range(min_tile, t, min_tile) if d % c == 0]
+            if smaller and (pick is None or t >= tiles[pick[0]]):
+                pick = (i, smaller[-1])
+        if pick is None:
+            raise ValueError(
+                f"tiles {tuple(tiles)} of {tuple(dims)} need "
+                f"{footprint(tuple(tiles))} bytes of VMEM, over the "
+                f"{budget}-byte budget, and cannot shrink"
+            )
+        tiles[pick[0]] = pick[1]
+    return tuple(tiles)
 
 
 class KernelSpace:

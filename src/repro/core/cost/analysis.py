@@ -72,6 +72,42 @@ def _record_trace(program_key, padded_batch: int) -> None:
     _TRACE_COMBOS.add((program_key, int(padded_batch)))
 
 
+class InjectedJaxFault(RuntimeError):
+    """The device failure ``UNION_FAULT_JAX`` injects (see
+    :meth:`AnalysisContext._ensure_jax`)."""
+
+
+def device_faults() -> Tuple[type, ...]:
+    """The failures a jax engine survives by degrading to numpy through
+    its circuit breaker: an injected fault, or a runtime error raised by
+    the device (XLA reports a compile failure as one too). Anything else
+    -- an import, attribute, type or trace error, or float32 output where
+    float64 is required -- is a defect and propagates out of the search."""
+    import jax
+
+    return (InjectedJaxFault, jax.errors.JaxRuntimeError)
+
+
+def x64():
+    """Context manager that runs the fused cores in float64 (bit-identity
+    with the scalar path needs it)."""
+    import jax
+
+    return jax.enable_x64(True)
+
+
+def require_float64(arr: np.ndarray) -> np.ndarray:
+    """Host copy of a fused core's output, checked to be float64: float32
+    output cannot honour the bit-identity contract, so it raises."""
+    arr = np.asarray(arr)
+    if arr.dtype != np.float64:
+        raise RuntimeError(
+            f"fused core returned {arr.dtype}, not float64: x64 is off, so "
+            "the jax engine cannot match the scalar path bit for bit"
+        )
+    return arr
+
+
 def reset_trace_registry() -> None:
     """Drop trace accounting AND the shared generic-program cache (test
     isolation helper; compiled programs are rebuilt on demand)."""
@@ -848,15 +884,15 @@ class AnalysisContext:
     def _ensure_jax(self):
         """Import JAX lazily; memoized on the context.
 
-        ``UNION_FAULT_JAX=1`` simulates a broken jax install (import/trace
-        failure) at the exact point every jax path funnels through: the
-        raise is caught by the callers' degradation handling, which sets
+        ``UNION_FAULT_JAX=1`` simulates a device failure at the exact point
+        every jax path funnels through: the raise is caught by the
+        callers' degradation handling (:func:`device_faults`), which sets
         ``_jax_failed`` and falls back to numpy -- the path the sweep
         executor's ``jaxfail`` fault spec and the CI fault-injection tests
-        exercise without needing a genuinely broken toolchain.
+        exercise without needing a failing device.
         """
         if os.environ.get("UNION_FAULT_JAX"):
-            raise RuntimeError("injected jax backend failure (UNION_FAULT_JAX)")
+            raise InjectedJaxFault("injected jax backend failure (UNION_FAULT_JAX)")
         if self._jax is None:
             import jax
 
@@ -865,7 +901,7 @@ class AnalysisContext:
 
     def _jax_device_arrays(self, sb: StackedBatch):
         """Upload a StackedBatch's matrices to the device once (int64; the
-        caller holds ``enable_x64``) and memoize them on the handle, so the
+        caller holds :func:`x64`) and memoize them on the handle, so the
         admission and scoring programs share one transfer."""
         if sb.dev is None:
             jax = self._ensure_jax()
@@ -923,9 +959,9 @@ class AnalysisContext:
         """JAX-jitted batch core over a (device-resident) StackedBatch:
         optionally gathers the ``select`` row subset ON DEVICE, pads the
         batch to a power of two (bounding retraces), runs in float64 under
-        ``enable_x64``, returns numpy arrays of the unpadded (selected)
-        batch -- or None so the caller falls back to numpy (missing jax,
-        trace failure, restricted platform)."""
+        :func:`x64`, returns numpy arrays of the unpadded (selected)
+        batch -- or None after a device fault (:func:`device_faults`), so
+        the caller falls back to numpy."""
         if self._jax_failed:
             return None
         try:
@@ -944,9 +980,7 @@ class AnalysisContext:
                 self._jax_batch_core = jax.jit(
                     self._make_batch_core(jnp, lax), donate_argnums=donate
                 )
-            from jax.experimental import enable_x64
-
-            with enable_x64():
+            with x64():
                 tt, st, perm = self._jax_device_arrays(sb)
                 if select is not None:
                     sel = jnp.asarray(np.asarray(select, dtype=np.int64))
@@ -958,17 +992,13 @@ class AnalysisContext:
             if self._jax_core_donates and select is None:
                 sb.dev = None  # donated away; re-upload on next use
             out = jax.tree_util.tree_map(np.asarray, out)
-            if out[0].dtype != np.float64:
-                # x64 unavailable on this build: results are float32 and
-                # cannot honour the bit-identity contract
-                self._jax_failed = True
-                return None
-            if out[0].shape[0] != B:
-                out = _tree_slice(out, B)
-            return out
-        except Exception:
+        except device_faults():
             self._jax_failed = True
             return None
+        require_float64(out[0])
+        if out[0].shape[0] != B:
+            out = _tree_slice(out, B)
+        return out
 
     def signature_traffic_batch(
         self,
@@ -1329,7 +1359,8 @@ class AnalysisContext:
     def _run_jax_lb(self, sb: StackedBatch):
         """Jitted lower-bound core over a device-resident StackedBatch; the
         uploaded matrices stay on ``sb.dev`` for the scoring pass. Returns
-        numpy (cycles, energy, guard) or None (fallback to numpy)."""
+        numpy (cycles, energy, guard) or None after a device fault
+        (fallback to numpy)."""
         if self._jax_failed:
             return None
         try:
@@ -1340,22 +1371,17 @@ class AnalysisContext:
             if self._jax_lb_core is None:
                 # never donate here: the scoring pass reuses sb.dev
                 self._jax_lb_core = jax.jit(self._make_lb_core(jnp, lax))
-            from jax.experimental import enable_x64
-
-            with enable_x64():
+            with x64():
                 tt, st, perm = self._jax_device_arrays(sb)
                 tt, st, perm, B = self._pad_pow2(tt, st, perm, jnp)
                 self.jax_dispatches += 1
                 _record_trace(("ctx-lb", id(self)), int(tt.shape[0]))
                 cyc, en, mx = self._jax_lb_core(tt, st, perm)
-            cyc = np.asarray(cyc)
-            if cyc.dtype != np.float64:
-                self._jax_failed = True
-                return None
-            return cyc[:B], np.asarray(en)[:B], np.asarray(mx)
-        except Exception:
+            cyc, en, mx = np.asarray(cyc), np.asarray(en), np.asarray(mx)
+        except device_faults():
             self._jax_failed = True
             return None
+        return require_float64(cyc)[:B], en[:B], mx
 
     def lower_bound_batch(
         self,
@@ -1450,8 +1476,8 @@ class AnalysisContext:
         """Jitted single-dispatch admit+score runner for one (model,
         metric): ``run(sb, incumbent) -> (admit[B] bool, lb_guard float,
         latency[B], energy[B], util[B], score_guard float, extras)`` as
-        host numpy, or None (jax unavailable / x64 undeliverable / trace
-        failure -- the engine then keeps the two-stage flow). The stacked
+        host numpy, or None after a device fault (:func:`device_faults`;
+        the engine then keeps the two-stage flow). The stacked
         batch is uploaded once and padded to a power of two (padding
         repeats row 0, a real candidate, so neither guard can trip on
         padding); only [B]-sized result arrays cross back to host.
@@ -1469,31 +1495,26 @@ class AnalysisContext:
                 return cached
         try:
             jax = self._ensure_jax()
-            from jax import lax
-            import jax.numpy as jnp
-        except Exception:
+        except device_faults():
             self._jax_failed = True
             return None
-        try:
-            # Donation mirrors the traffic core: XLA may reuse the batch
-            # matrices' device memory on accelerator backends (unsupported
-            # on CPU); the incumbent scalar (arg 3) is never donated.
-            donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-            core = jax.jit(
-                self._make_fused_core(jnp, lax, lb_builder, terms, metric),
-                donate_argnums=donate,
-            )
-        except Exception:
-            self._jax_failed = True
-            return None
+        from jax import lax
+        import jax.numpy as jnp
+
+        # Donation mirrors the traffic core: XLA may reuse the batch
+        # matrices' device memory on accelerator backends (unsupported
+        # on CPU); the incumbent scalar (arg 3) is never donated.
+        donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
+        core = jax.jit(
+            self._make_fused_core(jnp, lax, lb_builder, terms, metric),
+            donate_argnums=donate,
+        )
 
         def run(sb: StackedBatch, incumbent: float):
             if self._jax_failed:
                 return None
             try:
-                from jax.experimental import enable_x64
-
-                with enable_x64():
+                with x64():
                     tt, st, perm, B = self._jax_device_padded(sb)
                     inc = jnp.asarray(float(incumbent), dtype=jnp.float64)
                     self.jax_dispatches += 1
@@ -1503,22 +1524,8 @@ class AnalysisContext:
                     out = core(tt, st, perm, inc)
                 if donate:
                     sb.devp = None  # donated away; fallbacks re-upload
-                admit, lb_mx, latency, energy, util, score_mx, extras = out
-                latency = np.asarray(latency)
-                if latency.dtype != np.float64:
-                    # x64 unavailable: cannot honour bit-identity
-                    self._jax_failed = True
-                    return None
-                return (
-                    np.asarray(admit)[:B],
-                    float(np.asarray(lb_mx)),
-                    latency[:B],
-                    np.asarray(energy)[:B],
-                    np.asarray(util)[:B],
-                    float(np.asarray(score_mx)),
-                    {k: np.asarray(v)[:B] for k, v in extras.items()},
-                )
-            except Exception:
+                return _fused_to_host(out, B)
+            except device_faults():
                 self._jax_failed = True
                 return None
 
@@ -1536,9 +1543,9 @@ class AnalysisContext:
         ``generic`` is ``CostModel.batch_cost_terms_generic`` output:
         ``(model_struct_key, model_params, terms)`` with
         ``terms(bt, xp, p)``. Returns a :class:`GenericFusedRunner`
-        (same call protocol as the per-context runner) or None (jax
-        unavailable / trace failure -- callers fall back exactly as for
-        the per-context builder). ``cache_key`` memoizes the runner on
+        (same call protocol as the per-context runner) or None after a
+        device fault (callers fall back exactly as for the per-context
+        builder). ``cache_key`` memoizes the runner on
         the context as the lookup tier ABOVE the global program cache.
         """
         if self._jax_failed:
@@ -1550,24 +1557,21 @@ class AnalysisContext:
         model_key, model_params, terms = generic
         try:
             jax = self._ensure_jax()
-            from jax import lax
-            import jax.numpy as jnp
-        except Exception:
+        except device_faults():
             self._jax_failed = True
             return None
+        from jax import lax
+        import jax.numpy as jnp
+
         skey = self.shape_class_key()
         pkey = ("generic-fused", skey, model_key, metric)
         entry = _GENERIC_PROGRAMS.get(pkey)
         if entry is None:
-            try:
-                donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-                core = jax.jit(
-                    _make_generic_fused_core(skey, terms, metric, jnp, lax),
-                    donate_argnums=donate,
-                )
-            except Exception:
-                self._jax_failed = True
-                return None
+            donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
+            core = jax.jit(
+                _make_generic_fused_core(skey, terms, metric, jnp, lax),
+                donate_argnums=donate,
+            )
             entry = (core, bool(donate))
             _GENERIC_PROGRAMS[pkey] = entry
         params = dict(self.shape_params())
@@ -2109,64 +2113,59 @@ class GenericFusedRunner:
         if ctx._jax_failed:
             return None
         try:
-            jax = ctx._ensure_jax()
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
+            return self._dispatch(sb, math.inf)[0]
+        except device_faults():
+            ctx._jax_failed = True
+            return None
 
-            with enable_x64():
-                tt, st, perm, B = ctx._jax_device_padded(sb)
-                if self._dev_params is None:
-                    self._dev_params = jax.device_put(self._params)
+    def _dispatch(self, sb: StackedBatch, incumbent: float):
+        """One fused dispatch under x64: (raw device outputs, batch size)."""
+        ctx = self._ctx
+        jax = ctx._ensure_jax()
+        import jax.numpy as jnp
+
+        with x64():
+            tt, st, perm, B = ctx._jax_device_padded(sb)
+            if self._dev_params is None:
+                self._dev_params = jax.device_put(self._params)
+            if incumbent == math.inf:
                 if self._dev_inf is None:
                     self._dev_inf = jnp.asarray(math.inf, dtype=jnp.float64)
                 inc = self._dev_inf
-                ctx.jax_dispatches += 1
-                _record_trace(self._pkey, int(tt.shape[0]))
-                out = self._core(tt, st, perm, inc, self._dev_params)
-            if self._donates:
-                sb.devp = None  # donated away; fallbacks re-upload
-            return out
-        except Exception:
-            ctx._jax_failed = True
-            return None
+            else:
+                inc = jnp.asarray(float(incumbent), dtype=jnp.float64)
+            ctx.jax_dispatches += 1
+            _record_trace(self._pkey, int(tt.shape[0]))
+            out = self._core(tt, st, perm, inc, self._dev_params)
+        if self._donates:
+            sb.devp = None  # donated away; fallbacks re-upload
+        return out, B
 
     def __call__(self, sb: StackedBatch, incumbent: float):
         ctx = self._ctx
         if ctx._jax_failed:
             return None
         try:
-            jax = ctx._ensure_jax()
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-
-            with enable_x64():
-                tt, st, perm, B = ctx._jax_device_padded(sb)
-                if self._dev_params is None:
-                    self._dev_params = jax.device_put(self._params)
-                inc = jnp.asarray(float(incumbent), dtype=jnp.float64)
-                ctx.jax_dispatches += 1
-                _record_trace(self._pkey, int(tt.shape[0]))
-                out = self._core(tt, st, perm, inc, self._dev_params)
-            if self._donates:
-                sb.devp = None  # donated away; fallbacks re-upload
-            admit, lb_mx, latency, energy, util, score_mx, extras = out
-            latency = np.asarray(latency)
-            if latency.dtype != np.float64:
-                # x64 unavailable: cannot honour bit-identity
-                ctx._jax_failed = True
-                return None
-            return (
-                np.asarray(admit)[:B],
-                float(np.asarray(lb_mx)),
-                latency[:B],
-                np.asarray(energy)[:B],
-                np.asarray(util)[:B],
-                float(np.asarray(score_mx)),
-                {k: np.asarray(v)[:B] for k, v in extras.items()},
-            )
-        except Exception:
+            return _fused_to_host(*self._dispatch(sb, incumbent))
+        except device_faults():
             ctx._jax_failed = True
             return None
+
+
+def _fused_to_host(out, B: int):
+    """Host copy of one fused dispatch's outputs, cut to the ``B`` real
+    rows: ``(admit, lb_guard, latency, energy, util, score_guard,
+    extras)``."""
+    admit, lb_mx, latency, energy, util, score_mx, extras = out
+    return (
+        np.asarray(admit)[:B],
+        float(np.asarray(lb_mx)),
+        require_float64(latency)[:B],
+        np.asarray(energy)[:B],
+        np.asarray(util)[:B],
+        float(np.asarray(score_mx)),
+        {k: np.asarray(v)[:B] for k, v in extras.items()},
+    )
 
 
 # ---------------------------------------------------------------------- #
@@ -2200,8 +2199,8 @@ def _content_digest(obj, canon) -> str:
         ).hexdigest()
         try:
             obj._ctx_digest = d
-        except Exception:
-            pass  # immutable/slots type: recompute next time
+        except AttributeError:
+            pass  # frozen/slots type: recompute next time
     return d
 
 

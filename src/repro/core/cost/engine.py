@@ -748,8 +748,9 @@ class EvaluationEngine:
 
     def _check_backend_degraded(self) -> bool:
         """Degrade a jax engine to the numpy batch path once the analysis
-        context has flagged a jax failure (import, trace, compile, or
-        dispatch -- the context records all of them as ``_jax_failed``).
+        context has flagged a device fault (an injected one or a device
+        runtime error -- see ``analysis.device_faults``; any other jax
+        error propagates out of the search instead).
 
         The numpy and jax array programs are bit-identical by the repo's
         backend contract, so the search continues with unchanged results;

@@ -33,8 +33,8 @@ Two primitives, both strictly RESULT-PRESERVING:
     generations is observationally equivalent).
 
 Every primitive degrades to ``None``/host-loop behavior when the runner
-is unavailable (numpy backend, no generic terms, jax broken mid-flight)
-or an exactness guard trips -- callers fall through to the unchanged
+is unavailable (numpy backend, no generic terms, a device fault
+mid-flight) or an exactness guard trips -- callers fall through to the unchanged
 per-batch path, and results are identical either way.
 
 Env knobs: ``UNION_DEVICE_LOOP=0`` disables the device loops wholesale;
@@ -53,7 +53,9 @@ import numpy as np
 from repro.core.cost.analysis import (
     BATCH_EXACT_LIMIT,
     StackedBatch,
+    device_faults,
     global_trace_count,
+    require_float64,
 )
 from repro.core.cost.engine import EvaluationEngine, PrecomputedScores
 from repro.core.genome_batch import GenomeBatch
@@ -109,9 +111,7 @@ def _materialize(out, B: int) -> Optional[PrecomputedScores]:
         and float(np.asarray(score_mx)) < BATCH_EXACT_LIMIT
     ):
         return None
-    latency = np.asarray(latency)
-    if latency.dtype != np.float64:
-        return None  # x64 unavailable: bit-identity impossible
+    latency = require_float64(latency)
     extras_h = {k: np.asarray(v)[:B] for k, v in extras.items()}
     return PrecomputedScores(
         extras_h["lb_cycles"],
@@ -137,15 +137,12 @@ def device_precompute(
     runner = _precompute_runner(engine)
     if runner is None or not batches:
         return None
-    try:
-        sbs = [gb.stacked() for gb in batches]
-        mega = StackedBatch(
-            np.ascontiguousarray(np.concatenate([s.tt for s in sbs])),
-            np.ascontiguousarray(np.concatenate([s.st for s in sbs])),
-            np.ascontiguousarray(np.concatenate([s.perm for s in sbs])),
-        )
-    except Exception:
-        return None
+    sbs = [gb.stacked() for gb in batches]
+    mega = StackedBatch(
+        np.ascontiguousarray(np.concatenate([s.tt for s in sbs])),
+        np.ascontiguousarray(np.concatenate([s.st for s in sbs])),
+        np.ascontiguousarray(np.concatenate([s.perm for s in sbs])),
+    )
     total = int(mega.tt.shape[0])
     before = global_trace_count()
     try:
@@ -187,7 +184,7 @@ class DeviceGAScorer:
     sees the exact host-loop offer sequence.
 
     ``score`` returns None once the device path is unavailable (no
-    generic runner, guard trip, jax failure); buffered generations are
+    generic runner, guard trip, device fault); buffered generations are
     replayed first -- falling back to plain engine evaluation if their
     device buffers can no longer be read -- so no offer is ever lost and
     the caller can continue with the host loop mid-search."""
@@ -235,11 +232,8 @@ class DeviceGAScorer:
             ):
                 self._disable()
                 return None
-            fitness = np.asarray(extras["metric_score"])[: len(gb)]
-            if fitness.dtype != np.float64:
-                self._disable()
-                return None
-        except Exception:
+            fitness = require_float64(extras["metric_score"])[: len(gb)]
+        except device_faults():
             self._disable()
             return None
         self._buf.append((gb, out))
@@ -257,7 +251,7 @@ class DeviceGAScorer:
         for gb, out in buf:
             try:
                 pre = _materialize(out, len(gb))
-            except Exception:
-                pre = None  # device buffers gone (jax died): re-evaluate
+            except device_faults():
+                pre = None  # device buffers gone (device fault): re-evaluate
             costs = self._engine.evaluate_batch(gb, precomputed=pre)
             self._on_costs(gb, costs)

@@ -8,11 +8,13 @@ independence into a service-grade execution tier:
 
 * **Concurrent dispatch** -- groups run on a worker pool. ``pool="thread"``
   keeps every engine in-process (shared memo/ctx, but GIL-bound on the
-  numpy path); ``pool="process"`` (the default for ``workers > 1``) spawns
-  fresh interpreters per group dispatch -- imports stay jax-free on the
-  numpy path (see ``repro.runtime``'s lazy exports), each child opens its
-  own ResultStore handle on the shared directory, and the store's
-  union-on-flush merges results losslessly.
+  numpy path); ``pool="process"`` (the default for ``workers > 1`` on the
+  numpy backend) spawns fresh interpreters per group dispatch -- imports
+  stay jax-free on the numpy path (see ``repro.runtime``'s lazy exports),
+  each child opens its own ResultStore handle on the shared directory,
+  and the store's union-on-flush merges results losslessly. A jax sweep
+  never spawns: the chip belongs to one process, so ``pool="auto"`` picks
+  threads and ``pool="process"`` raises.
 
 * **Failure handling** -- every group dispatch is wrapped in
   :func:`repro.runtime.fault_tolerance.retry_call`: a per-attempt
@@ -23,8 +25,8 @@ independence into a service-grade execution tier:
   re-running is safe because scoring is deterministic and the store is
   idempotent.
 
-* **Graceful backend degradation** -- a jax failure inside a group
-  (import, trace, compile, or dispatch) does NOT consume a retry: the
+* **Graceful backend degradation** -- a device fault inside a group
+  (injected, or a device runtime error) does NOT consume a retry: the
   engine itself degrades to the numpy batch path mid-search
   (:meth:`EvaluationEngine._check_backend_degraded`), bit-identical by
   the backend contract, counted in ``backend_fallbacks``.
@@ -323,7 +325,7 @@ def run_group(payload: dict) -> dict:
     ctx = engine._ctx
     prior_jax_flag = ctx._jax_failed
     if payload.get("inject_jax_fail"):
-        # simulate a trace/compile failure at the shared choke point every
+        # simulate a device fault at the shared choke point every
         # jax path funnels through; restored below so the process-global
         # context cache is not poisoned for later (non-injected) sweeps
         ctx._jax_failed = True
@@ -415,6 +417,11 @@ class SweepExecutor:
         self.workers = max(0, int(workers))
         if pool not in ("auto", "thread", "process", "serial"):
             raise ValueError(f"unknown pool kind {pool!r}")
+        if pool == "process" and engine_backend == "jax":
+            raise ValueError(
+                "pool='process' with engine_backend='jax': the chip belongs "
+                "to one process, so jax sweeps run in threads or serially"
+            )
         self.pool_kind = pool
         self.group_timeout_s = group_timeout_s
         self.max_group_retries = max_group_retries
@@ -438,9 +445,10 @@ class SweepExecutor:
         if self.workers <= 1 or self.pool_kind == "serial":
             return "serial"
         if self.pool_kind == "auto":
-            # measured: the numpy engine path is GIL-bound (threads give
-            # ~1.0x), so processes are the load-bearing concurrency path
-            return "process"
+            # the chip belongs to one process, so jax groups share the
+            # parent's; the numpy engine path is GIL-bound (threads give
+            # ~1.0x), so processes are its load-bearing concurrency path
+            return "thread" if self.engine_backend == "jax" else "process"
         return self.pool_kind
 
     @staticmethod

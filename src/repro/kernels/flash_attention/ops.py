@@ -26,11 +26,29 @@ import jax.numpy as jnp
 
 from repro import codesign
 from repro import kernels as _cfg
-from repro.codesign import KernelSpace, repair_tile, round_up
+from repro.codesign import (
+    PLAN_OPERAND_BYTES,
+    KernelSpace,
+    fit_tiles,
+    pipeline_vmem_bytes,
+    repair_tile,
+    round_up,
+)
 from repro.core.constraints import mxu_aligned
 from repro.core.problem import Problem
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
+
+
+def flash_vmem_bytes(blocks, D: int, operand_bytes: int = PLAN_OPERAND_BYTES) -> int:
+    """VMEM the compiler allocates for ``flash_attention_pallas`` at (bq,
+    bk) and head dim D: double-buffered q, k, v and out blocks, the f32
+    max/denominator/accumulator scratch, and the f32 score block."""
+    bq, bk = blocks
+    return pipeline_vmem_bytes(
+        [operand_bytes * n * D for n in (bq, bk, bk, bq)],
+        [4 * bq * 128, 4 * bq * 128, 4 * bq * D, 4 * bq * bk],
+    )
 
 
 class FlashAttentionSpace(KernelSpace):
@@ -52,13 +70,19 @@ class FlashAttentionSpace(KernelSpace):
 
     def legalize(self, config, shape, vmem_budget=None):
         bq, bk = config
-        Sq, Skv, _D = shape
+        Sq, Skv, D = shape
         # blocks above 1024 blow the f32 score block past rule R3 even
-        # when the mapper's coarser model admits them: cap, then repair
-        # into divisor tiles
-        return (
+        # when the mapper's coarser model admits them: cap, repair into
+        # divisor tiles, then hold the compiler's VMEM within the budget
+        blocks = (
             repair_tile(bq, Sq, 512, cap=1024),
             repair_tile(bk, Skv, 512, cap=1024),
+        )
+        return fit_tiles(
+            blocks,
+            (Sq, Skv),
+            lambda b: flash_vmem_bytes(b, D),
+            int(vmem_budget or self.vmem_budget),
         )
 
     def example_inputs(self, shape, seed: int = 0):

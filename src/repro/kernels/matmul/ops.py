@@ -21,7 +21,14 @@ import jax.numpy as jnp
 
 from repro import codesign
 from repro import kernels as _cfg
-from repro.codesign import KernelSpace, repair_tile, round_up
+from repro.codesign import (
+    PLAN_OPERAND_BYTES,
+    KernelSpace,
+    fit_tiles,
+    pipeline_vmem_bytes,
+    repair_tile,
+    round_up,
+)
 from repro.core.constraints import mxu_aligned
 from repro.core.mapping import Mapping
 from repro.core.problem import Problem
@@ -32,6 +39,16 @@ def tiles_from_mapping(mapping: Mapping, problem: Problem) -> Tuple[int, int, in
     """Read (bm, bn, bk) from the innermost (VMEM) level temporal tile."""
     leaf = mapping.levels[-1]
     return leaf.tt("m"), leaf.tt("n"), leaf.tt("k")
+
+
+def matmul_vmem_bytes(tiles, operand_bytes: int = PLAN_OPERAND_BYTES) -> int:
+    """VMEM the compiler allocates for ``matmul_pallas`` at (bm, bn, bk):
+    double-buffered x, y and out blocks plus the f32 accumulator."""
+    bm, bn, bk = tiles
+    return pipeline_vmem_bytes(
+        [operand_bytes * bm * bk, operand_bytes * bk * bn, operand_bytes * bm * bn],
+        [4 * bm * bn],
+    )
 
 
 class MatmulSpace(KernelSpace):
@@ -53,11 +70,15 @@ class MatmulSpace(KernelSpace):
         bm, bn, bk = config
         M, N, K = shape
         # safe MXU-aligned defaults if the mapper degenerated (e.g.
-        # trivial mapping with tile 1): clamp into [128, dim]
-        return (
+        # trivial mapping with tile 1): clamp into [128, dim]; then hold
+        # the compiler's VMEM allocation within the budget
+        tiles = (
             repair_tile(bm, M, 256),
             repair_tile(bn, N, 256),
             repair_tile(bk, K, 512),
+        )
+        return fit_tiles(
+            tiles, shape, matmul_vmem_bytes, int(vmem_budget or self.vmem_budget)
         )
 
     def example_inputs(self, shape, seed: int = 0):

@@ -170,7 +170,9 @@ def _ssd_impl(
     xb, dab = to_blocks(x, True), to_blocks(dA, False)
     bb, cb = to_blocks(B, True), to_blocks(C, True)
 
-    y_diag, S_c, dte = ssd_intra_chunk_pallas(xb, dab, bb, cb, interpret=interpret)
+    cum = jnp.cumsum(dab, axis=-1)  # (b, nh, nc, cl) in-chunk log decay
+    y_diag, S_c = ssd_intra_chunk_pallas(xb, cum, bb, cb, interpret=interpret)
+    dte = jnp.exp(cum)  # in-chunk growth factors
 
     # inter-chunk recurrence (cheap, O(nc) elementwise+add)
     chunk_decay = dte[:, :, :, -1]  # (b, nh, nc) = exp(full-chunk decay)

@@ -29,6 +29,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.launch import steps as steps_mod
 from repro.models import init_cache, init_params
+from repro.runtime import use_compile_cache
 
 
 @dataclass
@@ -127,6 +128,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     assert cfg.supports_decode, f"{cfg.name} is encoder-only; nothing to serve"
     params = init_params(cfg, jax.random.PRNGKey(args.seed))

@@ -21,6 +21,7 @@ import argparse
 import logging
 import math
 import time
+from dataclasses import replace
 from pathlib import Path
 
 import jax
@@ -34,7 +35,7 @@ from repro.launch import steps as steps_mod
 from repro.launch.specs import batch_struct, state_struct
 from repro.optim.optimizers import adamw, lion
 from repro.optim.schedules import cosine_schedule
-from repro.runtime import FaultTolerantRunner, RunnerConfig
+from repro.runtime import FaultTolerantRunner, RunnerConfig, use_compile_cache
 from repro.sharding.hints import hints_from_mesh
 from repro.sharding.specs import ShardingRules, batch_specs, named, state_specs
 
@@ -68,12 +69,20 @@ def main(argv=None) -> dict:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--step-timeout", type=float, default=None)
     ap.add_argument("--no-remat", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only this many layers (0: the config's own)")
+    ap.add_argument("--ep-shardmap", action="store_true",
+                    help="route MoE layers through shard_map expert parallelism")
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
+    use_compile_cache()
     cfg = get_config(args.arch)
+    if args.layers:
+        log.info("%s: keeping %d of its %d layers", cfg.name, args.layers, cfg.n_layers)
+        cfg = replace(cfg, n_layers=args.layers)
     mesh = build_mesh(args.mesh)
-    rules = ShardingRules()
+    rules = ShardingRules(ep_shardmap=args.ep_shardmap)
     if mesh is not None:
         hints_from_mesh(mesh, rules)
 
@@ -155,7 +164,18 @@ def main(argv=None) -> dict:
     if ckpt is not None:
         ckpt.save(args.steps, state, extra={"loss": losses[-1]})
         ckpt.wait()
-    return {"first_loss": losses[0], "last_loss": losses[-1], "steps": len(losses)}
+    return {
+        "first_loss": losses[0],
+        "last_loss": losses[-1],
+        "steps": len(losses),
+        "losses": losses,
+        "params": sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state["params"])),
+        # per device while the state is live (None where the backend has
+        # no memory stats)
+        "bytes_in_use": [
+            (d.memory_stats() or {}).get("bytes_in_use") for d in jax.devices()
+        ],
+    }
 
 
 class _nullcontext:

@@ -1,4 +1,5 @@
-"""Runtime: fault tolerance, gradient compression, elastic mesh planning.
+"""Runtime: fault tolerance, gradient compression, elastic mesh planning,
+and the persistent compilation cache.
 
 Attribute access is lazy (PEP 562): ``repro.runtime.fault_tolerance``
 holds the jax-free watchdog/retry core that sweep worker PROCESSES import
@@ -23,6 +24,7 @@ _EXPORTS = {
     "error_feedback_update": "repro.runtime.compression",
     "make_compressed_allreduce": "repro.runtime.compression",
     "plan_mesh": "repro.runtime.elastic",
+    "use_compile_cache": "repro.runtime.compile_cache",
 }
 
 __all__ = sorted(_EXPORTS)

@@ -867,6 +867,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--ready-file", default=None,
                     help="write {port, pid} JSON here once listening")
     args = ap.parse_args(argv)
+    if args.backend == "jax":
+        from repro.runtime.compile_cache import use_compile_cache
+
+        use_compile_cache()
 
     logging.basicConfig(
         level=logging.INFO,

@@ -138,8 +138,7 @@ def test_jax_backend_matches_numpy():
     sigs = [space.random_genome(rng).signature(ctx.dims) for _ in range(13)]
     bt_np = ctx.signature_traffic_batch(sigs, backend="numpy")
     bt_jax = ctx.signature_traffic_batch(sigs, backend="jax")
-    if ctx._jax_failed:
-        pytest.skip("jax batch core unavailable on this platform")
+    assert not ctx._jax_failed
     assert np.array_equal(bt_np.compute_cycles, bt_jax.compute_cycles)
     assert np.array_equal(bt_np.inst_at, bt_jax.inst_at)
     for rn, rj in zip(bt_np.rows, bt_jax.rows):

@@ -40,7 +40,7 @@ from repro.core.problem import Problem
 jax = pytest.importorskip("jax")
 
 from repro.kernels.flash_attention.ops import FLASH_ATTENTION_SPACE, plan_blocks
-from repro.kernels.matmul.ops import MATMUL_SPACE, plan_tiles
+from repro.kernels.matmul.ops import MATMUL_SPACE, matmul_vmem_bytes, plan_tiles
 from repro.kernels.ssd_scan.ops import SSD_SCAN_SPACE, plan_chunk
 
 V8 = 8 * (1 << 20)
@@ -114,7 +114,16 @@ SSD_AB = [(64, 128), (64, 64), (256, 64)]
 
 @pytest.mark.parametrize("mnk", MATMUL_AB)
 def test_ab_matmul_tiles_match_legacy(mnk):
-    assert plan_tiles(*mnk) == _legacy_plan_tiles(*mnk)
+    # the legacy tiles stand wherever the VMEM the compiler allocates for
+    # them fits the budget; elsewhere legalize shrinks them into it
+    legacy = _legacy_plan_tiles(*mnk)
+    got = plan_tiles(*mnk)
+    if matmul_vmem_bytes(legacy) <= V8:
+        assert got == legacy
+    else:
+        assert matmul_vmem_bytes(got) <= V8
+        assert all(g <= l for g, l in zip(got, legacy))
+        assert all(d % g == 0 and g % 128 == 0 for g, d in zip(got, mnk))
 
 
 @pytest.mark.parametrize("sqd", FLASH_AB)
@@ -188,6 +197,47 @@ def test_flash_legalize_odd_shapes(shape):
         cfg = FLASH_ATTENTION_SPACE.legalize(cand, shape)
         _assert_legal(FLASH_ATTENTION_SPACE, shape, cfg)
         assert cfg[0] <= 1024 and cfg[1] <= 1024  # R3 cap
+
+
+@pytest.mark.parametrize(
+    "space, cand, shape",
+    [
+        (MATMUL_SPACE, (1024, 1536, 1024), (4096, 3072, 1024)),
+        (MATMUL_SPACE, (1024, 1024, 1536), (4096, 1024, 3072)),
+        (MATMUL_SPACE, (2048, 2048, 2048), (4096, 4096, 4096)),
+        (FLASH_ATTENTION_SPACE, (1024, 1024), (4096, 4096, 256)),
+    ],
+    ids=["mm-4096x3072x1024", "mm-4096x1024x3072", "mm-4096-cubed", "flash-d256"],
+)
+def test_legalize_holds_compiler_vmem_within_budget(space, cand, shape):
+    # these candidates need more VMEM than the budget once double
+    # buffering and the f32 scratch are counted (the matmul ones more than
+    # the v5e compiler's scoped limit, which refused them)
+    from repro.codesign import SCOPED_VMEM_LIMIT
+    from repro.kernels.flash_attention.ops import flash_vmem_bytes
+
+    def need(cfg):
+        if space is MATMUL_SPACE:
+            return matmul_vmem_bytes(cfg)
+        return flash_vmem_bytes(cfg, shape[2])
+
+    assert need(cand) > (
+        SCOPED_VMEM_LIMIT if space is MATMUL_SPACE else DEFAULT_VMEM_BUDGET
+    )
+    cfg = space.legalize(cand, shape)
+    assert need(cfg) <= DEFAULT_VMEM_BUDGET <= SCOPED_VMEM_LIMIT
+    _assert_legal(space, shape, cfg)
+    assert all(t % 128 == 0 for t in cfg)
+
+
+def test_fit_tiles_refuses_what_cannot_shrink():
+    from repro.codesign import fit_tiles
+
+    # the largest tile shrinks first, the later one on ties
+    area = lambda t: t[0] * t[1]  # noqa: E731
+    assert fit_tiles((512, 256), (512, 256), area, 256 * 128) == (256, 128)
+    with pytest.raises(ValueError, match="cannot shrink"):
+        fit_tiles((128, 128), (128, 128), area, 1)
 
 
 def test_ssd_legalize_is_binding():

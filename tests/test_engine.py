@@ -186,6 +186,29 @@ def test_engine_worker_pool_matches_serial():
         pooled.close()
 
 
+def test_engine_worker_pool_children_never_import_jax():
+    """Pool children score on the scalar path and never import jax, so
+    a jax engine's pool cannot reach for the parent's chip."""
+    arch = edge_accelerator()
+    cm = TimeloopLikeModel()
+    space = MapSpace(GEMM, arch)
+    ms = [space.random_mapping(random.Random(9)) for _ in range(4)]
+    pooled = EvaluationEngine(cm, GEMM, arch, backend="jax", workers=2)
+    try:
+        pool = pooled._get_pool()
+        pool.submit(_pool_eval_probe, [m.to_dict() for m in ms]).result()
+        probe = "'jax' in __import__('sys').modules"
+        assert pool.submit(eval, probe).result() is False
+    finally:
+        pooled.close()
+
+
+def _pool_eval_probe(dicts):
+    from repro.core.cost.engine import _pool_eval
+
+    return _pool_eval(dicts)
+
+
 # --------------------------------------------------------------------- #
 # Nearest-neighbor incumbent seeding (seed_incumbent)
 # --------------------------------------------------------------------- #
@@ -310,6 +333,86 @@ def test_engine_breaker_degrade_open_then_probe_recovers():
         ]
     finally:
         ctx._jax_failed = prior
+
+
+def _enable_x64_missing(monkeypatch):
+    import jax
+
+    monkeypatch.delattr(jax, "enable_x64")
+
+
+def _jax_import_broken(monkeypatch):
+    from repro.core.cost.analysis import AnalysisContext
+
+    def broken(self):
+        raise ImportError("jax cannot be imported")
+
+    monkeypatch.setattr(AnalysisContext, "_ensure_jax", broken)
+
+
+def _x64_off(monkeypatch):
+    import contextlib
+
+    from repro.core.cost import analysis
+
+    monkeypatch.setattr(analysis, "x64", contextlib.nullcontext)
+
+
+@pytest.mark.parametrize(
+    "breakage, error",
+    [
+        (_jax_import_broken, ImportError),
+        (_enable_x64_missing, AttributeError),
+        (_x64_off, RuntimeError),
+    ],
+    ids=["import-error", "attribute-error", "float32-output"],
+)
+@pytest.mark.parametrize("mapper", ["random", "genetic"])
+@pytest.mark.filterwarnings("ignore:Explicitly requested dtype float64")
+def test_jax_defects_raise_out_of_union_opt(monkeypatch, breakage, error, mapper):
+    """A defect in the jax path -- an import or attribute error, or
+    float32 output where float64 is required -- fails the search loudly
+    instead of degrading it to numpy."""
+    pytest.importorskip("jax")
+    arch = edge_accelerator()
+    breakage(monkeypatch)
+    kw = {"samples": 96} if mapper == "random" else {"generations": 2}
+    with pytest.raises(error):
+        union_opt(GEMM, arch, mapper=mapper, cost_model="timeloop",
+                  engine_backend="jax", **kw)
+    assert not get_context(GEMM, arch)._jax_failed
+
+
+def test_injected_jax_fault_degrades_then_breaker_recovers(monkeypatch):
+    """``UNION_FAULT_JAX`` is a device fault: the engine degrades to
+    numpy through its breaker, and recovers once the fault is gone."""
+    pytest.importorskip("jax")
+    from repro.core.mappers import RandomMapper
+    from repro.runtime.fault_tolerance import CircuitBreaker
+
+    arch = edge_accelerator()
+    cm = TimeloopLikeModel()
+    space = MapSpace(GEMM, arch)
+    br = CircuitBreaker(failure_threshold=1, probe_interval=1)
+    eng = EvaluationEngine(cm, GEMM, arch, metric="edp", backend="jax",
+                           breaker=br)
+    ctx = get_context(GEMM, arch)
+    want = union_opt(GEMM, arch, mapper="random", cost_model="timeloop",
+                     engine_backend="numpy", samples=64, seed=2)
+    monkeypatch.setenv("UNION_FAULT_JAX", "1")
+    try:
+        res = RandomMapper(samples=64, seed=2).search(space, cm, "edp", engine=eng)
+        assert eng.backend == "numpy" and eng.stats.backend_fallbacks == 1
+        assert br.state == CircuitBreaker.OPEN
+        assert res.best_mapping.to_dict() == want.mapping.to_dict()
+        monkeypatch.delenv("UNION_FAULT_JAX")
+        assert eng.maybe_restore_backend() is True
+        before = eng.stats.fused_dispatches
+        RandomMapper(samples=64, seed=4).search(space, cm, "edp", engine=eng)
+        assert eng.stats.fused_dispatches > before
+        assert br.state == CircuitBreaker.CLOSED
+    finally:
+        ctx._jax_failed = False
 
 
 def test_maybe_restore_backend_noop_paths():

@@ -173,3 +173,42 @@ def test_plan_mesh_shrinks_dp_keeps_tp():
     m3 = plan_mesh(17, model=16, prefer_pods=2, devices=devs[:17])
     assert m3.devices.shape[0] == 1
     assert m3.devices.shape[-1] == 16
+
+
+# ------------------------------------------------------------------ #
+# persistent compilation cache placement
+# ------------------------------------------------------------------ #
+@pytest.fixture
+def restore_cache_config():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prior = (
+        jax.config.jax_enable_compilation_cache,
+        jax.config.jax_compilation_cache_dir,
+    )
+    yield
+    jax.config.update("jax_enable_compilation_cache", prior[0])
+    jax.config.update("jax_compilation_cache_dir", prior[1])
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_honours_env(monkeypatch, tmp_path, restore_cache_config):
+    from repro.runtime.compile_cache import use_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    assert jax.config.jax_enable_compilation_cache
+
+
+def test_compile_cache_fixed_in_checkout(monkeypatch, restore_cache_config):
+    from pathlib import Path
+
+    from repro.runtime.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first, second = use_compile_cache(), use_compile_cache()
+    assert first == second == str(CHECKOUT_CACHE_DIR)
+    checkout = Path(__file__).resolve().parent.parent
+    assert Path(first).parent == checkout
+    assert jax.config.jax_compilation_cache_dir == first

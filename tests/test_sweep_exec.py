@@ -140,6 +140,18 @@ def test_thread_pool_matches_serial():
     assert serial.stats["pool"] == "serial"
 
 
+def test_jax_sweeps_stay_in_one_process():
+    # the chip belongs to one process: auto picks threads for jax, and an
+    # explicit process pool is refused before anything is spawned
+    tasks = _tasks()
+    threaded = union_opt_sweep(tasks, engine_backend="jax", workers=2)
+    assert threaded.stats["pool"] == "thread"
+    assert threaded.stats["backend_fallbacks"] == 0
+    assert _shape(threaded) == _shape(union_opt_sweep(tasks, workers=1))
+    with pytest.raises(ValueError, match="one process"):
+        union_opt_sweep(tasks, engine_backend="jax", workers=2, pool="process")
+
+
 def test_jax_failure_degrades_to_numpy_bit_identical(monkeypatch):
     tasks = _tasks()
     baseline = union_opt_sweep(tasks, engine_backend="numpy")

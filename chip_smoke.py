@@ -9,14 +9,18 @@ random, genetic and exhaustive mappers through
 ``union_opt(engine_backend="jax")`` and again on the numpy backend with
 the same seeds. The jax runs must not fall back (``backend_fallbacks ==
 0``), must run the device-resident loop (``device_syncs > 0``), and must
-find the same best mappings and bit-identical costs.
+find the same best mappings with the same costs, bit for bit. (The chip's
+float64 differs from the host's in the last bits, so a search on the chip
+states its best mapping's cost as the host computes it.)
 
 Serve phase: ``launch.serve.WaveServer`` serves a few waves of requests
 with qwen3-0.6b at its published widths (28 layers, d_model 1024, vocab
 151936, bf16), its attention on compiled Pallas flash attention with
 blocks from ``codesign.plan``. The planner must not fall back, the decode
 step must hold the kernel (``tpu_custom_call``), and one step's logits
-must agree with the jnp reference attention on the same params and cache.
+must agree with the jnp reference attention on the same params and cache
+-- more closely than two deliberately wrong attentions (the valid cache
+length off by one either way) do, which the tolerance must reject.
 
 Four chips (``--four-chips``): ``launch.train`` runs qwen2-moe-a2.7b at
 its published widths, cut to 2 of its 24 layers, on a (data=1, model=4)
@@ -45,12 +49,10 @@ ENGINE_MAPPERS = {
     "genetic": {"generations": 8, "seed": 0},
     "exhaustive": {"max_mappings": 2048},
 }
-# The chip's float64 is not the host's bit for bit: on a v5e the best
-# costs differ from numpy's in the last bits (at most 5.5e-16 relative,
-# 2 to 3 ulp). The best mappings must still be equal, and the costs equal
-# to within this.
-COST_RTOL = 1e-14
-LOGITS_TOL = 0.05  # max |pallas - reference| over max |reference|
+# max |pallas - reference| over max |reference|: on a v5e the sound kernel
+# reads 0.0138 and the nearer wrong attention (one empty cache slot
+# attended) 0.0676, so the tolerance sits between them
+LOGITS_TOL = 0.03
 LOSS_TOL = 0.02  # |EP - GSPMD| first-step loss, relative
 
 
@@ -88,7 +90,6 @@ def engine_phase(model: str = "qwen3-0.6b", shape: str = "prefill_32k") -> None:
         host = union_opt(problem, arch, mapper=mapper, engine_backend="numpy", **kw)
         s = dev.search
         got, want = _cost_fields(dev.cost), _cost_fields(host.cost)
-        rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-300)
         emit(
             phase="engine",
             problem=problem.name,
@@ -102,8 +103,6 @@ def engine_phase(model: str = "qwen3-0.6b", shape: str = "prefill_32k") -> None:
             best_edp=host.cost.edp,
             bit_identical=bool(np.array_equal(got, want)),
             max_abs_cost_diff=float(np.max(np.abs(got - want))),
-            max_rel_cost_diff=float(np.max(rel)),
-            cost_rtol=COST_RTOL,
             setup_and_search_s=dev_s,
         )
         assert s.backend_fallbacks == 0, f"{mapper}: jax engine fell back"
@@ -112,7 +111,7 @@ def engine_phase(model: str = "qwen3-0.6b", shape: str = "prefill_32k") -> None:
         assert dev.mapping.to_dict() == host.mapping.to_dict(), (
             f"{mapper}: best mapping differs from the numpy run"
         )
-        assert np.max(rel) <= COST_RTOL, f"{mapper}: cost off the numpy run"
+        assert np.array_equal(got, want), f"{mapper}: cost off the numpy run"
 
 
 def _cost_fields(cost):
@@ -180,10 +179,19 @@ def serve_phase(
     nxt = fill[:, -1:]
     got = np.asarray(step_pallas(params, cache, nxt, pos)[0], np.float32)
     kernels.enable_pallas(False)
-    step_ref = jax.jit(lambda p, c, t, i: decode_step(cfg, p, c, t, i))
-    want = np.asarray(step_ref(params, cache, nxt, pos)[0], np.float32)
-    kernels.enable_pallas(True)
-    rel = float(np.max(np.abs(got - want)) / max(1e-6, np.max(np.abs(want))))
+    try:
+        want = _reference_logits(cfg, params, cache, nxt, pos)
+        # controls: wrong attentions the tolerance must reject
+        controls = {
+            name: _rel_diff(
+                _reference_logits(cfg, params, cache, nxt, pos, kv_len_shift=d),
+                want,
+            )
+            for name, d in (("drops_newest_key", -1), ("attends_empty_slot", 1))
+        }
+    finally:
+        kernels.enable_pallas(True)
+    rel = _rel_diff(got, want)
     emit(
         phase="serve",
         arch=cfg.name,
@@ -200,6 +208,7 @@ def serve_phase(
         interpret=kernels.interpret_default(),
         logits_max_rel_diff=rel,
         logits_tol=LOGITS_TOL,
+        control_rel_diff=controls,
         argmax_agree=float(np.mean(got.argmax(-1) == want.argmax(-1))),
     )
     assert len(done) == requests and tokens == requests * max_new
@@ -208,6 +217,39 @@ def serve_phase(
     assert kernels.interpret_default() is False
     assert np.all(np.isfinite(got)) and got.shape == (batch, cfg.vocab)
     assert rel <= LOGITS_TOL, f"Pallas logits off the reference by {rel}"
+    for name, c in controls.items():
+        assert c > LOGITS_TOL, f"the tolerance does not reject {name} ({c})"
+
+
+def _rel_diff(got, want) -> float:
+    """max |got - want| over max |want|."""
+    import numpy as np
+
+    return float(np.max(np.abs(got - want)) / max(1e-6, np.max(np.abs(want))))
+
+
+def _reference_logits(cfg, params, cache, tok, pos, kv_len_shift: int = 0):
+    """One decode step's logits on the jnp reference attention (Pallas must
+    be off); ``kv_len_shift`` moves the valid cache length the attention
+    masks with, to make a deliberately wrong attention."""
+    import jax
+    import numpy as np
+
+    from repro.models import decode_step, layers
+
+    sound = layers.mha
+
+    def shifted(*args, kv_len=None, **kw):
+        if kv_len is not None:
+            kv_len = kv_len + kv_len_shift
+        return sound(*args, kv_len=kv_len, **kw)
+
+    layers.mha = shifted
+    try:
+        step = jax.jit(lambda p, c, t, i: decode_step(cfg, p, c, t, i))
+        return np.asarray(step(params, cache, tok, pos)[0], np.float32)
+    finally:
+        layers.mha = sound
 
 
 def four_chip_phase(layers: int = 2, steps: int = 3) -> None:

@@ -30,7 +30,6 @@ cost models. Now:
 
 from repro.codesign.space import (  # noqa: F401
     DEFAULT_VMEM_BUDGET,
-    PLAN_OPERAND_BYTES,
     SCOPED_VMEM_LIMIT,
     KernelSpace,
     all_spaces,

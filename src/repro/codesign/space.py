@@ -51,17 +51,15 @@ BlockConfig = Tuple[int, ...]
 SCOPED_VMEM_LIMIT = 16 * (1 << 20)
 
 #: The one VMEM budget every kernel space plans under by default, counted
-#: the way the compiler allocates (:func:`pipeline_vmem_bytes`, bf16
-#: operands). It is half of :data:`SCOPED_VMEM_LIMIT`: the same tiles with
-#: float32 operands at most double the footprint and still compile, and
-#: the compiler's own internal scratch (score blocks, temporaries) has
-#: room. Kernel-specific overrides go through ``KernelSpace.vmem_budget``
-#: or the ``vmem_budget=`` parameter of :func:`repro.codesign.planner.plan`
-#: -- never through inline literals.
+#: the way the compiler allocates (:func:`pipeline_vmem_bytes`; matmul and
+#: flash attention count bf16 operands, the dtype the models run in). It
+#: is half of :data:`SCOPED_VMEM_LIMIT`: the same tiles with float32
+#: operands at most double the footprint and still compile, and the
+#: compiler's own internal scratch (score blocks, temporaries) has room.
+#: Kernel-specific overrides go through ``KernelSpace.vmem_budget`` or the
+#: ``vmem_budget=`` parameter of :func:`repro.codesign.planner.plan` --
+#: never through inline literals.
 DEFAULT_VMEM_BUDGET = SCOPED_VMEM_LIMIT // 2
-
-#: Bytes per operand element the planner counts: the models run in bf16.
-PLAN_OPERAND_BYTES = 2
 
 
 def pipeline_vmem_bytes(blocks: Sequence[int], scratch: Sequence[int] = ()) -> int:

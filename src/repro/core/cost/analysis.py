@@ -77,15 +77,51 @@ class InjectedJaxFault(RuntimeError):
     :meth:`AnalysisContext._ensure_jax`)."""
 
 
+class CoreCompileError(RuntimeError):
+    """XLA refused to compile a fused core (see :class:`CompiledCore`)."""
+
+
 def device_faults() -> Tuple[type, ...]:
     """The failures a jax engine survives by degrading to numpy through
     its circuit breaker: an injected fault, or a runtime error raised by
-    the device (XLA reports a compile failure as one too). Anything else
-    -- an import, attribute, type or trace error, or float32 output where
-    float64 is required -- is a defect and propagates out of the search."""
+    the device while a compiled core runs. Anything else -- an import,
+    attribute, type, trace or compile error (:class:`CoreCompileError`),
+    or float32 output where float64 is required -- is a defect and
+    propagates out of the search."""
     import jax
 
     return (InjectedJaxFault, jax.errors.JaxRuntimeError)
+
+
+class CompiledCore:
+    """``jax.jit(fn)`` compiled ahead of time once per argument shape.
+
+    XLA reports a compile failure as the same ``JaxRuntimeError`` a
+    failing device raises; compiling explicitly lets this wrapper re-raise
+    it as :class:`CoreCompileError`, which no degradation handler catches,
+    so only running a compiled core can degrade the engine."""
+
+    def __init__(self, fn, donate_argnums=()) -> None:
+        import jax
+
+        self._jax = jax
+        self._jit = jax.jit(fn, donate_argnums=donate_argnums)
+        self._compiled: Dict[tuple, object] = {}
+
+    def lower(self, *args):
+        return self._jit.lower(*args)
+
+    def __call__(self, *args):
+        leaves, tree = self._jax.tree_util.tree_flatten(args)
+        key = (tree, tuple((np.shape(a), str(a.dtype)) for a in leaves))
+        exe = self._compiled.get(key)
+        if exe is None:
+            try:
+                exe = self._jit.lower(*args).compile()
+            except self._jax.errors.JaxRuntimeError as e:
+                raise CoreCompileError(f"XLA cannot compile the core: {e}") from e
+            self._compiled[key] = exe
+        return exe(*args)
 
 
 def x64():
@@ -977,7 +1013,7 @@ class AnalysisContext:
                 # re-uploaded from the host copy if the handle is reused.
                 donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
                 self._jax_core_donates = bool(donate)
-                self._jax_batch_core = jax.jit(
+                self._jax_batch_core = CompiledCore(
                     self._make_batch_core(jnp, lax), donate_argnums=donate
                 )
             with x64():
@@ -1370,7 +1406,7 @@ class AnalysisContext:
 
             if self._jax_lb_core is None:
                 # never donate here: the scoring pass reuses sb.dev
-                self._jax_lb_core = jax.jit(self._make_lb_core(jnp, lax))
+                self._jax_lb_core = CompiledCore(self._make_lb_core(jnp, lax))
             with x64():
                 tt, st, perm = self._jax_device_arrays(sb)
                 tt, st, perm, B = self._pad_pow2(tt, st, perm, jnp)
@@ -1505,7 +1541,7 @@ class AnalysisContext:
         # matrices' device memory on accelerator backends (unsupported
         # on CPU); the incumbent scalar (arg 3) is never donated.
         donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-        core = jax.jit(
+        core = CompiledCore(
             self._make_fused_core(jnp, lax, lb_builder, terms, metric),
             donate_argnums=donate,
         )
@@ -1568,7 +1604,7 @@ class AnalysisContext:
         entry = _GENERIC_PROGRAMS.get(pkey)
         if entry is None:
             donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-            core = jax.jit(
+            core = CompiledCore(
                 _make_generic_fused_core(skey, terms, metric, jnp, lax),
                 donate_argnums=donate,
             )

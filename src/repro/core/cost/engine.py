@@ -18,7 +18,8 @@ dies on the throughput of that loop, so this module centralizes it:
     population at once. Cache misses are scored as ONE vectorized array
     program (``CostModel.evaluate_signature_batch`` over the stacked
     signature matrices; numpy by default, jitted JAX via ``backend="jax"``,
-    bit-identical to the scalar path either way), or optionally fanned out
+    bit-identical to the scalar path either way on the CPU; see
+    :func:`device_platform` for an accelerator), or optionally fanned out
     to a process pool (``workers > 0``).
 
 The engine is the single evaluation path for all mappers (see
@@ -63,6 +64,21 @@ _BATCH_MIN = 4
 # (``repro.core.mapspace.Genome``): anything with .signature(dims) and
 # .to_mapping(). Genomes let the samplers defer Mapping materialization to
 # actual cache misses.
+
+
+def device_platform(backend: Optional[str]) -> Optional[str]:
+    """The accelerator a ``backend`` engine scores on, or None where its
+    costs are the host's bit for bit (numpy, the scalar path, jax on the
+    CPU). An accelerator's float64 can differ from the host's in the last
+    bits (a TPU v5e by 2-3 ulp), so its costs are stored under their own
+    key and a search reports its best mapping's cost as the host computes
+    it (``mappers.base._Tracker.result``)."""
+    if backend != "jax":
+        return None
+    import jax
+
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
 
 
 class _FusedOutcome(NamedTuple):
@@ -126,11 +142,11 @@ class EngineStats:
     # (jax backend): one jitted dispatch covered bound + mask + traffic +
     # energy for the whole batch.
     fused_dispatches: int = 0
-    # jax backend broke mid-flight (trace/compile/dispatch failure or a
-    # missing install) and the engine degraded itself to the numpy batch
-    # path -- results are bit-identical by the backend contract, so this
-    # is a warning-level event, not an error (at most 1 per engine unless
-    # a circuit breaker re-arms the jax path and it fails again).
+    # a device fault (injected, or a runtime error while a compiled core
+    # ran) degraded the engine to the numpy batch path, which computes the
+    # same costs -- a warning-level event, not an error (at most 1 per
+    # engine unless a circuit breaker re-arms the jax path and it fails
+    # again).
     backend_fallbacks: int = 0
     # batches whose incumbent was warm-started from ``seed_incumbent``
     # (nearest-neighbor warm start): admission pruned from candidate #1
@@ -242,6 +258,7 @@ class EvaluationEngine:
         self.prune = prune
         self.workers = max(0, int(workers))
         self.backend = backend if backend in ("numpy", "jax") else None
+        self.device = device_platform(self.backend)
         self.stats = EngineStats()
         self._dims: Tuple[str, ...] = tuple(problem.dims.keys())
         self._cache: "OrderedDict[Signature, Cost]" = OrderedDict()
@@ -252,7 +269,9 @@ class EvaluationEngine:
         self._lb_batch_fn = cost_model.lower_bound_batch_fn(problem, arch)
         self._store = store
         self._store_skey = (
-            store.space_key(cost_model, problem, arch) if store is not None else None
+            store.space_key(cost_model, problem, arch, self.device)
+            if store is not None
+            else None
         )
         self._pool = None
         self._pool_failed = False
@@ -752,11 +771,12 @@ class EvaluationEngine:
         runtime error -- see ``analysis.device_faults``; any other jax
         error propagates out of the search instead).
 
-        The numpy and jax array programs are bit-identical by the repo's
-        backend contract, so the search continues with unchanged results;
-        the event is counted (``stats.backend_fallbacks``) and warned once
-        per engine so sweep summaries surface the degradation instead of
-        it hiding behind silent per-batch fallbacks.
+        The numpy path computes the same costs (bit for bit where jax runs
+        on the CPU, to the last bits on an accelerator -- see
+        :func:`device_platform`), so the search continues; the event is
+        counted (``stats.backend_fallbacks``) and warned once per engine
+        so sweep summaries surface the degradation instead of it hiding
+        behind silent per-batch fallbacks.
         """
         if self.backend == "jax" and getattr(self._ctx, "_jax_failed", False):
             self.backend = "numpy"
@@ -766,7 +786,7 @@ class EvaluationEngine:
                 self._breaker.record_failure()
             log.warning(
                 "jax backend failed for engine (%s on %s); degraded to the "
-                "numpy path -- results identical by the backend contract",
+                "numpy path",
                 type(self.cost_model).__name__,
                 getattr(self.problem, "name", "?"),
             )

@@ -145,18 +145,25 @@ def _canon_arch(arch: Architecture) -> dict:
     }
 
 
-def space_key(cost_model: CostModel, problem: Problem, arch: Architecture) -> str:
-    """Stable digest of the (cost model, problem, arch) triple."""
-    desc = json.dumps(
-        {
-            "version": STORE_VERSION,
-            "model": [repr(p) for p in cost_model.store_key_parts()],
-            "problem": _canon_problem(problem),
-            "arch": _canon_arch(arch),
-        },
-        sort_keys=True,
-        default=repr,
-    )
+def space_key(
+    cost_model: CostModel,
+    problem: Problem,
+    arch: Architecture,
+    device: Optional[str] = None,
+) -> str:
+    """Stable digest of the (cost model, problem, arch) triple. ``device``
+    names the accelerator that scored the costs when they are not the
+    host's bit for bit (see ``engine.device_platform``), so they are kept
+    apart from the host's."""
+    parts = {
+        "version": STORE_VERSION,
+        "model": [repr(p) for p in cost_model.store_key_parts()],
+        "problem": _canon_problem(problem),
+        "arch": _canon_arch(arch),
+    }
+    if device is not None:
+        parts["device"] = device
+    desc = json.dumps(parts, sort_keys=True, default=repr)
     return hashlib.sha256(desc.encode()).hexdigest()[:32]
 
 
@@ -298,9 +305,13 @@ class ResultStore:
 
     # -------------------------------------------------------------- #
     def space_key(
-        self, cost_model: CostModel, problem: Problem, arch: Architecture
+        self,
+        cost_model: CostModel,
+        problem: Problem,
+        arch: Architecture,
+        device: Optional[str] = None,
     ) -> str:
-        return space_key(cost_model, problem, arch)
+        return space_key(cost_model, problem, arch, device)
 
     def _trim(self, d: "OrderedDict[object, Cost]") -> None:
         cap = self.max_entries_per_space

@@ -226,9 +226,15 @@ class _Tracker:
         best = self.best_mapping
         if best is not None and not isinstance(best, Mapping):
             best = best.to_mapping()  # chain-level genome -> Mapping
+        best_cost = self.best_cost
+        eng = self.engine
+        if best is not None and eng is not None and eng.device is not None:
+            # an accelerator's float64 is not the host's bit for bit: the
+            # device picked the mapping, the host states its cost
+            best_cost = eng.cost_model.evaluate(eng.problem, best, eng.arch)
         return SearchResult(
             best_mapping=best,
-            best_cost=self.best_cost,
+            best_cost=best_cost,
             metric=self.metric,
             evaluated=self.evaluated,
             elapsed_s=time.time() - self.t0,

@@ -90,7 +90,8 @@ def union_opt(
     only per-candidate ``(cycles, energy_pj, util)`` scalars (plus small
     breakdown arrays) to host, with Cost objects materialized for
     admitted rows only -- costs, decisions, and counters bit-identical to
-    the numpy and scalar paths. The compiled program is cached on the
+    the numpy and scalar paths on the CPU (on an accelerator, see
+    ``engine.device_platform``). The compiled program is cached on the
     (problem, arch) analysis context, so repeated ``union_opt`` calls
     over the same space reuse it. ``result_store`` is an optional
     persistent cross-search cache shared between calls (see

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from repro import codesign
 from repro import kernels as _cfg
 from repro.codesign import (
-    PLAN_OPERAND_BYTES,
     KernelSpace,
     fit_tiles,
     pipeline_vmem_bytes,
@@ -40,13 +39,13 @@ from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
 
 
-def flash_vmem_bytes(blocks, D: int, operand_bytes: int = PLAN_OPERAND_BYTES) -> int:
+def flash_vmem_bytes(blocks, D: int) -> int:
     """VMEM the compiler allocates for ``flash_attention_pallas`` at (bq,
-    bk) and head dim D: double-buffered q, k, v and out blocks, the f32
-    max/denominator/accumulator scratch, and the f32 score block."""
+    bk) and head dim D: double-buffered bf16 q, k, v and out blocks, the
+    f32 max/denominator/accumulator scratch, and the f32 score block."""
     bq, bk = blocks
     return pipeline_vmem_bytes(
-        [operand_bytes * n * D for n in (bq, bk, bk, bq)],
+        [2 * n * D for n in (bq, bk, bk, bq)],
         [4 * bq * 128, 4 * bq * 128, 4 * bq * D, 4 * bq * bk],
     )
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from repro import codesign
 from repro import kernels as _cfg
 from repro.codesign import (
-    PLAN_OPERAND_BYTES,
     KernelSpace,
     fit_tiles,
     pipeline_vmem_bytes,
@@ -41,14 +40,11 @@ def tiles_from_mapping(mapping: Mapping, problem: Problem) -> Tuple[int, int, in
     return leaf.tt("m"), leaf.tt("n"), leaf.tt("k")
 
 
-def matmul_vmem_bytes(tiles, operand_bytes: int = PLAN_OPERAND_BYTES) -> int:
+def matmul_vmem_bytes(tiles) -> int:
     """VMEM the compiler allocates for ``matmul_pallas`` at (bm, bn, bk):
-    double-buffered x, y and out blocks plus the f32 accumulator."""
+    double-buffered bf16 x, y and out blocks plus the f32 accumulator."""
     bm, bn, bk = tiles
-    return pipeline_vmem_bytes(
-        [operand_bytes * bm * bk, operand_bytes * bk * bn, operand_bytes * bm * bn],
-        [4 * bm * bn],
-    )
+    return pipeline_vmem_bytes([2 * bm * bk, 2 * bk * bn, 2 * bm * bn], [4 * bm * bn])
 
 
 class MatmulSpace(KernelSpace):

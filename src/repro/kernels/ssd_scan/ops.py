@@ -5,10 +5,10 @@ TPU. Chunk sizing goes through the shared co-design layer
 (docs/codesign.md): :class:`SsdScanSpace` registers the intra-chunk score
 GEMM with ``repro.codesign`` and ``plan_chunk`` is a thin wrapper over
 the single ``codesign.plan`` path. The space's ``legalize`` is BINDING --
-it encodes the kernel's exact working-set rule (the same Union R3
-legality rule the matmul planner uses: cl*cl f32 scores + operands within
-the unified VMEM budget) and picks the largest power-of-two chunk that
-satisfies it, regardless of what the mapper proposed, so the policy
+it holds the VMEM the compiler allocates for the kernel
+(:func:`ssd_vmem_bytes`, the rule the matmul and flash-attention spaces
+also follow) within the unified budget and picks the largest power-of-two
+chunk that fits, regardless of what the mapper proposed, so the policy
 "maximize the chunk under R3" stays exact.
 """
 
@@ -22,13 +22,33 @@ import jax.numpy as jnp
 
 from repro import codesign
 from repro import kernels as _cfg
-from repro.codesign import DEFAULT_VMEM_BUDGET, KernelSpace
+from repro.codesign import (
+    DEFAULT_VMEM_BUDGET,
+    KernelSpace,
+    pipeline_vmem_bytes,
+    round_up,
+)
 from repro.core.problem import Problem
 from repro.kernels.ssd_scan.ssd_scan import ssd_intra_chunk_pallas
 
 #: proxy extent of the chunk dims in the search Problem = the largest
 #: chunk ``legalize`` can pick, so every candidate tile is a divisor
 _MAX_CHUNK = 1024
+
+
+def ssd_vmem_bytes(cl: int, hp: int, n: int) -> int:
+    """VMEM the compiler allocates for ``ssd_intra_chunk_pallas`` at chunk
+    cl: its f32 in blocks (x, the decay row, two decay columns, B, C) and
+    out blocks (y, the chunk state), each padded to whole (8, 128) tiles
+    and double-buffered, plus the (cl, cl) score and decay-mask values."""
+
+    def block(rows: int, cols: int) -> int:
+        return 4 * round_up(rows, 8) * round_up(cols, 128)
+
+    ins = [block(cl, hp), block(1, cl), block(cl, 1), block(cl, 1)]
+    ins += [block(cl, n), block(cl, n)]
+    outs = [block(cl, hp), block(n, hp)]
+    return pipeline_vmem_bytes(ins + outs, [block(cl, cl), block(cl, cl)])
 
 
 class SsdScanSpace(KernelSpace):
@@ -51,19 +71,16 @@ class SsdScanSpace(KernelSpace):
         )
 
     def legalize(self, config, shape, vmem_budget=None):
-        """BINDING repair: largest power-of-two chunk cl with the kernel
-        working set in VMEM -- cl*cl scores + L (2x) + cl*(hp + 2n + 2)
-        operands, all f32. The mapper's proposal is intentionally ignored
-        (the policy is maximize-chunk-under-R3, not argmin of a model)."""
+        """BINDING repair: largest power-of-two chunk cl whose compiler
+        VMEM allocation (:func:`ssd_vmem_bytes`) fits the budget. The
+        mapper's proposal is intentionally ignored (the policy is
+        maximize-chunk-under-R3, not argmin of a model)."""
         hp, n = shape
         budget = int(vmem_budget or self.vmem_budget)
         cl = _MAX_CHUNK
-        while cl > 64:
-            ws = 4 * (2 * cl * cl + cl * (hp + 2 * n + 2) + n * hp)
-            if ws <= budget:
-                return (cl,)
+        while cl > 64 and ssd_vmem_bytes(cl, hp, n) > budget:
             cl //= 2
-        return (64,)
+        return (cl,)
 
     def block_tiles(self, shape, config):
         # the chunk is BOTH free dims of the score block (n stays full)

@@ -87,7 +87,7 @@ from repro.core.architecture import (
     edge_accelerator,
     tpu_chip,
 )
-from repro.core.cost.engine import EvaluationEngine
+from repro.core.cost.engine import EvaluationEngine, device_platform
 from repro.core.cost.store import (
     ResultStore,
     SweepJournal,
@@ -188,28 +188,30 @@ def _parse_arch(spec):
 
 
 def query_fingerprint(cost_model, problem, arch, metric: str,
-                      mapper_name: str, mapper_kw: dict, budget: int) -> str:
+                      mapper_name: str, mapper_kw: dict, budget: int,
+                      device: Optional[str] = None) -> str:
     """Stable content fingerprint of one mapping query.
 
     Built on the store's canonical problem/arch forms, so two queries
     that differ only in display names (which never affect costs) share
     one journal answer. The DEADLINE is deliberately excluded: it shapes
     how long a cold search may run, not what the converged answer is,
-    and only complete (non-exhausted) answers are journaled.
+    and only complete (non-exhausted) answers are journaled. ``device``
+    keeps answers searched on an accelerator apart, as the store does
+    (``engine.device_platform``).
     """
-    desc = json.dumps(
-        {
-            "version": QUERY_VERSION,
-            "model": [repr(p) for p in cost_model.store_key_parts()],
-            "problem": _canon_problem(problem),
-            "arch": _canon_arch(arch),
-            "metric": metric,
-            "mapper": [mapper_name, sorted(mapper_kw.items())],
-            "budget": int(budget),
-        },
-        sort_keys=True,
-        default=repr,
-    )
+    parts = {
+        "version": QUERY_VERSION,
+        "model": [repr(p) for p in cost_model.store_key_parts()],
+        "problem": _canon_problem(problem),
+        "arch": _canon_arch(arch),
+        "metric": metric,
+        "mapper": [mapper_name, sorted(mapper_kw.items())],
+        "budget": int(budget),
+    }
+    if device is not None:
+        parts["device"] = device
+    desc = json.dumps(parts, sort_keys=True, default=repr)
     return hashlib.sha256(desc.encode()).hexdigest()[:24]
 
 
@@ -219,7 +221,8 @@ class _ParsedQuery:
         "mapper_kw", "budget", "deadline_s", "fingerprint",
     )
 
-    def __init__(self, q: dict, default_deadline_s: Optional[float]) -> None:
+    def __init__(self, q: dict, default_deadline_s: Optional[float],
+                 device: Optional[str] = None) -> None:
         if not isinstance(q, dict):
             raise QueryError("query must be a JSON object")
         self.problem = _parse_problem(q.get("problem"))
@@ -256,7 +259,7 @@ class _ParsedQuery:
             raise QueryError("deadline_s must be positive (or null)")
         self.fingerprint = query_fingerprint(
             self.cost_model, self.problem, self.arch, self.metric,
-            self.mapper_name, self.mapper_kw, self.budget,
+            self.mapper_name, self.mapper_kw, self.budget, device,
         )
 
 
@@ -353,6 +356,7 @@ class MappingService:
         if backend not in ("numpy", "jax"):
             raise ValueError(f"unknown backend {backend!r}")
         self.backend = backend
+        self.device = device_platform(backend)
         self.default_deadline_s = deadline_s
         self.queue_cap = int(queue_cap)
         self.n_workers = max(1, int(workers))
@@ -435,7 +439,7 @@ class MappingService:
     def handle_query(self, q: dict) -> dict:
         t0 = time.perf_counter()
         try:
-            parsed = _ParsedQuery(q, self.default_deadline_s)
+            parsed = _ParsedQuery(q, self.default_deadline_s, self.device)
         except QueryError as e:
             with self._state_lock:
                 self.errors += 1

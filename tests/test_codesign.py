@@ -249,6 +249,25 @@ def test_ssd_legalize_is_binding():
     assert SSD_SCAN_SPACE.legalize((0,), (64, 128), vmem_budget=1024) == (64,)
 
 
+@pytest.mark.parametrize("shape", [(64, 128), (64, 64), (128, 256)])
+def test_ssd_chunk_is_the_largest_within_the_compiler_budget(shape):
+    """The planned chunk's compiler VMEM (padded, double-buffered blocks
+    plus the (cl, cl) values) fits the one budget; twice the chunk does
+    not."""
+    from repro.kernels.ssd_scan.ops import ssd_vmem_bytes
+
+    hp, n = shape
+    (cl,) = SSD_SCAN_SPACE.legalize((0,), shape)
+    assert ssd_vmem_bytes(cl, hp, n) <= DEFAULT_VMEM_BUDGET
+    assert ssd_vmem_bytes(2 * cl, hp, n) > DEFAULT_VMEM_BUDGET
+    # six (512, <=128)-lane blocks -- the (cl, 1) decay columns take whole
+    # 128-lane tiles too -- the (1, cl) row padded to 8 sublanes, the
+    # (n, hp) state, all double-buffered; plus two (cl, cl) f32 values
+    assert ssd_vmem_bytes(512, 64, 64) == (
+        2 * 4 * (512 * 128 * 6 + 8 * 512 + 64 * 128) + 2 * 4 * 512 * 512
+    )
+
+
 def test_plan_search_on_odd_shapes_yields_legal_tiles():
     # full plan() path (search included) on shapes the MXU constraints
     # can only satisfy via the full-dim escape hatch

@@ -383,6 +383,59 @@ def test_jax_defects_raise_out_of_union_opt(monkeypatch, breakage, error, mapper
     assert not get_context(GEMM, arch)._jax_failed
 
 
+@pytest.mark.parametrize("mapper", ["random", "genetic"])
+def test_core_compile_error_raises_out_of_union_opt(monkeypatch, mapper):
+    """XLA reports a refused compile as a JaxRuntimeError, the device
+    fault type; it must still fail the search instead of degrading it."""
+    jax = pytest.importorskip("jax")
+    from repro.core.cost.analysis import CoreCompileError, reset_trace_registry
+
+    def refuse(self, *a, **kw):
+        raise jax.errors.JaxRuntimeError("planted compile failure")
+
+    # a problem no other test maps, so no compiled core is cached for it
+    problem = Problem.gemm(48, 40, 24, word_bytes=1, name="compile_fail")
+    arch = edge_accelerator()
+    reset_trace_registry()
+    monkeypatch.setattr(jax.stages.Lowered, "compile", refuse)
+    kw = {"samples": 96} if mapper == "random" else {"generations": 2}
+    with pytest.raises(CoreCompileError):
+        union_opt(problem, arch, mapper=mapper, cost_model="timeloop",
+                  engine_backend="jax", **kw)
+    assert not get_context(problem, arch)._jax_failed
+    monkeypatch.undo()
+    reset_trace_registry()
+
+
+def test_accelerator_costs_keyed_apart_and_best_rescored(monkeypatch, tmp_path):
+    """An engine on an accelerator stores its costs under their own key,
+    and a search reports the best mapping's cost as the host computes it,
+    whatever the device returned."""
+    from dataclasses import replace
+
+    from repro.core.cost import engine as engine_mod
+    from repro.core.cost.store import ResultStore
+    from repro.core.mappers.base import _Tracker
+
+    arch = edge_accelerator()
+    cm = TimeloopLikeModel()
+    store = ResultStore(str(tmp_path))
+    host = EvaluationEngine(cm, GEMM, arch, backend="numpy", store=store)
+    assert host.device is None
+    monkeypatch.setattr(engine_mod, "device_platform", lambda b: "tpu")
+    dev = EvaluationEngine(cm, GEMM, arch, backend="jax", store=store)
+    assert dev.device == "tpu" and dev._store_skey != host._store_skey
+    m = MapSpace(GEMM, arch).random_mapping(random.Random(3))
+    exact = cm.evaluate(GEMM, m, arch)
+    drifted = replace(exact, energy_pj=exact.energy_pj * (1 + 2**-50))
+    tracker = _Tracker("edp", dev)
+    tracker.offer(m, drifted)
+    assert _costs_equal(tracker.result().best_cost, exact)
+    host_tracker = _Tracker("edp", host)
+    host_tracker.offer(m, drifted)
+    assert host_tracker.result().best_cost is drifted
+
+
 def test_injected_jax_fault_degrades_then_breaker_recovers(monkeypatch):
     """``UNION_FAULT_JAX`` is a device fault: the engine degrades to
     numpy through its breaker, and recovers once the fault is gone."""

@@ -73,6 +73,13 @@ def test_query_fingerprint_stable_and_deadline_excluded():
     p2 = Problem.gemm(64, 32, 16, name="fp-OTHER")
     assert f0 == query_fingerprint(cm, p2, arch, "edp", "random",
                                    {"seed": 7}, 100)
+    # answers searched on an accelerator are kept apart from the host's
+    f_tpu = query_fingerprint(cm, p, arch, "edp", "random", {"seed": 7}, 100,
+                              "tpu")
+    assert f_tpu != f0
+    assert _ParsedQuery(_gemm_query(64, 32, 16), 5.0, "tpu").fingerprint != (
+        _ParsedQuery(_gemm_query(64, 32, 16), 5.0).fingerprint
+    )
     # the deadline shapes search time, not the converged answer
     qa = _ParsedQuery(_gemm_query(64, 32, 16), 5.0)
     qb = _ParsedQuery(_gemm_query(64, 32, 16, deadline_s=0.25), 5.0)

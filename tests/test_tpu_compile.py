@@ -97,13 +97,15 @@ def test_flash_attention_decode_compiles_at_qwen3_widths(one_chip):
 
 
 def test_ssd_scan_compiles_at_zamba2_widths(one_chip):
-    from repro.kernels.ssd_scan.ops import plan_chunk, ssd_chunked
+    from repro.codesign import DEFAULT_VMEM_BUDGET
+    from repro.kernels.ssd_scan.ops import plan_chunk, ssd_chunked, ssd_vmem_bytes
 
     cfg = get_config("zamba2-2.7b")
     hp, n = cfg.ssm_head_dim, cfg.ssm_state
     nh = cfg.ssm_expand * cfg.d_model // hp
     L = 2048
-    assert L % plan_chunk(hp, n) == 0
+    cl = plan_chunk(hp, n)
+    assert L % cl == 0 and ssd_vmem_bytes(cl, hp, n) <= DEFAULT_VMEM_BUDGET
     x = _spec((1, L, nh, hp), jnp.bfloat16, one_chip)
     dA = _spec((1, L, nh), jnp.float32, one_chip)
     bc = _spec((1, L, nh, n), jnp.bfloat16, one_chip)

@@ -1,0 +1,6 @@
+"""Share of the traced wave in which no operation ran on the chip."""
+
+
+def read(ctx):
+    red = ctx.trace_reduction
+    return 100.0 * red.idle_share if red is not None and red.window_s > 0 else None

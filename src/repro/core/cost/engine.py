@@ -51,6 +51,7 @@ from repro.core.cost.store import ResultStore
 from repro.core.genome_batch import GenomeBatch, RowCandidate
 from repro.core.mapping import Mapping, mapping_signature  # noqa: F401 (re-export)
 from repro.core.problem import Problem
+from repro.runtime.spans import span
 
 log = logging.getLogger("repro.engine")
 
@@ -161,6 +162,9 @@ class EngineStats:
     # loops (one per mega-batch precompute / deferred-generation flush);
     # stays 0 on the host-loop paths.
     device_syncs: int = 0
+    # wall-clock of the batch paths' ``union.map.admit`` and
+    # ``union.map.materialize`` spans (``repro.runtime.spans``), and of
+    # the per-candidate bound and scoring in ``evaluate_admit``
     admit_s: float = 0.0  # wall-clock spent in the admission (bound) stage
     score_s: float = 0.0  # wall-clock spent scoring admitted misses
 
@@ -514,34 +518,35 @@ class EvaluationEngine:
         self.stats.batches += 1
         self.stats.considered += len(gb)
         results: List[Optional[Cost]] = [None] * len(gb)
-        rows2d = gb.key_rows()
         pending: Dict = {}
         order: List[Tuple[object, object]] = []
         miss_rows: List[int] = []
-        for idx in range(len(gb)):
-            key = rows2d[idx].tobytes()
-            c = self._cache_get(key)
-            if c is not None:
-                results[idx] = c
-                continue
-            dup = pending.get(key)
-            if dup is not None:
-                dup.append(idx)
-                continue
-            cand = RowCandidate(gb, idx)
-            c = self._store_get(key, cand)
-            if c is not None:
-                results[idx] = c
-                continue
-            pending[key] = [idx]
-            order.append((key, cand))
-            miss_rows.append(idx)
+        with span("map.dedup"):
+            rows2d = gb.key_rows()
+            for idx in range(len(gb)):
+                key = rows2d[idx].tobytes()
+                c = self._cache_get(key)
+                if c is not None:
+                    results[idx] = c
+                    continue
+                dup = pending.get(key)
+                if dup is not None:
+                    dup.append(idx)
+                    continue
+                cand = RowCandidate(gb, idx)
+                c = self._store_get(key, cand)
+                if c is not None:
+                    results[idx] = c
+                    continue
+                pending[key] = [idx]
+                order.append((key, cand))
+                miss_rows.append(idx)
 
-        stacked = (
-            gb.stacked(miss_rows)
-            if (order and self.backend is not None and precomputed is None)
-            else None
-        )
+            stacked = (
+                gb.stacked(miss_rows)
+                if (order and self.backend is not None and precomputed is None)
+                else None
+            )
         self._serve_order(
             order,
             incumbent,
@@ -607,22 +612,23 @@ class EvaluationEngine:
         results: List[Optional[Cost]] = [None] * len(candidates)
         pending: Dict = {}
         order: List[Tuple[object, object]] = []  # unique non-hit (key, cand)
-        for idx, cand in enumerate(candidates):
-            key = self._key_of(cand)
-            c = self._cache_get(key)
-            if c is not None:
-                results[idx] = c
-                continue
-            dup = pending.get(key)
-            if dup is not None:
-                dup.append(idx)
-                continue
-            c = self._store_get(key, cand)
-            if c is not None:
-                results[idx] = c
-                continue
-            pending[key] = [idx]
-            order.append((key, cand))
+        with span("map.dedup"):
+            for idx, cand in enumerate(candidates):
+                key = self._key_of(cand)
+                c = self._cache_get(key)
+                if c is not None:
+                    results[idx] = c
+                    continue
+                dup = pending.get(key)
+                if dup is not None:
+                    dup.append(idx)
+                    continue
+                c = self._store_get(key, cand)
+                if c is not None:
+                    results[idx] = c
+                    continue
+                pending[key] = [idx]
+                order.append((key, cand))
 
         self._serve_order(order, incumbent, results, pending)
         return results
@@ -684,28 +690,26 @@ class EvaluationEngine:
             if len(order) >= _BATCH_MIN:
                 self.stats.fused_dispatches += 1
             if self.prune and incumbent != math.inf:
-                t0 = perf_counter()
-                scal = self._scalarize_batch(pre.lb_cyc[rows], pre.lb_en[rows])
-                admit = [bool(v < incumbent) for v in scal]
-                misses, select = self._partition_admitted(order, admit)
-                self.stats.admit_s += perf_counter() - t0
+                with span("map.admit", (self.stats, "admit_s")):
+                    scal = self._scalarize_batch(pre.lb_cyc[rows], pre.lb_en[rows])
+                    admit = [bool(v < incumbent) for v in scal]
+                    misses, select = self._partition_admitted(order, admit)
             else:
                 misses, select = list(order), list(range(len(order)))
             if misses:
-                t0 = perf_counter()
-                commit(
-                    misses,
-                    self.cost_model.costs_from_batch(
-                        self.problem,
-                        self.arch,
-                        pre.latency,
-                        pre.energy,
-                        pre.util,
-                        pre.extras,
-                        indices=[rows[pos] for pos in select],
-                    ),
-                )
-                self.stats.score_s += perf_counter() - t0
+                with span("map.materialize", (self.stats, "score_s")):
+                    commit(
+                        misses,
+                        self.cost_model.costs_from_batch(
+                            self.problem,
+                            self.arch,
+                            pre.latency,
+                            pre.energy,
+                            pre.util,
+                            pre.extras,
+                            indices=[rows[pos] for pos in select],
+                        ),
+                    )
             # precomputed rows exist only because the device mega-dispatch
             # actually served: that is jax evidence too (probe recovery),
             # and a flag tripped since then must still degrade us
@@ -725,42 +729,39 @@ class EvaluationEngine:
                 misses, select = fused.misses, fused.select
                 if misses and fused.arrays is not None:
                     latency, energy, util, extras = fused.arrays
-                    t0 = perf_counter()
-                    commit(
-                        misses,
-                        self.cost_model.costs_from_batch(
-                            self.problem,
-                            self.arch,
-                            latency,
-                            energy,
-                            util,
-                            extras,
-                            indices=select,
-                        ),
-                    )
-                    self.stats.score_s += perf_counter() - t0
+                    with span("map.materialize", (self.stats, "score_s")):
+                        commit(
+                            misses,
+                            self.cost_model.costs_from_batch(
+                                self.problem,
+                                self.arch,
+                                latency,
+                                energy,
+                                util,
+                                extras,
+                                indices=select,
+                            ),
+                        )
                     return
                 # score guard tripped (arrays is None): the decisions
                 # stand and the shared scoring path below re-scores the
                 # admitted subset through the numpy/scalar flow.
 
         if not decided and self.prune and incumbent != math.inf and order:
-            t0 = perf_counter()
-            admit, stacked = self._admit_batch(order, incumbent, stacked=stacked)
-            misses, select = self._partition_admitted(order, admit)
-            self.stats.admit_s += perf_counter() - t0
+            with span("map.admit", (self.stats, "admit_s")):
+                admit, stacked = self._admit_batch(order, incumbent, stacked=stacked)
+                misses, select = self._partition_admitted(order, admit)
 
         if misses:
-            t0 = perf_counter()
-            commit(
-                misses,
-                self._evaluate_misses(
+            with span("map.materialize", (self.stats, "score_s")):
+                commit(
                     misses,
-                    stacked=stacked,
-                    select=select if stacked is not None else None,
-                ),
-            )
-            self.stats.score_s += perf_counter() - t0
+                    self._evaluate_misses(
+                        misses,
+                        stacked=stacked,
+                        select=select if stacked is not None else None,
+                    ),
+                )
         # scoring (or the batched bound) may have tripped the context's jax
         # flag: degrade now so subsequent batches skip the broken path
         self._check_backend_degraded()
@@ -871,29 +872,29 @@ class EvaluationEngine:
         on-device score results -- or None when the score guard tripped,
         in which case the admitted subset must be re-scored host-side.
         The fused dispatch (and mask derivation) is accounted to
-        ``admit_s``; Cost materialization is the caller's ``score_s``.
+        ``admit_s``, and the dispatch with its wait for the results is the
+        span ``union.map.sync`` inside ``union.map.admit``; Cost
+        materialization is the caller's ``score_s``.
         """
         runner = self._get_fused_runner()
         if runner is None:
             return _FusedOutcome(False, None, None, stacked, None)
-        t0 = perf_counter()
-        sb = stacked
-        if sb is None:
-            sigs = [self.signature(cand) for _key, cand in order]
-            sb = self._ctx.stacked_batch(sigs)
-        inc = incumbent if (self.prune and incumbent != math.inf) else math.inf
-        out = runner(sb, inc)
-        if out is None:
-            self._fused_failed = True  # jax broke: stop trying
-            self.stats.admit_s += perf_counter() - t0
-            return _FusedOutcome(False, None, None, sb, None)
-        admit, lb_mx, latency, energy, util, score_mx, extras = out
-        if not (lb_mx < BATCH_EXACT_LIMIT):
-            self.stats.admit_s += perf_counter() - t0
-            return _FusedOutcome(False, None, None, sb, None)
-        self.stats.fused_dispatches += 1
-        misses, select = self._partition_admitted(order, admit)
-        self.stats.admit_s += perf_counter() - t0
+        with span("map.admit", (self.stats, "admit_s")):
+            sb = stacked
+            if sb is None:
+                sigs = [self.signature(cand) for _key, cand in order]
+                sb = self._ctx.stacked_batch(sigs)
+            inc = incumbent if (self.prune and incumbent != math.inf) else math.inf
+            with span("map.sync"):
+                out = runner(sb, inc)
+            if out is None:
+                self._fused_failed = True  # jax broke: stop trying
+                return _FusedOutcome(False, None, None, sb, None)
+            admit, lb_mx, latency, energy, util, score_mx, extras = out
+            if not (lb_mx < BATCH_EXACT_LIMIT):
+                return _FusedOutcome(False, None, None, sb, None)
+            self.stats.fused_dispatches += 1
+            misses, select = self._partition_admitted(order, admit)
         arrays = (
             (latency, energy, util, extras)
             if score_mx < BATCH_EXACT_LIMIT

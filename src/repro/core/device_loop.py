@@ -59,6 +59,7 @@ from repro.core.cost.analysis import (
 )
 from repro.core.cost.engine import EvaluationEngine, PrecomputedScores
 from repro.core.genome_batch import GenomeBatch
+from repro.runtime.spans import span
 
 __all__ = [
     "sync_cadence",
@@ -137,18 +138,19 @@ def device_precompute(
     runner = _precompute_runner(engine)
     if runner is None or not batches:
         return None
-    sbs = [gb.stacked() for gb in batches]
-    mega = StackedBatch(
-        np.ascontiguousarray(np.concatenate([s.tt for s in sbs])),
-        np.ascontiguousarray(np.concatenate([s.st for s in sbs])),
-        np.ascontiguousarray(np.concatenate([s.perm for s in sbs])),
-    )
-    total = int(mega.tt.shape[0])
-    before = global_trace_count()
-    try:
-        out = runner(mega, math.inf)
-    finally:
-        engine.stats.n_traces += global_trace_count() - before
+    with span("map.sync"):
+        sbs = [gb.stacked() for gb in batches]
+        mega = StackedBatch(
+            np.ascontiguousarray(np.concatenate([s.tt for s in sbs])),
+            np.ascontiguousarray(np.concatenate([s.st for s in sbs])),
+            np.ascontiguousarray(np.concatenate([s.perm for s in sbs])),
+        )
+        total = int(mega.tt.shape[0])
+        before = global_trace_count()
+        try:
+            out = runner(mega, math.inf)
+        finally:
+            engine.stats.n_traces += global_trace_count() - before
     if out is None:
         return None
     _admit, lb_mx, latency, energy, util, score_mx, extras = out
@@ -215,14 +217,26 @@ class DeviceGAScorer:
         if getattr(runner, "dispatch_device", None) is None:
             self._disable()
             return None
+        with span("map.sync"):
+            out, fitness = self._dispatch(runner, gb)
+        if fitness is None:
+            self._disable()
+            return None
+        self._buf.append((gb, out))
+        if len(self._buf) >= self._k:
+            self.flush()
+        return fitness
+
+    def _dispatch(self, runner, gb: GenomeBatch):
+        """One generation's device results and its fitness on the host;
+        fitness None when the device path is unavailable."""
         before = global_trace_count()
         try:
             out = runner.dispatch_device(gb.stacked())
         finally:
             self._engine.stats.n_traces += global_trace_count() - before
         if out is None:
-            self._disable()
-            return None
+            return None, None
         try:
             _admit, lb_mx, _lat, _en, _ut, score_mx, extras = out
             # guards + fitness are the ONLY per-generation host transfers
@@ -230,16 +244,10 @@ class DeviceGAScorer:
                 float(np.asarray(lb_mx)) < BATCH_EXACT_LIMIT
                 and float(np.asarray(score_mx)) < BATCH_EXACT_LIMIT
             ):
-                self._disable()
-                return None
-            fitness = require_float64(extras["metric_score"])[: len(gb)]
+                return out, None
+            return out, require_float64(extras["metric_score"])[: len(gb)]
         except device_faults():
-            self._disable()
-            return None
-        self._buf.append((gb, out))
-        if len(self._buf) >= self._k:
-            self.flush()
-        return fitness
+            return out, None
 
     def flush(self) -> None:
         """Materialize and replay every buffered generation, in order.
@@ -248,10 +256,11 @@ class DeviceGAScorer:
             return
         buf, self._buf = self._buf, []
         self._engine.stats.device_syncs += 1
-        for gb, out in buf:
-            try:
-                pre = _materialize(out, len(gb))
-            except device_faults():
-                pre = None  # device buffers gone (device fault): re-evaluate
-            costs = self._engine.evaluate_batch(gb, precomputed=pre)
-            self._on_costs(gb, costs)
+        with span("map.sync"):
+            for gb, out in buf:
+                try:
+                    pre = _materialize(out, len(gb))
+                except device_faults():
+                    pre = None  # device buffers gone (device fault): re-evaluate
+                costs = self._engine.evaluate_batch(gb, precomputed=pre)
+                self._on_costs(gb, costs)

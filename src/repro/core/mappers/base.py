@@ -182,7 +182,7 @@ class _Tracker:
         self.best_cost: Optional[Cost] = None
         self.best_metric_value: float = math.inf
         self.evaluated = 0
-        self.t0 = time.time()
+        self.t0 = time.perf_counter()
         self.trajectory: List[Tuple[int, float]] = []
 
     def offer(self, mapping: Mapping, cost: Cost) -> bool:
@@ -237,7 +237,7 @@ class _Tracker:
             best_cost=best_cost,
             metric=self.metric,
             evaluated=self.evaluated,
-            elapsed_s=time.time() - self.t0,
+            elapsed_s=time.perf_counter() - self.t0,
             trajectory=self.trajectory,
             cache_hits=delta("cache_hits"),
             pruned=delta("pruned"),

@@ -39,6 +39,7 @@ from repro.core.cost.engine import EvaluationEngine
 from repro.core.device_loop import DeviceGAScorer, device_loop_enabled
 from repro.core.mappers.base import Mapper, SearchResult
 from repro.core.mapspace import MapSpace, fast_sample
+from repro.runtime.spans import span
 
 
 class GeneticMapper(Mapper):
@@ -98,10 +99,11 @@ class GeneticMapper(Mapper):
         # so deferring the offers is observationally equivalent -- best,
         # trajectory, memo and store contents equal the host loop's.
         def on_costs(g, cs):
-            for i, c in enumerate(cs):
-                tr.offer_lazy(
-                    lambda b=i, gg=g: gg.genome(b), c, score=c.metric(metric)
-                )
+            with span("map.offer"):
+                for i, c in enumerate(cs):
+                    tr.offer_lazy(
+                        lambda b=i, gg=g: gg.genome(b), c, score=c.metric(metric)
+                    )
 
         scorer = DeviceGAScorer(engine, on_costs) if device_loop_enabled(engine) else None
 
@@ -114,108 +116,111 @@ class GeneticMapper(Mapper):
                     return f
             cs = engine.evaluate_batch(g)
             out = np.empty(len(g), dtype=np.float64)
-            for i, c in enumerate(cs):
-                s = c.metric(metric)
-                tr.offer_lazy(lambda b=i, gg=g: gg.genome(b), c, score=s)
-                out[i] = s
+            with span("map.offer"):
+                for i, c in enumerate(cs):
+                    s = c.metric(metric)
+                    tr.offer_lazy(lambda b=i, gg=g: gg.genome(b), c, score=s)
+                    out[i] = s
             return out
 
-        tt, st, perm = gbm.random_rows_batch(space, rng, P)
-        gb = gbm.GenomeBatch(space, tt, st, perm)
+        with span("map.generate"):
+            tt, st, perm = gbm.random_rows_batch(space, rng, P)
+            gb = gbm.GenomeBatch(space, tt, st, perm)
         fitness = score_batch(gb)
 
         T = min(self.tournament, P)
         elite = min(self.elite, P)
         C = P - elite
         for _gen in range(self.generations):
-            order = np.argsort(fitness, kind="stable")
-            tt, st, perm, fitness = tt[order], st[order], perm[order], fitness[order]
-            if C <= 0:
-                break
-            # tournament selection: per (child, parent), T distinct
-            # population indices via the smallest-keys trick, winner by
-            # fitness
-            keys = rng.random((C, 2, P))
-            contenders = np.argpartition(keys, T - 1, axis=2)[:, :, :T]
-            cfit = fitness[contenders]
-            winner = np.take_along_axis(
-                contenders, np.argmin(cfit, axis=2)[:, :, None], axis=2
-            )[:, :, 0]
-            pa, pb = winner[:, 0], winner[:, 1]
-            # FUSED child construction: per-dim uniform chain crossover +
-            # per-level order choice, mutation applied in the same round
-            # (mutated children: one order swap or one chain re-sample),
-            # ONE legality program per round over all still-illegal
-            # children, which redraw their masks/moves against the same
-            # parents
-            ctt = np.empty((C, n, D), dtype=np.int64)
-            cst = np.empty_like(ctt)
-            cperm = np.empty_like(ctt)
-            mut = rng.random(C) < self.mutation_rate
-            todo = np.arange(C)
-            for _try in range(3):
-                V = todo.size
-                sa, sb = pa[todo], pb[todo]
-                md = (rng.random((V, D)) < 0.5)[:, None, :]
-                mo = (rng.random((V, n)) < 0.5)[:, :, None]
-                t2 = np.where(md, tt[sa], tt[sb])
-                s2 = np.where(md, st[sa], st[sb])
-                p2 = np.where(mo, perm[sa], perm[sb])
-                mrows = np.flatnonzero(mut[todo])
-                if mrows.size:
-                    move = rng.random(mrows.size) < 0.3
-                    om = mrows[move]
-                    if om.size and D >= 2:
-                        lvl = rng.integers(0, n, om.size)
-                        a = rng.integers(0, D, om.size)
-                        b = rng.integers(0, D - 1, om.size)
-                        b = b + (b >= a)
-                        swp = p2[om, lvl, a].copy()
-                        p2[om, lvl, a] = p2[om, lvl, b]
-                        p2[om, lvl, b] = swp
-                    cmr = mrows[~move]
-                    if cmr.size:
-                        dsel = rng.integers(0, D, cmr.size)
-                        for j in range(D):
-                            rr = cmr[dsel == j]
-                            if rr.size == 0:
-                                continue
-                            tcol, scol = gbm.sample_chain_cols(
-                                space, rng, j, rr.size
-                            )
-                            t2[rr, :, j] = tcol
-                            s2[rr, :, j] = scol
-                # two-phase legality: pass the (majority) already-legal
-                # children untouched -- duplicate children stay exact
-                # duplicates and keep hitting the engine memo -- then
-                # repair ONLY the failures' fanout (the dominant failure
-                # mode of cross-dim mixing) and re-check that small subset
-                ok = gbm.legal_batch(space, t2, s2, p2, structured=True)
-                bad = np.flatnonzero(~ok)
-                if bad.size:
-                    bt, bs, bp = t2[bad], s2[bad], p2[bad]
-                    gbm.repair_fanout_batch(space, rng, bt, bs)
-                    ok2 = gbm.legal_batch(space, bt, bs, bp, structured=True)
-                    fixed = np.flatnonzero(ok2)
-                    t2[bad[fixed]] = bt[fixed]
-                    s2[bad[fixed]] = bs[fixed]
-                    ok[bad[fixed]] = True
-                ctt[todo], cst[todo], cperm[todo] = t2, s2, p2
-                todo = todo[~ok]
-                if todo.size == 0:
+            with span("map.generate"):
+                order = np.argsort(fitness, kind="stable")
+                tt, st, perm, fitness = tt[order], st[order], perm[order], fitness[order]
+                if C <= 0:
                     break
-            # Fallback after the bounded retry rounds: parent a wholesale.
-            # Deliberately a DUPLICATE of an already-scored candidate --
-            # it shows up as a memo hit, costing a dict probe instead of
-            # an array-program evaluation (the scalar GA converged to the
-            # same behavior through its per-candidate fallbacks).
-            if todo.size:
-                ctt[todo], cst[todo], cperm[todo] = (
-                    tt[pa[todo]],
-                    st[pa[todo]],
-                    perm[pa[todo]],
-                )
-            cgb = gbm.GenomeBatch(space, ctt, cst, cperm)
+                # tournament selection: per (child, parent), T distinct
+                # population indices via the smallest-keys trick, winner by
+                # fitness
+                keys = rng.random((C, 2, P))
+                contenders = np.argpartition(keys, T - 1, axis=2)[:, :, :T]
+                cfit = fitness[contenders]
+                winner = np.take_along_axis(
+                    contenders, np.argmin(cfit, axis=2)[:, :, None], axis=2
+                )[:, :, 0]
+                pa, pb = winner[:, 0], winner[:, 1]
+                # FUSED child construction: per-dim uniform chain crossover +
+                # per-level order choice, mutation applied in the same round
+                # (mutated children: one order swap or one chain re-sample),
+                # ONE legality program per round over all still-illegal
+                # children, which redraw their masks/moves against the same
+                # parents
+                ctt = np.empty((C, n, D), dtype=np.int64)
+                cst = np.empty_like(ctt)
+                cperm = np.empty_like(ctt)
+                mut = rng.random(C) < self.mutation_rate
+                todo = np.arange(C)
+                for _try in range(3):
+                    V = todo.size
+                    sa, sb = pa[todo], pb[todo]
+                    md = (rng.random((V, D)) < 0.5)[:, None, :]
+                    mo = (rng.random((V, n)) < 0.5)[:, :, None]
+                    t2 = np.where(md, tt[sa], tt[sb])
+                    s2 = np.where(md, st[sa], st[sb])
+                    p2 = np.where(mo, perm[sa], perm[sb])
+                    mrows = np.flatnonzero(mut[todo])
+                    if mrows.size:
+                        move = rng.random(mrows.size) < 0.3
+                        om = mrows[move]
+                        if om.size and D >= 2:
+                            lvl = rng.integers(0, n, om.size)
+                            a = rng.integers(0, D, om.size)
+                            b = rng.integers(0, D - 1, om.size)
+                            b = b + (b >= a)
+                            swp = p2[om, lvl, a].copy()
+                            p2[om, lvl, a] = p2[om, lvl, b]
+                            p2[om, lvl, b] = swp
+                        cmr = mrows[~move]
+                        if cmr.size:
+                            dsel = rng.integers(0, D, cmr.size)
+                            for j in range(D):
+                                rr = cmr[dsel == j]
+                                if rr.size == 0:
+                                    continue
+                                tcol, scol = gbm.sample_chain_cols(
+                                    space, rng, j, rr.size
+                                )
+                                t2[rr, :, j] = tcol
+                                s2[rr, :, j] = scol
+                    # two-phase legality: pass the (majority) already-legal
+                    # children untouched -- duplicate children stay exact
+                    # duplicates and keep hitting the engine memo -- then
+                    # repair ONLY the failures' fanout (the dominant failure
+                    # mode of cross-dim mixing) and re-check that small subset
+                    ok = gbm.legal_batch(space, t2, s2, p2, structured=True)
+                    bad = np.flatnonzero(~ok)
+                    if bad.size:
+                        bt, bs, bp = t2[bad], s2[bad], p2[bad]
+                        gbm.repair_fanout_batch(space, rng, bt, bs)
+                        ok2 = gbm.legal_batch(space, bt, bs, bp, structured=True)
+                        fixed = np.flatnonzero(ok2)
+                        t2[bad[fixed]] = bt[fixed]
+                        s2[bad[fixed]] = bs[fixed]
+                        ok[bad[fixed]] = True
+                    ctt[todo], cst[todo], cperm[todo] = t2, s2, p2
+                    todo = todo[~ok]
+                    if todo.size == 0:
+                        break
+                # Fallback after the bounded retry rounds: parent a wholesale.
+                # Deliberately a DUPLICATE of an already-scored candidate --
+                # it shows up as a memo hit, costing a dict probe instead of
+                # an array-program evaluation (the scalar GA converged to the
+                # same behavior through its per-candidate fallbacks).
+                if todo.size:
+                    ctt[todo], cst[todo], cperm[todo] = (
+                        tt[pa[todo]],
+                        st[pa[todo]],
+                        perm[pa[todo]],
+                    )
+                cgb = gbm.GenomeBatch(space, ctt, cst, cperm)
             cfit2 = score_batch(cgb)
             tt = np.concatenate([tt[:elite], ctt])
             st = np.concatenate([st[:elite], cst])

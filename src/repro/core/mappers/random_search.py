@@ -37,6 +37,7 @@ from repro.core.device_loop import (
 from repro.core.genome_batch import philox_rng, random_genome_batch
 from repro.core.mappers.base import Mapper, SearchResult
 from repro.core.mapspace import MapSpace
+from repro.runtime.spans import span
 
 
 class RandomMapper(Mapper):
@@ -103,12 +104,13 @@ class RandomMapper(Mapper):
                 rem2 -= k
                 sizes.append(k)
             remaining = rem2
-            if v2:
-                batches = [random_genome_batch(space, rng, k) for k in sizes]
-            else:
-                batches = [
-                    [space.random_genome(rng) for _ in range(k)] for k in sizes
-                ]
+            with span("map.generate"):
+                if v2:
+                    batches = [random_genome_batch(space, rng, k) for k in sizes]
+                else:
+                    batches = [
+                        [space.random_genome(rng) for _ in range(k)] for k in sizes
+                    ]
             pres = device_precompute(engine, batches) if window > 1 else None
             if pres is None:
                 pres = [None] * len(batches)
@@ -119,19 +121,20 @@ class RandomMapper(Mapper):
                     probe=self.probe,
                     precomputed=pre,
                 )
-                for i, c in enumerate(costs):
-                    if c is not None and (
-                        tr.offer_lazy(lambda b=i, g=batch: g.genome(b), c)
-                        if v2
-                        else tr.offer(batch[i], c)
-                    ):
-                        stale = 0
-                    else:
-                        # pruned candidates are provably non-improving
-                        stale += 1
-                        if self.patience and stale >= self.patience:
-                            stop = True
-                            break
+                with span("map.offer"):
+                    for i, c in enumerate(costs):
+                        if c is not None and (
+                            tr.offer_lazy(lambda b=i, g=batch: g.genome(b), c)
+                            if v2
+                            else tr.offer(batch[i], c)
+                        ):
+                            stale = 0
+                        else:
+                            # pruned candidates are provably non-improving
+                            stale += 1
+                            if self.patience and stale >= self.patience:
+                                stop = True
+                                break
                 if stop:
                     break
         return tr.result()

@@ -41,6 +41,7 @@ from repro.core.mappers.base import SearchResult
 from repro.core.mapping import Mapping
 from repro.core.mapspace import MapSpace
 from repro.core.problem import Problem
+from repro.runtime.spans import span
 
 COST_MODEL_REGISTRY = {
     "timeloop": TimeloopLikeModel,
@@ -100,36 +101,43 @@ def union_opt(
     pass one store so identical signatures are scored once across runs;
     callers own ``flush()``.
     """
-    problem = (
-        lower_layer_to_problem(workload) if isinstance(workload, LayerOp) else workload
-    )
-    cm = (
-        COST_MODEL_REGISTRY[cost_model]() if isinstance(cost_model, str) else cost_model
-    )
-    rep = conformable_models(problem, [cm])
-    ok, why = rep.results.get(cm.name, (cm.conformable(problem), "model check"))
-    if not ok:
-        raise ValueError(
-            f"problem {problem.name!r} is not conformable to cost model "
-            f"{cm.name!r}: {why}"
-        )
-    mp = MAPPER_REGISTRY[mapper](**mapper_kw) if isinstance(mapper, str) else mapper
-    space = MapSpace(problem, arch, constraints)
-    engine = EvaluationEngine(
-        cm,
-        problem,
-        arch,
-        metric=metric,
-        cache_size=engine_cache,
-        prune=engine_prune,
-        workers=engine_workers,
-        backend=engine_backend,
-        store=result_store,
-    )
-    try:
-        res = mp.search(space, cm, metric, engine=engine)
-    finally:
-        engine.close()
+    with span("map.query"):
+        with span("map.setup"):
+            problem = (
+                lower_layer_to_problem(workload)
+                if isinstance(workload, LayerOp)
+                else workload
+            )
+            cm = (
+                COST_MODEL_REGISTRY[cost_model]()
+                if isinstance(cost_model, str)
+                else cost_model
+            )
+            rep = conformable_models(problem, [cm])
+            ok, why = rep.results.get(cm.name, (cm.conformable(problem), "model check"))
+            if not ok:
+                raise ValueError(
+                    f"problem {problem.name!r} is not conformable to cost model "
+                    f"{cm.name!r}: {why}"
+                )
+            mp = MAPPER_REGISTRY[mapper](**mapper_kw) if isinstance(mapper, str) else mapper
+            space = MapSpace(problem, arch, constraints)
+            engine = EvaluationEngine(
+                cm,
+                problem,
+                arch,
+                metric=metric,
+                cache_size=engine_cache,
+                prune=engine_prune,
+                workers=engine_workers,
+                backend=engine_backend,
+                store=result_store,
+            )
+        try:
+            res = mp.search(space, cm, metric, engine=engine)
+        finally:
+            with span("map.setup"):
+                engine.close()
     if res.best_mapping is None:
         raise RuntimeError(f"mapper {mp.name} found no legal mapping for {problem.name}")
     return UnionSolution(

@@ -30,6 +30,7 @@ from repro.configs.base import get_config
 from repro.launch import steps as steps_mod
 from repro.models import init_cache, init_params
 from repro.runtime import use_compile_cache
+from repro.runtime.spans import span, step_span
 
 
 @dataclass
@@ -71,36 +72,50 @@ class WaveServer:
         toks = np.full((self.slots, L), self.pad, np.int32)
         for i, r in enumerate(wave):
             toks[i, L - len(r.prompt):] = r.prompt
-        cache = init_cache(self.cfg, self.slots, self.max_len)
+        with span("serve.init_cache"):
+            cache = init_cache(self.cfg, self.slots, self.max_len)
         logits = None
-        for t in range(L):
-            logits, cache = self._decode(
-                self.params, cache, jnp.asarray(toks[:, t : t + 1]), jnp.int32(t)
-            )
+        with span("serve.prefill"):
+            for t in range(L):
+                with span("serve.dispatch"):
+                    logits, cache = self._decode(
+                        self.params, cache, jnp.asarray(toks[:, t : t + 1]), jnp.int32(t)
+                    )
         return logits, cache, L
 
     def run_wave(self, wave: List[Request]) -> int:
         """Prefill + decode one wave to completion. Returns decode steps."""
+        with span("serve.wave"):
+            return self._run_wave(wave)
+
+    def _run_wave(self, wave: List[Request]) -> int:
         nxt, cache, pos = self._prefill(wave)
-        last = np.asarray(nxt)[:, 0].astype(np.int32)  # (slots,)
+        with span("serve.pull"):
+            last = np.asarray(nxt)[:, 0].astype(np.int32)  # (slots,)
         steps = 0
         live = {i: r for i, r in enumerate(wave)}
-        for i, r in live.items():
-            r.out.append(int(last[i]))
-        while any(not r.done for r in wave) and pos < self.max_len - 1:
-            logits, cache = self._decode(
-                self.params, cache, jnp.asarray(last)[:, None], jnp.int32(pos)
-            )
-            last = np.asarray(logits)[:, 0].astype(np.int32)
-            pos += 1
-            steps += 1
-            for i, r in list(live.items()):
-                if r.done:
-                    continue
+        with span("serve.emit"):
+            for i, r in live.items():
                 r.out.append(int(last[i]))
-                if len(r.out) >= r.max_new:
-                    r.done = True
-                    del live[i]
+        with span("serve.decode"):
+            while any(not r.done for r in wave) and pos < self.max_len - 1:
+                with step_span("serve.step", pos):
+                    with span("serve.dispatch"):
+                        logits, cache = self._decode(
+                            self.params, cache, jnp.asarray(last)[:, None], jnp.int32(pos)
+                        )
+                    with span("serve.pull"):
+                        last = np.asarray(logits)[:, 0].astype(np.int32)
+                    pos += 1
+                    steps += 1
+                    with span("serve.emit"):
+                        for i, r in list(live.items()):
+                            if r.done:
+                                continue
+                            r.out.append(int(last[i]))
+                            if len(r.out) >= r.max_new:
+                                r.done = True
+                                del live[i]
         for r in wave:
             r.done = True
         return steps

@@ -36,6 +36,7 @@ from repro.launch.specs import batch_struct, state_struct
 from repro.optim.optimizers import adamw, lion
 from repro.optim.schedules import cosine_schedule
 from repro.runtime import FaultTolerantRunner, RunnerConfig, use_compile_cache
+from repro.runtime.spans import step_span
 from repro.sharding.hints import hints_from_mesh
 from repro.sharding.specs import ShardingRules, batch_specs, named, state_specs
 
@@ -149,9 +150,10 @@ def main(argv=None) -> dict:
     ctx = mesh if mesh is not None else _nullcontext()
     with ctx:
         for step in range(start_step, args.steps):
-            batch = next(pipe)
-            state, metrics = runner.run_step(state, batch, step)
-            loss = float(metrics["loss"])
+            with step_span("train.step", step):
+                batch = next(pipe)
+                state, metrics = runner.run_step(state, batch, step)
+                loss = float(metrics["loss"])
             losses.append(loss)
             if step % args.log_every == 0 or step == args.steps - 1:
                 dt = time.time() - t0

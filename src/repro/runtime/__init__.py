@@ -1,5 +1,5 @@
 """Runtime: fault tolerance, gradient compression, elastic mesh planning,
-and the persistent compilation cache.
+the persistent compilation cache, and named host spans.
 
 Attribute access is lazy (PEP 562): ``repro.runtime.fault_tolerance``
 holds the jax-free watchdog/retry core that sweep worker PROCESSES import
@@ -25,6 +25,8 @@ _EXPORTS = {
     "make_compressed_allreduce": "repro.runtime.compression",
     "plan_mesh": "repro.runtime.elastic",
     "use_compile_cache": "repro.runtime.compile_cache",
+    "span": "repro.runtime.spans",
+    "step_span": "repro.runtime.spans",
 }
 
 __all__ = sorted(_EXPORTS)

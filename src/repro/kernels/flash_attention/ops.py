@@ -150,8 +150,8 @@ _fa.defvjp(_fa_fwd, _fa_bwd)
 
 def flash_attention(
     q: jnp.ndarray,  # (b, Sq, hq, d) -- model layout (see models/layers.py)
-    k: jnp.ndarray,  # (b, Skv, hkv, d)
-    v: jnp.ndarray,  # (b, Skv, hkv, dv)
+    k: jnp.ndarray,  # (b, Skv, hkv, d), or (b, hkv, Skv, d) if kv_heads_major
+    v: jnp.ndarray,  # (b, Skv, hkv, dv), or (b, hkv, Skv, dv) if kv_heads_major
     *,
     causal: bool,
     q_offset=0,
@@ -159,19 +159,24 @@ def flash_attention(
     sm_scale: Optional[float] = None,
     blocks: Optional[Tuple[int, int]] = None,
     interpret: Optional[bool] = None,
+    kv_heads_major: bool = False,
 ) -> jnp.ndarray:
     """Drop-in for models.layers.mha's math on TPU. Handles GQA natively
-    and pads Sq/Skv up to the block grid (padded KV is masked via kv_len)."""
+    and pads Sq/Skv up to the block grid (padded KV is masked via kv_len).
+    K and V already in the kernel's (b, h, S, d) layout, as the decode cache
+    holds them, pass with ``kv_heads_major`` and are read without a swap."""
     interpret = _cfg.interpret_default() if interpret is None else interpret
     b, Sq, hq, d = q.shape
-    _, Skv, hkv, dv = v.shape
+    Skv = v.shape[2] if kv_heads_major else v.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     bq, bk = blocks or plan_blocks(round_up(Sq, 128), round_up(Skv, 128), d)
     bq, bk = min(bq, round_up(Sq, 8)), min(bk, round_up(Skv, 8))
     Sqp, Skvp = round_up(Sq, bq), round_up(Skv, bk)
     qt = jnp.swapaxes(q, 1, 2)  # (b, hq, Sq, d)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    if kv_heads_major:
+        kt, vt = k, v
+    else:
+        kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
     if Sqp != Sq:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, Sqp - Sq), (0, 0)))
     if Skvp != Skv:

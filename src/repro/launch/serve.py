@@ -10,7 +10,10 @@ then decodes in lock-step; a wave retires when all its members finish.
 
 This is the fixed-shape JAX analogue of batch-of-requests serving; the
 decode step is EXACTLY the step the multi-pod dry-run compiles
-(launch/steps.make_serve_step). A continuous-batching scheduler with
+(launch/steps.make_serve_step). The server jits it with the cache donated:
+each step writes its one new token per layer into the cache in place
+(``cache_alias_share`` reads 1.0 when the compiled step does), instead of
+writing a whole new cache. A continuous-batching scheduler with
 per-slot position vectors is a server-side extension that changes only
 this file, not the model/step layer.
 """
@@ -53,7 +56,12 @@ class WaveServer:
         self.max_len = max_len
         self.pad = pad_token
         self.queue: List[Request] = []
-        self._decode = jax.jit(steps_mod.make_serve_step(cfg))
+        # the cache (argument 1) is donated: the step updates it in place
+        self._decode = jax.jit(steps_mod.make_serve_step(cfg), donate_argnums=(1,))
+        # share of the cache's bytes the compiled step's output aliases,
+        # recorded when the step is first compiled: 1.0 when the update is
+        # in place, 0.0 when donation did not take
+        self.cache_alias_share: Optional[float] = None
 
     def submit(self, req: Request) -> None:
         self.queue.append(req)
@@ -74,6 +82,8 @@ class WaveServer:
             toks[i, L - len(r.prompt):] = r.prompt
         with span("serve.init_cache"):
             cache = init_cache(self.cfg, self.slots, self.max_len)
+        if self.cache_alias_share is None:
+            self.cache_alias_share = self._alias_share(cache, toks[:, :1])
         logits = None
         with span("serve.prefill"):
             for t in range(L):
@@ -82,6 +92,15 @@ class WaveServer:
                         self.params, cache, jnp.asarray(toks[:, t : t + 1]), jnp.int32(t)
                     )
         return logits, cache, L
+
+    def _alias_share(self, cache, tok) -> float:
+        """Compile the step (its calls reuse this compile) and return the
+        share of the cache's bytes that its output aliases."""
+        compiled = self._decode.lower(
+            self.params, cache, jnp.asarray(tok), jnp.int32(0)
+        ).compile()
+        aliased = compiled.memory_analysis().alias_size_in_bytes
+        return aliased / sum(a.nbytes for a in jax.tree.leaves(cache))
 
     def run_wave(self, wave: List[Request]) -> int:
         """Prefill + decode one wave to completion. Returns decode steps."""

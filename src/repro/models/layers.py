@@ -104,28 +104,31 @@ def _auto_q_chunk(B: int, Sq: int, Skv: int, hq: int,
 
 def mha(
     q: jnp.ndarray,  # (b, Sq, hq, d)
-    k: jnp.ndarray,  # (b, Skv, hkv, d)
-    v: jnp.ndarray,  # (b, Skv, hkv, dv)
+    k: jnp.ndarray,  # (b, Skv, hkv, d), or (b, hkv, Skv, d) if kv_heads_major
+    v: jnp.ndarray,  # (b, Skv, hkv, dv), or (b, hkv, Skv, dv) if kv_heads_major
     *,
     causal: bool,
     q_offset=0,  # int or scalar array: global position of q[0]
     kv_len: Optional[jnp.ndarray] = None,  # valid cache length (decode)
     q_chunk: Optional[int] = None,
     sm_scale: Optional[float] = None,
+    kv_heads_major: bool = False,  # k/v in the decode cache's layout
 ) -> jnp.ndarray:
     b, Sq, hq, d = q.shape
-    _, Skv, hkv, dv = v.shape
-    g = hq // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    if q_chunk is None:
-        q_chunk = _auto_q_chunk(b, Sq, Skv, hq)
     from repro import kernels as _k
     if _k.pallas_enabled():
         from repro.kernels.flash_attention import flash_attention
         return flash_attention(
             q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-            sm_scale=scale,
+            sm_scale=scale, kv_heads_major=kv_heads_major,
         )
+    if kv_heads_major:
+        k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+    _, Skv, hkv, dv = v.shape
+    g = hq // hkv
+    if q_chunk is None:
+        q_chunk = _auto_q_chunk(b, Sq, Skv, hq)
     # GQA: repeat KV heads to hq so the head axis stays flat and GSPMD can
     # shard it over 'model' even when hkv < mesh size (e.g. starcoder2 kv=4
     # on a 16-way TP axis). The repeat is sharded and cheap; the Pallas
@@ -166,6 +169,28 @@ def mha(
 
 
 # --------------------------------------------------------------------- #
+# decode cache writes
+# --------------------------------------------------------------------- #
+def cache_write(c: jnp.ndarray, new: jnp.ndarray, pos, seq_axis: int,
+                layer=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Write ``new`` into the cache leaf ``c`` at ``pos`` along its sequence
+    axis; returns (updated leaf, this layer's cache). ``c`` is one layer's
+    leaf, or with ``layer`` the stack of every scanned layer's leaves, whose
+    leading axis is the layer: the token is then written into the stack,
+    which the donated decode step updates in place, and the layer is read
+    out of it."""
+    new = new.astype(c.dtype)
+    idx = [0] * c.ndim
+    if layer is None:
+        idx[seq_axis] = pos
+        c = jax.lax.dynamic_update_slice(c, new, idx)
+        return c, c
+    idx[0], idx[seq_axis + 1] = layer, pos
+    c = jax.lax.dynamic_update_slice(c, new[None], idx)
+    return c, jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+
+
+# --------------------------------------------------------------------- #
 # GQA attention layer (with optional qk-norm, bias, KV cache)
 # --------------------------------------------------------------------- #
 def init_attention(key, cfg: ModelConfig) -> Params:
@@ -188,8 +213,9 @@ def attention_apply(
     cfg: ModelConfig,
     x: jnp.ndarray,  # (b, S, d)
     positions: jnp.ndarray,  # (S,) global positions of x
-    cache: Optional[Params] = None,  # {"k","v"}: (b, Smax, hkv, hd); decode only
+    cache: Optional[Params] = None,  # {"k","v"}: (b, hkv, Smax, hd); decode only
     cache_len=None,  # filled length of the cache before this call
+    layer=None,  # cache leaves stacked over layers: this layer's index
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
     b, S, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -205,12 +231,13 @@ def attention_apply(
         k = apply_rope(k, cos, sin)
     new_cache = None
     if cache is not None:
-        # decode: write new k/v at cache_len, attend over the whole cache
-        ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, cache_len, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, cache_len, 0, 0))
+        # decode: write new k/v at cache_len, attend over the whole cache,
+        # which is kept in the flash kernel's (b, hkv, S, hd) layout
+        ck, k_all = cache_write(cache["k"], jnp.swapaxes(k, 1, 2), cache_len, 2, layer)
+        cv, v_all = cache_write(cache["v"], jnp.swapaxes(v, 1, 2), cache_len, 2, layer)
         new_cache = {"k": ck, "v": cv}
-        out = mha(q, ck, cv, causal=False, q_offset=cache_len,
-                  kv_len=cache_len + S)
+        out = mha(q, k_all, v_all, causal=False, q_offset=cache_len,
+                  kv_len=cache_len + S, kv_heads_major=True)
     else:
         out = mha(q, k, v, causal=not cfg.encoder_only, q_offset=0)
     y = dense(p["wo"], out.reshape(b, S, hq * hd))
@@ -240,6 +267,7 @@ def mla_apply(
     positions: jnp.ndarray,
     cache: Optional[Params] = None,  # {"ckv": (b,Smax,r), "krope": (b,Smax,dr)}
     cache_len=None,
+    layer=None,  # as in attention_apply
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
     b, S, d = x.shape
     h = cfg.n_heads
@@ -255,10 +283,9 @@ def mla_apply(
 
     new_cache = None
     if cache is not None:
-        ckv = jax.lax.dynamic_update_slice(cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, cache_len, 0))
-        kr = jax.lax.dynamic_update_slice(
-            cache["krope"], k_rope.reshape(b, S, dr).astype(cache["krope"].dtype), (0, cache_len, 0))
-        new_cache = {"ckv": ckv, "krope": kr}
+        c_ckv, ckv = cache_write(cache["ckv"], ckv, cache_len, 1, layer)
+        c_kr, kr = cache_write(cache["krope"], k_rope.reshape(b, S, dr), cache_len, 1, layer)
+        new_cache = {"ckv": c_ckv, "krope": c_kr}
         k_rope = kr.reshape(b, -1, 1, dr)
         kv_len = cache_len + S
         q_offset = cache_len

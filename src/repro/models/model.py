@@ -138,12 +138,16 @@ def apply_block(
     positions: jnp.ndarray,
     cache: Optional[Params],
     cache_len,
+    layer=None,
 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
+    """One block. In decode, ``cache`` is this block's cache, or with
+    ``layer`` its cache stacked over the scanned units: the block then
+    updates its own entry of the stack and returns the whole stack."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "attn":
         h = rms_norm(x, p["ln1"], cfg.rms_eps)
         fn = mla_apply if cfg.use_mla else attention_apply
-        a, new_cache = fn(p["attn"], cfg, h, positions, cache, cache_len)
+        a, new_cache = fn(p["attn"], cfg, h, positions, cache, cache_len, layer)
         x = x + checkpoint_name(a, "block_out")
         if "moe" in p:
             h2 = rms_norm(x, p["ln2"], cfg.rms_eps)
@@ -163,14 +167,23 @@ def apply_block(
             x = x + checkpoint_name(mlp_apply(p["ffn"], cfg, h2), "block_out")
         return x, new_cache, aux
     h = rms_norm(x, p["ln"], cfg.rms_eps)
+    stacked = cache is not None and layer is not None
+    state = (
+        jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), cache)
+        if stacked else cache
+    )
     if kind == "mamba2":
-        y, new_cache = ssm_mod.mamba2_apply(p["core"], cfg, h, cache)
+        y, new_cache = ssm_mod.mamba2_apply(p["core"], cfg, h, state)
     elif kind == "mlstm":
-        y, new_cache = ssm_mod.mlstm_apply(p["core"], cfg, h, cache)
+        y, new_cache = ssm_mod.mlstm_apply(p["core"], cfg, h, state)
     elif kind == "slstm":
-        y, new_cache = ssm_mod.slstm_apply(p["core"], cfg, h, cache)
+        y, new_cache = ssm_mod.slstm_apply(p["core"], cfg, h, state)
     else:
         raise ValueError(kind)
+    if stacked:  # the recurrent state is small: replace the layer's entry
+        new_cache = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0), cache, new_cache
+        )
     return x + checkpoint_name(y, "block_out"), new_cache, aux
 
 
@@ -282,9 +295,10 @@ def _init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int) -> 
                 "ckv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), DTYPE),
                 "krope": jnp.zeros((batch, max_len, cfg.rope_head_dim), DTYPE),
             }
+        # (b, hkv, S, hd): the layout the flash kernel reads
         return {
-            "k": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.head_dim), DTYPE),
-            "v": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.head_dim), DTYPE),
+            "k": jnp.zeros((batch, cfg.n_kv_heads, max_len, cfg.head_dim), DTYPE),
+            "v": jnp.zeros((batch, cfg.n_kv_heads, max_len, cfg.head_dim), DTYPE),
         }
     if kind == "mamba2":
         return ssm_mod.init_mamba2_cache(cfg, batch)
@@ -336,17 +350,24 @@ def decode_step(
             new_prefix.append(nc)
         new_cache["prefix"] = new_prefix
 
-    def unit_fn(x, pu_cu):
-        pu, cu = pu_cu
-        ncs = {}
+    # the stacked unit cache is the scan's carry: each layer writes its new
+    # token into the stack, which the donated serving step updates in place.
+    # Scanned as an input with a new stack as output, the whole cache would
+    # be sliced out and restacked every step
+    def unit_fn(carry, pu_l):
+        x, cu = carry
+        pu, l = pu_l
+        cu = dict(cu)
         for i, kind in enumerate(cfg.block_pattern):
-            x, nc, _ = apply_block(cfg, kind, pu[f"b{i}"], x, positions, cu[f"b{i}"], pos)
-            ncs[f"b{i}"] = nc
-        return x, ncs
+            x, cu[f"b{i}"], _ = apply_block(
+                cfg, kind, pu[f"b{i}"], x, positions, cu[f"b{i}"], pos, layer=l
+            )
+        return (x, cu), None
 
-    x, new_units = jax.lax.scan(
-        unit_fn, x, (params["units"], cache["units"]), unroll=_SCAN_UNROLL
+    n_units = jax.tree.leaves(params["units"])[0].shape[0]
+    (x, new_cache["units"]), _ = jax.lax.scan(
+        unit_fn, (x, cache["units"]), (params["units"], jnp.arange(n_units)),
+        unroll=_SCAN_UNROLL,
     )
-    new_cache["units"] = new_units
     logits = lm_logits(cfg, params, x)
     return logits[:, 0], new_cache

@@ -248,12 +248,14 @@ def cache_specs(cache_shape, cfg: ModelConfig, mesh: Mesh,
         # batch-1 long-context decode: the batch axis cannot shard, so the
         # cache SEQUENCE axis takes the dp axes instead (sequence parallelism
         # over the ring) -- this is what keeps the 500k cells per-chip small
-        seq_dp = None if bdp else _maybe_dp(dp, body[1] if len(body) > 1 else 0, sizes)
+        s_ax = 2 if name in ("k", "v") else 1
+        seq_dp = None if bdp else _maybe_dp(dp, body[s_ax] if len(body) > s_ax else 0, sizes)
         if name in ("k", "v"):
-            # (b, S, hkv, hd): heads over model if divisible, else sequence
-            if rules.shard_cache_heads and body[2] % sizes.get(tp, 1) == 0:
-                return wrap(bdp, seq_dp, tp, None)
-            return wrap(bdp, seq_dp or _maybe(tp, body[1], sizes), None, None)
+            # (b, hkv, S, hd), the flash kernel's layout: heads over model if
+            # divisible, else sequence
+            if rules.shard_cache_heads and body[1] % sizes.get(tp, 1) == 0:
+                return wrap(bdp, tp, seq_dp, None)
+            return wrap(bdp, None, seq_dp or _maybe(tp, body[2], sizes), None)
         if name in ("ckv", "krope"):
             return wrap(bdp, seq_dp or _maybe(tp, body[1], sizes), None)
         if name in ("conv", "conv_x", "conv_B", "conv_C"):

@@ -77,15 +77,15 @@ def test_cache_specs_head_vs_sequence_fallback():
     # qwen3: kv=8 NOT divisible by 16 -> sequence-sharded over model
     cfg = get_config("qwen3-0.6b")
     cs = jax.eval_shape(lambda: {"units": {"b0": {
-        "k": jnp.zeros((8, 128, 32768, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)}}})
+        "k": jnp.zeros((8, 128, cfg.n_kv_heads, 32768, cfg.head_dim), jnp.bfloat16)}}})
     sp = cache_specs(cs, cfg, MESH, rules)["units"]["b0"]["k"]
-    assert sp[3] is None and sp[2] == "model"
+    assert sp[2] is None and sp[3] == "model"
     # codeqwen kv=32 divisible -> head-sharded
     cfg2 = get_config("codeqwen1.5-7b")
     cs2 = jax.eval_shape(lambda: {"units": {"b0": {
-        "k": jnp.zeros((8, 128, 32768, cfg2.n_kv_heads, cfg2.head_dim), jnp.bfloat16)}}})
+        "k": jnp.zeros((8, 128, cfg2.n_kv_heads, 32768, cfg2.head_dim), jnp.bfloat16)}}})
     sp2 = cache_specs(cs2, cfg2, MESH, rules)["units"]["b0"]["k"]
-    assert sp2[3] == "model"
+    assert sp2[2] == "model"
     assert sp2[1] == ("pod", "data")  # batch 128 shardable
 
 
@@ -93,10 +93,10 @@ def test_cache_specs_batch1_seq_over_dp():
     """long_500k: batch axis unshardable -> cache sequence takes dp axes."""
     cfg = get_config("zamba2-2.7b")
     cs = jax.eval_shape(lambda: {"units": {"b0": {
-        "k": jnp.zeros((7, 1, 524288, 32, 80), jnp.bfloat16)}}})
+        "k": jnp.zeros((7, 1, 32, 524288, 80), jnp.bfloat16)}}})
     sp = cache_specs(cs, cfg, MESH, ShardingRules())["units"]["b0"]["k"]
     assert sp[1] is None
-    assert sp[2] == ("pod", "data")
+    assert sp[3] == ("pod", "data")
 
 
 def test_input_specs_struct_only():

@@ -139,3 +139,49 @@ def test_shape_generic_engine_core_compiles_under_x64(one_chip):
     latency = out[2]
     assert latency.shape == (B,) and latency.dtype == jnp.float64
     assert "f64" in compiled.as_text()
+
+
+def test_serve_step_updates_the_full_width_cache_in_place(one_chip):
+    """qwen3-0.6b's donated serve step at the serving cell's widths (32
+    slots, max_len 1024, Pallas on): its output aliases the whole cache, it
+    needs no cache-sized temporary, and nothing but an in-place
+    dynamic-update-slice writes a whole stacked cache leaf."""
+    import re
+
+    from repro import kernels
+    from repro.launch.steps import make_serve_step
+    from repro.models import init_cache, init_params
+
+    cfg = get_config("qwen3-0.6b")
+    B, max_len = 32, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, B, max_len)))
+    tok = _spec((B, 1), jnp.int32, one_chip)
+    pos = _spec((), jnp.int32, one_chip)
+    prior = kernels.pallas_enabled(), kernels.interpret_default()
+    kernels.enable_pallas(True, interpret=False)
+    try:
+        step = jax.jit(make_serve_step(cfg), donate_argnums=(1,))
+        compiled = step.lower(params, cache, tok, pos).compile()
+    finally:
+        kernels.enable_pallas(prior[0], interpret=prior[1])
+    stats = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert stats.alias_size_in_bytes == cache_bytes
+    assert stats.temp_size_in_bytes < 150e6
+
+    leaf = jax.tree.leaves(cache["units"])[0]
+    shape = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    writers = re.findall(
+        r"%\S+ = " + re.escape(shape) + r"\{[^}]*\} ([\w-]+)\(", text
+    )
+    assert "dynamic-update-slice" in writers
+    # parameters and loop-tuple elements pass the leaf through; nothing
+    # else (a copy, a fusion restacking it) may produce it
+    assert set(writers) <= {"parameter", "get-tuple-element", "dynamic-update-slice"}

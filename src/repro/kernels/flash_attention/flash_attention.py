@@ -13,19 +13,87 @@ Causality is handled two ways, in Union mapping terms both at the C2
 grid level: fully-masked KV blocks are skipped via ``pl.when`` (no MXU
 work), and only diagonal blocks pay the element mask. A (1,1) SMEM
 ``kv_len`` input masks the valid cache prefix for decode.
+
+Each call records, as it is built (at trace time, never per step), the
+bytes its grid and BlockSpecs move between HBM and VMEM: the pipeline
+copies a block in at the first grid step and again at each step whose
+block index differs from the step before, and writes an output block back
+likewise. ``kernel_record()`` returns them by call shape.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+import threading
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+class FlashCall(NamedTuple):
+    """The shape of one ``union_flash_attention`` call (padded lengths)."""
+
+    batch: int
+    q_heads: int
+    kv_heads: int
+    q_seq: int
+    kv_seq: int
+    head_dim: int
+    bq: int
+    bk: int
+
+
+_RECORD_LOCK = threading.Lock()
+_RECORD: Dict[FlashCall, Dict[str, int]] = {}
+
+
+def kernel_record() -> Dict[FlashCall, Dict[str, int]]:
+    """Bytes one call moves, by call shape: ``q``, ``k``, ``v`` copied in
+    and ``o`` written back, for every call shape built in this process."""
+    with _RECORD_LOCK:
+        return {k: dict(v) for k, v in _RECORD.items()}
+
+
+def reset_kernel_record() -> None:
+    with _RECORD_LOCK:
+        _RECORD.clear()
+
+
+def block_copies(grid: Tuple[int, ...], index_map, chunk: int = 1 << 20) -> int:
+    """Copies of a BlockSpec's blocks over ``grid``: one at the first grid
+    step and one at each step whose block index differs from the step
+    before (steps in row-major order, the last axis innermost). The index
+    map is evaluated with numpy over the grid, ``chunk`` steps at a time."""
+    n = math.prod(grid)
+    copies, prev = 0, None
+    for start in range(0, n, chunk):
+        steps = np.arange(start, min(n, start + chunk))
+        idx = np.stack([np.broadcast_to(np.asarray(x), steps.shape)
+                        for x in index_map(*np.unravel_index(steps, grid))])
+        if prev is None:  # block indices are never negative: the first step copies
+            prev = np.full_like(idx[:, :1], -1)
+        copies += int(np.any(np.diff(idx, axis=1, prepend=prev) != 0, axis=0).sum())
+        prev = idx[:, -1:]
+    return copies
+
+
+def _record(call: FlashCall, grid, specs) -> None:
+    """Record the bytes moved per call for ``specs``: {name: (BlockSpec,
+    dtype)}."""
+    moved = {
+        name: block_copies(grid, spec.index_map)
+        * math.prod(spec.block_shape) * jnp.dtype(dtype).itemsize
+        for name, (spec, dtype) in specs.items()
+    }
+    with _RECORD_LOCK:
+        _RECORD[call] = moved
 
 
 def _fa_kernel(
@@ -134,16 +202,18 @@ def flash_attention_pallas(
         bk=bk,
         n_kv=grid[3],
     )
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
+    k_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // group, j, 0))
+    v_spec = pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j: (b, h // group, j, 0))
+    o_spec = pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0))
+    _record(FlashCall(B, Hq, Hkv, Sq, Skv, D, bq, bk), grid,
+            {"q": (q_spec, q.dtype), "k": (k_spec, k.dtype),
+             "v": (v_spec, v.dtype), "o": (o_spec, q.dtype)})
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j: (b, h // group, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, k_spec, v_spec],
+        out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),

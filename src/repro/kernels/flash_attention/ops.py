@@ -35,7 +35,11 @@ from repro.codesign import (
 )
 from repro.core.constraints import mxu_aligned
 from repro.core.problem import Problem
-from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention.flash_attention import (  # noqa: F401
+    flash_attention_pallas,
+    kernel_record,
+    reset_kernel_record,
+)
 from repro.kernels.flash_attention.ref import attention_ref
 
 

@@ -1,6 +1,8 @@
 """Sharding rules: divisibility guards, family-specific layouts, and the
 Union-mapping <-> PartitionSpec correspondence."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,13 +82,20 @@ def test_cache_specs_head_vs_sequence_fallback():
         "k": jnp.zeros((8, 128, cfg.n_kv_heads, 32768, cfg.head_dim), jnp.bfloat16)}}})
     sp = cache_specs(cs, cfg, MESH, rules)["units"]["b0"]["k"]
     assert sp[2] is None and sp[3] == "model"
-    # codeqwen kv=32 divisible -> head-sharded
-    cfg2 = get_config("codeqwen1.5-7b")
+    # an MHA-shaped kv=32 (divisible by 16) -> head-sharded
+    cfg2 = dataclasses.replace(get_config("codeqwen1.5-7b"), n_kv_heads=32)
     cs2 = jax.eval_shape(lambda: {"units": {"b0": {
         "k": jnp.zeros((8, 128, cfg2.n_kv_heads, 32768, cfg2.head_dim), jnp.bfloat16)}}})
     sp2 = cache_specs(cs2, cfg2, MESH, rules)["units"]["b0"]["k"]
     assert sp2[2] == "model"
     assert sp2[1] == ("pod", "data")  # batch 128 shardable
+    # codeqwen as published: kv=4 NOT divisible by 16 -> sequence-sharded
+    cfg3 = get_config("codeqwen1.5-7b")
+    assert cfg3.n_kv_heads == 4
+    cs3 = jax.eval_shape(lambda: {"units": {"b0": {
+        "k": jnp.zeros((8, 128, cfg3.n_kv_heads, 32768, cfg3.head_dim), jnp.bfloat16)}}})
+    sp3 = cache_specs(cs3, cfg3, MESH, rules)["units"]["b0"]["k"]
+    assert sp3[2] is None and sp3[3] == "model"
 
 
 def test_cache_specs_batch1_seq_over_dp():

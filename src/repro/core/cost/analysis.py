@@ -132,6 +132,17 @@ def x64():
     return jax.enable_x64(True)
 
 
+def host_pull(tree):
+    """Host copy of a dispatch's device results in ONE blocking transfer.
+
+    ``jax.device_get`` starts the copy of every leaf before it waits, so
+    the outputs of one dispatch come back in one round trip, not one per
+    array; each point that reads device results pulls through here once."""
+    import jax
+
+    return jax.device_get(tree)
+
+
 def require_float64(arr: np.ndarray) -> np.ndarray:
     """Host copy of a fused core's output, checked to be float64: float32
     output cannot honour the bit-identity contract, so it raises."""
@@ -1027,7 +1038,7 @@ class AnalysisContext:
                 out = self._jax_batch_core(tt, st, perm)
             if self._jax_core_donates and select is None:
                 sb.dev = None  # donated away; re-upload on next use
-            out = jax.tree_util.tree_map(np.asarray, out)
+            out = host_pull(out)
         except device_faults():
             self._jax_failed = True
             return None
@@ -1413,7 +1424,7 @@ class AnalysisContext:
                 self.jax_dispatches += 1
                 _record_trace(("ctx-lb", id(self)), int(tt.shape[0]))
                 cyc, en, mx = self._jax_lb_core(tt, st, perm)
-            cyc, en, mx = np.asarray(cyc), np.asarray(en), np.asarray(mx)
+            cyc, en, mx = host_pull((cyc, en, mx))
         except device_faults():
             self._jax_failed = True
             return None
@@ -2189,18 +2200,18 @@ class GenericFusedRunner:
 
 
 def _fused_to_host(out, B: int):
-    """Host copy of one fused dispatch's outputs, cut to the ``B`` real
-    rows: ``(admit, lb_guard, latency, energy, util, score_guard,
-    extras)``."""
-    admit, lb_mx, latency, energy, util, score_mx, extras = out
+    """Host copy of one fused dispatch's outputs in one pull, cut to the
+    ``B`` real rows: ``(admit, lb_guard, latency, energy, util,
+    score_guard, extras)``."""
+    admit, lb_mx, latency, energy, util, score_mx, extras = host_pull(out)
     return (
-        np.asarray(admit)[:B],
-        float(np.asarray(lb_mx)),
+        admit[:B],
+        float(lb_mx),
         require_float64(latency)[:B],
-        np.asarray(energy)[:B],
-        np.asarray(util)[:B],
-        float(np.asarray(score_mx)),
-        {k: np.asarray(v)[:B] for k, v in extras.items()},
+        energy[:B],
+        util[:B],
+        float(score_mx),
+        {k: v[:B] for k, v in extras.items()},
     )
 
 

@@ -162,6 +162,10 @@ class EngineStats:
     # loops (one per mega-batch precompute / deferred-generation flush);
     # stays 0 on the host-loop paths.
     device_syncs: int = 0
+    # blocking reads of fused-dispatch results to the host (one per
+    # host-loop fused batch, device-loop window, GA generation and GA
+    # flush): each read pulls all of its outputs in one transfer.
+    host_pulls: int = 0
     # wall-clock of the batch paths' ``union.map.admit`` and
     # ``union.map.materialize`` spans (``repro.runtime.spans``), and of
     # the per-candidate bound and scoring in ``evaluate_admit``
@@ -890,6 +894,7 @@ class EvaluationEngine:
             if out is None:
                 self._fused_failed = True  # jax broke: stop trying
                 return _FusedOutcome(False, None, None, sb, None)
+            self.stats.host_pulls += 1
             admit, lb_mx, latency, energy, util, score_mx, extras = out
             if not (lb_mx < BATCH_EXACT_LIMIT):
                 return _FusedOutcome(False, None, None, sb, None)

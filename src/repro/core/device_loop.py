@@ -37,6 +37,11 @@ is unavailable (numpy backend, no generic terms, a device fault
 mid-flight) or an exactness guard trips -- callers fall through to the unchanged
 per-batch path, and results are identical either way.
 
+Each point that reads device results (a window, a generation's guards
+and fitness, a flush) pulls them in ONE transfer
+(:func:`~repro.core.cost.analysis.host_pull`), counted in
+``stats.host_pulls``.
+
 Env knobs: ``UNION_DEVICE_LOOP=0`` disables the device loops wholesale;
 ``UNION_DEVICE_K`` sets the sync cadence (default 8 batches/generations
 per host sync).
@@ -55,6 +60,7 @@ from repro.core.cost.analysis import (
     StackedBatch,
     device_faults,
     global_trace_count,
+    host_pull,
     require_float64,
 )
 from repro.core.cost.engine import EvaluationEngine, PrecomputedScores
@@ -104,22 +110,19 @@ def _precompute_runner(engine: EvaluationEngine):
 
 
 def _materialize(out, B: int) -> Optional[PrecomputedScores]:
-    """Host :class:`PrecomputedScores` from one raw (possibly padded)
-    device output tuple, or None when exactness cannot be honoured."""
+    """Host :class:`PrecomputedScores` from one pulled (possibly padded)
+    output tuple, or None when exactness cannot be honoured."""
     _admit, lb_mx, latency, energy, util, score_mx, extras = out
-    if not (
-        float(np.asarray(lb_mx)) < BATCH_EXACT_LIMIT
-        and float(np.asarray(score_mx)) < BATCH_EXACT_LIMIT
-    ):
+    if not (float(lb_mx) < BATCH_EXACT_LIMIT and float(score_mx) < BATCH_EXACT_LIMIT):
         return None
     latency = require_float64(latency)
-    extras_h = {k: np.asarray(v)[:B] for k, v in extras.items()}
+    extras_h = {k: v[:B] for k, v in extras.items()}
     return PrecomputedScores(
         extras_h["lb_cycles"],
         extras_h["lb_energy"],
         latency[:B],
-        np.asarray(energy)[:B],
-        np.asarray(util)[:B],
+        energy[:B],
+        util[:B],
         extras_h,
     )
 
@@ -134,7 +137,8 @@ def device_precompute(
     The dispatch runs with ``incumbent=inf`` (every row scored); the
     engine replays admission per batch against the then-current incumbent
     from the returned bound arrays, which equals the per-batch decision
-    bit for bit. One host sync per window (``stats.device_syncs``)."""
+    bit for bit. One host sync per window (``stats.device_syncs``), and
+    one pull of all its results (``stats.host_pulls``)."""
     runner = _precompute_runner(engine)
     if runner is None or not batches:
         return None
@@ -153,18 +157,11 @@ def device_precompute(
             engine.stats.n_traces += global_trace_count() - before
     if out is None:
         return None
-    _admit, lb_mx, latency, energy, util, score_mx, extras = out
-    if not (lb_mx < BATCH_EXACT_LIMIT and score_mx < BATCH_EXACT_LIMIT):
+    engine.stats.host_pulls += 1
+    whole = _materialize(out, total)
+    if whole is None:
         return None
     engine.stats.device_syncs += 1
-    whole = PrecomputedScores(
-        extras["lb_cycles"][:total],
-        extras["lb_energy"][:total],
-        latency[:total],
-        energy[:total],
-        util[:total],
-        {k: v[:total] for k, v in extras.items()},
-    )
     views: List[PrecomputedScores] = []
     off = 0
     for gb in batches:
@@ -178,9 +175,10 @@ class DeviceGAScorer:
 
     ``score(gb)`` dispatches one generation and returns its float64
     fitness vector (the engine metric, scalarized on device) -- the only
-    host transfer is that vector plus two guard scalars. The full device
-    results are buffered; every :func:`sync_cadence` generations (and at
-    :meth:`flush`) they are materialized and replayed IN ORDER through
+    host transfer is that vector plus two guard scalars, pulled together.
+    The full device results are buffered; every :func:`sync_cadence`
+    generations (and at :meth:`flush`) they are pulled in one transfer,
+    materialized and replayed IN ORDER through
     ``engine.evaluate_batch(gb, precomputed=...)``, with ``on_costs(gb,
     costs)`` invoked per generation so the caller's incumbent tracking
     sees the exact host-loop offer sequence.
@@ -237,30 +235,35 @@ class DeviceGAScorer:
             self._engine.stats.n_traces += global_trace_count() - before
         if out is None:
             return None, None
+        _admit, lb_mx, _lat, _en, _ut, score_mx, extras = out
         try:
-            _admit, lb_mx, _lat, _en, _ut, score_mx, extras = out
-            # guards + fitness are the ONLY per-generation host transfers
-            if not (
-                float(np.asarray(lb_mx)) < BATCH_EXACT_LIMIT
-                and float(np.asarray(score_mx)) < BATCH_EXACT_LIMIT
-            ):
-                return out, None
-            return out, require_float64(extras["metric_score"])[: len(gb)]
+            # guards + fitness: the generation's ONE host transfer
+            lb_mx, score_mx, fitness = host_pull(
+                (lb_mx, score_mx, extras["metric_score"])
+            )
         except device_faults():
             return out, None
+        self._engine.stats.host_pulls += 1
+        if not (
+            float(lb_mx) < BATCH_EXACT_LIMIT and float(score_mx) < BATCH_EXACT_LIMIT
+        ):
+            return out, None
+        return out, require_float64(fitness)[: len(gb)]
 
     def flush(self) -> None:
         """Materialize and replay every buffered generation, in order.
-        One host sync for the whole buffer."""
+        One host sync and one pull for the whole buffer."""
         if not self._buf:
             return
         buf, self._buf = self._buf, []
         self._engine.stats.device_syncs += 1
         with span("map.sync"):
-            for gb, out in buf:
-                try:
-                    pre = _materialize(out, len(gb))
-                except device_faults():
-                    pre = None  # device buffers gone (device fault): re-evaluate
+            try:
+                pulled = host_pull([out for _gb, out in buf])
+                self._engine.stats.host_pulls += 1
+            except device_faults():
+                pulled = [None] * len(buf)  # device buffers gone: re-evaluate
+            for (gb, _), out in zip(buf, pulled):
+                pre = None if out is None else _materialize(out, len(gb))
                 costs = self._engine.evaluate_batch(gb, precomputed=pre)
                 self._on_costs(gb, costs)

@@ -55,6 +55,9 @@ class SearchResult:
     # host<->device sync points of the device-resident search loops (one
     # per mega-batch precompute / K-generation flush; 0 on host loops)
     device_syncs: int = 0
+    # blocking reads of fused-dispatch results to the host, one transfer
+    # each (the device loops make one per sync point and GA generation)
+    host_pulls: int = 0
     admit_s: float = 0.0  # engine wall-clock in the admission (bound) stage
     score_s: float = 0.0  # engine wall-clock scoring admitted misses
 
@@ -120,6 +123,7 @@ class SearchResult:
             "backend_fallbacks": self.backend_fallbacks,
             "n_traces": self.n_traces,
             "device_syncs": self.device_syncs,
+            "host_pulls": self.host_pulls,
             "elapsed_s": round(self.elapsed_s, 4),
             "evals_per_s": round(self.evals_per_s, 1),
             "admit_s": round(self.admit_s, 4),
@@ -248,6 +252,7 @@ class _Tracker:
             backend_fallbacks=delta("backend_fallbacks"),
             n_traces=delta("n_traces"),
             device_syncs=delta("device_syncs"),
+            host_pulls=delta("host_pulls"),
             admit_s=delta("admit_s", 0.0),
             score_s=delta("score_s", 0.0),
         )

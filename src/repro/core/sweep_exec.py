@@ -238,6 +238,7 @@ def result_to_record(res: SearchResult) -> dict:
             "backend_fallbacks": res.backend_fallbacks,
             "n_traces": res.n_traces,
             "device_syncs": res.device_syncs,
+            "host_pulls": res.host_pulls,
             "admit_s": res.admit_s,
             "score_s": res.score_s,
         },
@@ -263,6 +264,7 @@ def result_from_record(rec: dict) -> SearchResult:
         backend_fallbacks=int(c.get("backend_fallbacks", 0)),
         n_traces=int(c.get("n_traces", 0)),
         device_syncs=int(c.get("device_syncs", 0)),
+        host_pulls=int(c.get("host_pulls", 0)),
         admit_s=float(c["admit_s"]),
         score_s=float(c["score_s"]),
     )
@@ -761,6 +763,7 @@ class SweepExecutor:
             "fused_dispatches": sum(r.fused_dispatches for r in results),
             "n_traces": sum(r.n_traces for r in results),
             "device_syncs": sum(r.device_syncs for r in results),
+            "host_pulls": sum(r.host_pulls for r in results),
             "elapsed_s": round(sum(r.elapsed_s for r in results), 4),
             # robustness ledger
             "workers": self.workers,

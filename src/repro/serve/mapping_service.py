@@ -296,6 +296,7 @@ def _merge_results(a: Optional[SearchResult], b: Optional[SearchResult],
         backend_fallbacks=a.backend_fallbacks + b.backend_fallbacks,
         n_traces=a.n_traces + b.n_traces,
         device_syncs=a.device_syncs + b.device_syncs,
+        host_pulls=a.host_pulls + b.host_pulls,
         admit_s=a.admit_s + b.admit_s,
         score_s=a.score_s + b.score_s,
     )

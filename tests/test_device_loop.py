@@ -211,3 +211,135 @@ def test_genetic_device_fitness_is_engine_metric(monkeypatch):
     host = np.asarray([c.metric("edp") for c in costs], dtype=np.float64)
     assert np.array_equal(fitness, host)
     assert eng.stats.device_syncs == 1
+
+
+# ------------------------------------------------------------------ #
+# one host pull per read point (jax)
+# ------------------------------------------------------------------ #
+
+
+def _count_device_gets(monkeypatch, fail_from=None):
+    """Spy on ``jax.device_get``, the one transfer every read point makes:
+    returns the call counter; from call ``fail_from`` on, every pull
+    raises the device's runtime error (the device is gone)."""
+    import jax
+
+    real = jax.device_get
+    calls = [0]
+
+    def spy(tree):
+        calls[0] += 1
+        if fail_from is not None and calls[0] >= fail_from:
+            raise jax.errors.JaxRuntimeError("planted fault in the host pull")
+        return real(tree)
+
+    monkeypatch.setattr(jax, "device_get", spy)
+    return calls
+
+
+@pytest.mark.parametrize(
+    "mk, device_loop",
+    [
+        (lambda: RandomMapper(samples=320, seed=7, batch_size=32, patience=0), "1"),
+        (lambda: ExhaustiveMapper(max_mappings=200, batch_size=32), "1"),
+        (lambda: RandomMapper(samples=320, seed=7, batch_size=32, patience=0), "0"),
+    ],
+    ids=["random-device-loop", "exhaustive-device-loop", "random-host-loop"],
+)
+def test_one_pull_per_read_point(monkeypatch, mk, device_loop):
+    """Every read of fused-dispatch results is one ``jax.device_get``:
+    a device loop pulls once per sync, the host loop once per fused
+    batch, and ``host_pulls`` counts exactly those transfers."""
+    pytest.importorskip("jax")
+    monkeypatch.setenv("UNION_DEVICE_LOOP", device_loop)
+    monkeypatch.setenv("UNION_DEVICE_K", "3")
+    calls = _count_device_gets(monkeypatch)
+    res, eng = _run(mk(), "jax")
+    assert not eng._ctx._jax_failed
+    assert res.host_pulls == calls[0] > 0
+    assert res.stats_dict()["host_pulls"] == res.host_pulls
+    if device_loop == "1":
+        assert res.host_pulls == res.device_syncs
+    else:
+        assert res.device_syncs == 0
+        assert res.host_pulls == res.fused_dispatches
+
+
+@pytest.mark.parametrize("generations, k", [(1, 8), (7, 3), (8, 8)])
+def test_genetic_pulls_once_per_generation_and_flush(monkeypatch, generations, k):
+    """The GA scorer pulls guards and fitness together once a generation,
+    and every buffered generation's outputs together once a flush."""
+    pytest.importorskip("jax")
+    monkeypatch.setenv("UNION_DEVICE_LOOP", "1")
+    monkeypatch.setenv("UNION_DEVICE_K", str(k))
+    arch = cloud_accelerator()
+    eng = EvaluationEngine(TimeloopLikeModel(), GEMM, arch, metric="edp", backend="jax")
+    space = MapSpace(GEMM, arch)
+    rng = np.random.default_rng(11)
+    replayed = []
+    scorer = DeviceGAScorer(eng, lambda g, cs: replayed.append(len(cs)))
+    calls = _count_device_gets(monkeypatch)
+    sizes = [16 + 3 * g for g in range(generations)]
+    for n in sizes:
+        assert scorer.score(random_genome_batch(space, rng, n)) is not None
+    scorer.flush()
+    assert replayed == sizes
+    assert eng.stats.device_syncs == math.ceil(generations / k)
+    assert eng.stats.host_pulls == generations + eng.stats.device_syncs
+    assert calls[0] == eng.stats.host_pulls
+
+
+@pytest.mark.parametrize("read_point", ["device-precompute", "ga-score"])
+@pytest.mark.filterwarnings("ignore:Explicitly requested dtype float64")
+def test_float32_pull_raises(monkeypatch, read_point):
+    """float32 results still fail loudly through ``require_float64`` on
+    the pulled arrays; they are a defect, not a device fault."""
+    pytest.importorskip("jax")
+    import contextlib
+
+    from repro.core.cost import analysis
+
+    monkeypatch.setenv("UNION_DEVICE_LOOP", "1")
+    monkeypatch.setattr(analysis, "x64", contextlib.nullcontext)
+    # a problem no other test maps: the context's runner keeps the
+    # parameters it uploads, here in float32
+    problem = Problem.gemm(40, 24, 16, word_bytes=1, name="float32_pull")
+    arch = edge_accelerator()
+    eng = EvaluationEngine(
+        TimeloopLikeModel(), problem, arch, metric="edp", backend="jax"
+    )
+    gb = random_genome_batch(MapSpace(problem, arch), np.random.default_rng(2), 16)
+    with pytest.raises(RuntimeError, match="not float64"):
+        if read_point == "device-precompute":
+            device_precompute(eng, [gb, gb])
+        else:
+            DeviceGAScorer(eng, lambda g, cs: None).score(gb)
+    assert not eng._ctx._jax_failed
+
+
+@pytest.mark.parametrize("fail_from", [1, 2], ids=["first-pull", "second-pull"])
+@pytest.mark.parametrize(
+    "mk",
+    [
+        lambda: RandomMapper(samples=320, seed=7, batch_size=32, patience=0),
+        lambda: GeneticMapper(population=16, generations=8, seed=5),
+    ],
+    ids=["random", "genetic"],
+)
+def test_fault_in_the_pull_degrades_to_host(monkeypatch, mk, fail_from):
+    """A device fault raised by the single pull degrades the search to the
+    host path, which finds the same answer as a numpy run, bit for bit."""
+    pytest.importorskip("jax")
+    from repro.core.cost.analysis import get_context
+
+    monkeypatch.setenv("UNION_DEVICE_LOOP", "1")
+    monkeypatch.setenv("UNION_DEVICE_K", "3")
+    res_np, _ = _run(mk(), "numpy")
+    calls = _count_device_gets(monkeypatch, fail_from=fail_from)
+    try:
+        res, eng = _run(mk(), "jax")
+        assert calls[0] >= fail_from
+        assert eng.backend == "numpy" and res.backend_fallbacks == 1
+        _assert_results_equal(res, res_np, same_backend=False)
+    finally:
+        get_context(GEMM, cloud_accelerator())._jax_failed = False

@@ -47,15 +47,14 @@ from repro.core.problem import DataSpace, Problem
 BATCH_EXACT_LIMIT = float(1 << 52)
 
 # ---------------------------------------------------------------------- #
-# Process-global trace registry. Every jitted dispatch registers its
-# (program identity, padded batch size) combination here; the set's size
-# is therefore the number of DISTINCT XLA traces the process has paid
-# for. Shape-generic programs register under their structural
-# ShapeClassKey -- content-different contexts in one shape class share a
-# single entry per bucket -- while the legacy per-context programs
-# register under the context's identity (each context is its own trace).
-# Engines sample ``global_trace_count()`` deltas around their dispatches
-# to attribute traces to a search (``EngineStats.n_traces``).
+# Process-global program cache and trace registry. Every array program is
+# built once per shape class (see ``AnalysisContext.shape_class_key``) and
+# kept in ``_GENERIC_PROGRAMS``; every jitted dispatch registers its
+# (program key, padded batch size) combination in ``_TRACE_COMBOS``, whose
+# size is therefore the number of DISTINCT XLA traces the process has paid
+# for -- content-different contexts in one shape class share one entry per
+# bucket. Engines sample ``global_trace_count()`` deltas around their
+# dispatches to attribute traces to a search (``EngineStats.n_traces``).
 # ---------------------------------------------------------------------- #
 _GENERIC_PROGRAMS: Dict[tuple, object] = {}
 _TRACE_COMBOS: set = set()
@@ -63,8 +62,8 @@ _TRACE_COMBOS: set = set()
 
 def global_trace_count() -> int:
     """Number of distinct (program, padded batch size) jit traces this
-    process has dispatched (shape-generic programs count once per shape
-    class, not once per context)."""
+    process has dispatched (programs count once per shape class, not once
+    per context)."""
     return len(_TRACE_COMBOS)
 
 
@@ -156,28 +155,27 @@ def require_float64(arr: np.ndarray) -> np.ndarray:
 
 
 def reset_trace_registry() -> None:
-    """Drop trace accounting AND the shared generic-program cache (test
-    isolation helper; compiled programs are rebuilt on demand)."""
+    """Drop trace accounting AND the shared program cache (test isolation
+    helper; programs are rebuilt on demand)."""
     _TRACE_COMBOS.clear()
     _GENERIC_PROGRAMS.clear()
 
 
 def exact_divisor(xp, v):
-    """A host constant to DIVIDE by inside a traced array program.
+    """A parameter-pack value to DIVIDE by inside an array program.
 
-    numpy returns the plain value. Under a jax trace the constant is
-    wrapped in an optimization barrier so XLA's simplifier cannot fold
-    ``x / c`` into ``x * (1/c)`` -- that rewrite is exact only for powers
-    of two and would break bit-identity with the host numpy division for
-    every other bandwidth/frequency/PE-count constant.
+    numpy returns the plain value. Under a jax trace the value is wrapped
+    in an optimization barrier so XLA's simplifier cannot fold ``x / c``
+    into ``x * (1/c)`` -- that rewrite is exact only for powers of two and
+    would break bit-identity with the host numpy division for every other
+    bandwidth/frequency/PE-count value.
     """
     if xp is np:
         return v
     from jax import lax
 
-    # asarray (not the float64 constructor) so TRACED scalars -- the
-    # shape-generic cores divide by parameter values -- pass through the
-    # barrier unchanged; host constants take the same asarray path.
+    # asarray (not the float64 constructor) so traced scalars pass through
+    # the barrier unchanged
     return lax.optimization_barrier(xp.asarray(v, dtype=xp.float64))
 
 
@@ -237,24 +235,25 @@ def ordered_pair_sum(xp, init, pairs):
     return out
 
 
-def batch_projection_footprint(axes, ttf_lvl, xp=np):
-    """Batched data-space footprint over one level's tile rows.
+def batch_projection_footprint(axes, ttf_lvl, xp, coeffs):
+    """Batched data-space footprint over tile rows ``ttf_lvl[..., D]``.
 
-    ``axes`` is one entry of :attr:`AnalysisContext.ds_projection_axes`
-    (lists of ``(|coeff|, dim_index)`` terms per projection axis);
-    ``ttf_lvl`` is the clamped float64 tile matrix ``[B, D]`` of one
-    level. Replays the scalar span math (``span = 1 + sum(coeff *
-    (tt[j] - 1))``, footprint = product of spans) in the same float-op
-    order, so results are exact below :data:`BATCH_EXACT_LIMIT`. The one
-    batched form of the projection-span product -- the lower-bound cores
-    and the roofline bound all consume it.
+    ``axes`` is one data space's entry of
+    :meth:`AnalysisContext.coeff_layout` (per projection axis, the
+    ``(coefficient index, dim index)`` of each term); ``coeffs`` is the
+    parameter pack's ``coeffs`` array. Replays the scalar span math
+    (``span = 1 + sum(coeff * (tt[j] - 1))``, footprint = product of
+    spans) in the same float-op order, so results are exact below
+    :data:`BATCH_EXACT_LIMIT`. The one batched form of the projection-span
+    product: the traffic and bound cores and the roofline terms all
+    consume it.
     """
-    B = ttf_lvl.shape[0]
-    foot = xp.ones(B, dtype=xp.float64)
+    shape = ttf_lvl.shape[:-1]
+    foot = xp.ones(shape, dtype=xp.float64)
     for ax in axes:
-        span = xp.ones(B, dtype=xp.float64)
-        for coeff, j in ax:
-            span = span + coeff * (ttf_lvl[:, j] - 1.0)
+        span = xp.ones(shape, dtype=xp.float64)
+        for ci, j in ax:
+            span = span + coeffs[ci] * (ttf_lvl[..., j] - 1.0)
         foot = foot * span
     return foot
 
@@ -322,6 +321,15 @@ class BatchTraffic(NamedTuple):
     st: np.ndarray  # [B, n_levels, D] int64
     fans: np.ndarray  # [B, n_levels, D] int64
     rows: Tuple[DsTrafficBatch, ...]  # one entry per data space
+
+
+class BatchCostTerms(NamedTuple):
+    """A cost model's array program (``CostModel.batch_cost_terms``)."""
+
+    key: tuple  # structural properties ``terms`` and ``lb`` branch on
+    params: Dict[str, np.ndarray]  # values, joined with ``shape_params()``
+    terms: object  # terms(bt, xp, p) -> (latency, energy, util, guard, extras)
+    lb: object  # None (the hierarchical bound) or lb(skey, xp, lax) -> core
 
 
 class Loop(NamedTuple):
@@ -466,26 +474,22 @@ class AnalysisContext:
             self._top_read_e = arch.clusters[0].read_energy
             self._top_write_e = arch.clusters[0].write_energy
         # --- vectorized batch-analysis state (built lazily) ------------- #
-        self._np_batch_core = None
-        self._jax_batch_core = None
-        self._np_lb_core = None
-        self._jax_lb_core = None
         self._jax = None
         self._jax_failed = False
-        self._jax_core_donates = False
         # jitted-program invocations (lb, traffic, or fused admit+score):
         # the observable "dispatches per batch" count tests probe.
         self.jax_dispatches = 0
         # fused admit+score runners, keyed by (model store-key parts,
-        # metric): engines come and go per search, the compiled program
-        # is reused (equal store_key_parts => bit-identical costs, so
-        # sharing is sound by the same contract the ResultStore relies on)
+        # metric): engines come and go per search, the runner and its
+        # uploaded parameter pack are reused (equal store_key_parts =>
+        # bit-identical costs, the contract the ResultStore relies on)
         self._fused_runners: Dict[Tuple, object] = {}
-        # shape-generic machinery (lazy): the structural key + traced
-        # parameter pack that let ONE process-global compiled program
-        # serve every context in this shape class
+        # the structural key + parameter pack that let ONE process-wide
+        # program serve every context in this shape class (lazy), and the
+        # pack's device copy for the two-stage jax programs
         self._shape_class_key: Optional[tuple] = None
         self._shape_params: Optional[Dict[str, np.ndarray]] = None
+        self._dev_shape_params = None
 
     @property
     def ds_projection_axes(self) -> List[Tuple[int, List[List[Tuple[int, int]]], Tuple[int, ...]]]:
@@ -502,21 +506,18 @@ class AnalysisContext:
         return self._ds_axes_idx
 
     # ------------------------------------------------------------------ #
-    # Shape-generic program support. ``shape_class_key`` captures every
-    # STRUCTURAL property the batch/lower-bound cores branch or reshape
-    # on (ranks, level topology, projection term layout, which levels
-    # carry bandwidth terms); ``shape_params`` packs every VALUE those
-    # cores consume (dim sizes, projection coefficients, energies,
-    # bandwidth reciprocals) as arrays whose shapes are fully determined
-    # by the key. Two contexts with equal keys therefore run the SAME
-    # compiled program -- only the parameter pack differs -- and because
-    # the generic cores replay the per-context closures' float operations
-    # in the identical order, results stay bit-identical per row.
+    # Shape classes. ``shape_class_key`` captures every STRUCTURAL
+    # property the array programs branch or reshape on (ranks, level
+    # topology, projection term layout, which levels carry bandwidth
+    # terms); ``shape_params`` packs every VALUE they consume (dim sizes,
+    # projection coefficients, energies, bandwidth reciprocals) as arrays
+    # whose shapes are fully determined by the key. Two contexts with
+    # equal keys therefore run the SAME program -- built once per process,
+    # compiled once per pow2 bucket on jax -- and only the pack differs.
     # ------------------------------------------------------------------ #
     def shape_class_key(self) -> tuple:
         """Structural identity of this context's array programs (hashable;
-        equal keys <=> one compiled shape-generic program serves both
-        contexts)."""
+        equal keys <=> one program serves both contexts)."""
         if self._shape_class_key is None:
             axes_struct = tuple(
                 tuple(tuple(j for _c, j in ax) for ax in axes)
@@ -536,10 +537,10 @@ class AnalysisContext:
         return self._shape_class_key
 
     def shape_params(self) -> Dict[str, np.ndarray]:
-        """Traced parameter pack for the shape-generic cores: every value
-        the per-context closures bake in as Python constants, as arrays
-        keyed/shaped by :meth:`shape_class_key` (content may differ across
-        contexts of one class; shapes never do)."""
+        """Parameter pack for the array programs: every value they
+        consume, as arrays keyed/shaped by :meth:`shape_class_key`
+        (content may differ across contexts of one class; shapes never
+        do). On jax the pack is a traced argument."""
         if self._shape_params is None:
             D = len(self.dims)
             coeffs = [
@@ -568,6 +569,19 @@ class AnalysisContext:
                 "freq": np.float64(self.arch.frequency_hz),
             }
         return self._shape_params
+
+    def model_pack(self, params: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+        """:meth:`shape_params` joined with a cost model's own values (the
+        ``params`` of its :class:`BatchCostTerms`)."""
+        p = dict(self.shape_params())
+        p.update(params)
+        return p
+
+    def coeff_layout(self):
+        """Per data space, per projection axis: the ``(index into
+        shape_params()['coeffs'], dim index)`` of each term -- the layout
+        :func:`batch_projection_footprint` reads."""
+        return _axes_coeff_layout(self.shape_class_key()[6])
 
     # ------------------------------------------------------------------ #
     def analyze(self, mapping: Mapping) -> AccessProfile:
@@ -816,118 +830,6 @@ class AnalysisContext:
         shareable between the admission and scoring array programs."""
         return StackedBatch(*self.stack_signatures(sigs))
 
-    def _make_batch_core(self, xp, lax=None):
-        """Build the (tt, st, perm) -> stacked-traffic array program.
-
-        ``xp`` is numpy or jax.numpy; ``lax`` supplies ``cummax`` on the
-        JAX path. The program is the exact vectorization of
-        :meth:`signature_traffic`: same trip/fan derivation, same
-        relevant/irrelevant prefix products (the order-dependent
-        ``changes`` term uses a cummax over the last relevant loop
-        position), same footprint spans.
-        """
-        sizes_row = np.asarray(self._size_tuple, dtype=np.int64)[None, None, :]
-        n = self.n_levels
-        D = len(self.dims)
-        real_levels = list(self.real_levels)
-        L = len(real_levels)
-        real_parent = self.real_parent
-        mpc = self.macs_per_cycle
-        K = len(self._ds_rel_sets)
-        # [K, D] relevance mask, stacked over data spaces: the reuse
-        # cumprods below run for ALL data spaces in one array op.
-        rel_stack = np.array(
-            [[j in rset for j in range(D)] for rset in self._ds_rel_sets], dtype=bool
-        )
-        ds_axes = [axes for _wb, axes, _rel in self._ds_axes_idx]
-        ds_out = [ds.is_output for ds, _rel in self.ds_rel]
-        ends = np.asarray([(i + 1) * D - 1 for i in real_levels])
-        real_arr = np.asarray(real_levels)
-        # parent gather indices for rel_spatial (parentless levels divide by
-        # themselves -> ratio 1.0 exactly)
-        parent_arr = np.asarray(
-            [real_parent[i] if real_parent[i] is not None else i for i in real_levels]
-        )
-        pos_seq = np.arange(n * D)
-
-        def core(tt, st, perm):
-            B = tt.shape[0]
-            tt = xp.maximum(tt, 1)
-            st = xp.maximum(st, 1)
-            outer = xp.concatenate(
-                [xp.broadcast_to(xp.asarray(sizes_row), (B, 1, D)), st[:, :-1, :]],
-                axis=1,
-            )
-            trips = xp.maximum(outer // tt, 1)
-            fans = xp.maximum(tt // st, 1)
-            tripsf = trips.astype(xp.float64)
-            fansf = fans.astype(xp.float64)
-            total_trips = xp.prod(tripsf.reshape(B, n * D), axis=1)
-            leaf_macs = xp.prod(tt[:, -1, :].astype(xp.float64), axis=1)
-            compute_cycles = total_trips * xp.ceil(leaf_macs / exact_divisor(xp, mpc))
-            par = xp.prod(fansf.reshape(B, n * D), axis=1)
-            lvl_all = xp.prod(fansf, axis=2)  # [B, n]
-            cp_all = xp.cumprod(lvl_all, axis=1)
-            inst_at = xp.concatenate(
-                [xp.ones((B, 1), dtype=xp.float64), cp_all[:, :-1]], axis=1
-            )
-            # temporal loop sequence in emission order (order-major per level)
-            perm_flat = perm.reshape(B, n * D)
-            tseqf = xp.take_along_axis(trips, perm, axis=2).reshape(B, n * D).astype(
-                xp.float64
-            )
-            # ---- all data spaces at once: [K, B, S] ---------------------- #
-            rel_seq = xp.asarray(rel_stack)[:, perm_flat]  # [K, B, S]
-            present = (tseqf > 1.0)[None, :, :]
-            relm = rel_seq & present
-            irrm = (~rel_seq) & present
-            tseq_b = xp.broadcast_to(tseqf[None, :, :], (K, B, n * D))
-            relprod = xp.cumprod(xp.where(relm, tseq_b, 1.0), axis=2)
-            irrprod = xp.cumprod(xp.where(irrm, tseq_b, 1.0), axis=2)
-            # irrelevant-trip product at the LAST relevant loop <= s: gather
-            # the (exclusive == inclusive, s is relevant) irrprod at that
-            # position, 1.0 when no relevant loop yet.
-            idx = xp.where(relm, pos_seq[None, None, :], -1)
-            if lax is None:
-                lastrel = np.maximum.accumulate(idx, axis=2)
-            else:
-                lastrel = lax.cummax(idx, axis=2)
-            gathered = xp.take_along_axis(irrprod, xp.maximum(lastrel, 0), axis=2)
-            ip = xp.where(lastrel >= 0, gathered, 1.0)
-            unique = relprod[:, :, ends]  # [K, B, L]
-            changes = unique * ip[:, :, ends]
-            # spatial: relevant-fan products per level, exclusive cumprod
-            lvl_rel = xp.prod(
-                xp.where(xp.asarray(rel_stack)[:, None, None, :], fansf[None], 1.0),
-                axis=3,
-            )  # [K, B, n]
-            cp_rel = xp.cumprod(lvl_rel, axis=2)
-            srel_excl = xp.concatenate(
-                [xp.ones((K, B, 1), dtype=xp.float64), cp_rel[:, :, :-1]], axis=2
-            )
-            # exact: srel_excl at the parent divides srel_excl at the level
-            rel_sp = srel_excl[:, :, real_arr] / srel_excl[:, :, parent_arr]
-            # footprints per data space (projections differ per ds)
-            ttf_real = tt[:, real_arr, :].astype(xp.float64)  # [B, L, D]
-            rows = []
-            for k in range(K):
-                foot = xp.ones((B, L), dtype=xp.float64)
-                for ax in ds_axes[k]:
-                    span = xp.ones((B, L), dtype=xp.float64)
-                    for coeff, j in ax:
-                        span = span + coeff * (ttf_real[:, :, j] - 1.0)
-                    foot = foot * span
-                cf = changes[k] * foot
-                if ds_out[k]:
-                    rmw = xp.maximum(changes[k] - unique[k], 0.0) * foot
-                    rows.append((rmw, cf, rmw * rel_sp[k], cf * rel_sp[k], foot))
-                else:
-                    z = xp.zeros_like(cf)
-                    rows.append((cf, z, cf * rel_sp[k], z, foot))
-            return compute_cycles, total_trips, par, inst_at, tt, st, fans, tuple(rows)
-
-        return core
-
     def _ensure_jax(self):
         """Import JAX lazily; memoized on the context.
 
@@ -1002,50 +904,52 @@ class AnalysisContext:
 
         return pad(tt), pad(st), pad(perm), B
 
-    def _run_jax_core(self, sb: StackedBatch, select=None):
-        """JAX-jitted batch core over a (device-resident) StackedBatch:
-        optionally gathers the ``select`` row subset ON DEVICE, pads the
-        batch to a power of two (bounding retraces), runs in float64 under
-        :func:`x64`, returns numpy arrays of the unpadded (selected)
-        batch -- or None after a device fault (:func:`device_faults`), so
-        the caller falls back to numpy."""
+    def _run_jax(
+        self, key, build, sb: StackedBatch, pack=None, select=None, donate=False
+    ):
+        """One dispatch of the process-wide jitted program ``key`` (built
+        by ``build(jnp, lax)`` on first use) over a device-resident
+        StackedBatch: optionally gathers the ``select`` row subset ON
+        DEVICE, pads the batch to a power of two (bounding retraces) and
+        runs in float64 under :func:`x64`. ``pack`` is a host parameter
+        pack, uploaded per call (None: this context's :meth:`shape_params`,
+        uploaded once).
+        Returns ``(host outputs, batch size)`` -- or None after a device
+        fault (:func:`device_faults`), so the caller falls back to numpy.
+
+        ``donate`` lets XLA reuse the batch matrices' device memory for
+        the program's temporaries; it is unsupported (and warns) on CPU,
+        so only accelerator backends request it. Donated matrices are
+        re-uploaded from the host copy if the handle is reused."""
         if self._jax_failed:
             return None
         try:
             jax = self._ensure_jax()
-            from jax import lax
             import jax.numpy as jnp
 
-            if self._jax_batch_core is None:
-                # Buffer donation lets XLA reuse the input matrices' device
-                # memory for the program's temporaries; it is unsupported
-                # (and warns) on CPU, so only accelerator backends request
-                # it. The donated buffers are the batch matrices, which are
-                # re-uploaded from the host copy if the handle is reused.
-                donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-                self._jax_core_donates = bool(donate)
-                self._jax_batch_core = CompiledCore(
-                    self._make_batch_core(jnp, lax), donate_argnums=donate
-                )
+            donate = donate and jax.default_backend() != "cpu"
+            program = _jax_program(key, build, (0, 1, 2) if donate else ())
             with x64():
                 tt, st, perm = self._jax_device_arrays(sb)
                 if select is not None:
                     sel = jnp.asarray(np.asarray(select, dtype=np.int64))
                     tt, st, perm = tt[sel], st[sel], perm[sel]
                 tt, st, perm, B = self._pad_pow2(tt, st, perm, jnp)
+                if pack is not None:
+                    pack = jax.device_put(pack)
+                else:
+                    if self._dev_shape_params is None:
+                        self._dev_shape_params = jax.device_put(self.shape_params())
+                    pack = self._dev_shape_params
                 self.jax_dispatches += 1
-                _record_trace(("ctx-core", id(self)), int(tt.shape[0]))
-                out = self._jax_batch_core(tt, st, perm)
-            if self._jax_core_donates and select is None:
+                _record_trace(key, int(tt.shape[0]))
+                out = program(tt, st, perm, pack)
+            if donate and select is None:
                 sb.dev = None  # donated away; re-upload on next use
-            out = host_pull(out)
+            return host_pull(out), B
         except device_faults():
             self._jax_failed = True
             return None
-        require_float64(out[0])
-        if out[0].shape[0] != B:
-            out = _tree_slice(out, B)
-        return out
 
     def signature_traffic_batch(
         self,
@@ -1057,14 +961,14 @@ class AnalysisContext:
         """Stacked :meth:`signature_traffic` over a batch of signatures.
 
         ``backend`` selects the array program: ``"numpy"`` (default) or
-        ``"jax"`` (jitted, falls back to numpy when JAX cannot deliver
-        float64). ``stacked`` reuses an already-stacked batch -- the
-        evaluation engine stacks each miss-batch ONCE and shares the handle
-        between the admission filter and this scoring pass. ``select``
-        restricts the program to the given row indices of the stacked
-        batch (on the jax backend the gather runs on device, so pruned
-        candidates' traffic never returns to host). Returns None for an
-        empty batch/selection.
+        ``"jax"`` (jitted, falls back to numpy after a device fault).
+        ``stacked`` reuses an already-stacked batch -- the evaluation
+        engine stacks each miss-batch ONCE and shares the handle between
+        the admission filter and this scoring pass. ``select`` restricts
+        the program to the given row indices of the stacked batch (on the
+        jax backend the gather runs on device, so pruned candidates'
+        traffic never returns to host). Returns None for an empty
+        batch/selection.
         """
         sb = stacked
         if sb is None:
@@ -1073,17 +977,29 @@ class AnalysisContext:
             sb = self.stacked_batch(sigs)
         if sb.size == 0 or (select is not None and len(select) == 0):
             return None
+        skey = self.shape_class_key()
+        key = ("traffic", skey)
         out = None
         if backend == "jax":
-            out = self._run_jax_core(sb, select=select)
+            run = self._run_jax(
+                key,
+                lambda xp, lax: _make_generic_batch_core(skey, xp, lax),
+                sb,
+                select=select,
+                donate=True,
+            )
+            if run is not None:
+                out, B = run
+                require_float64(out[0])
+                if out[0].shape[0] != B:
+                    out = _tree_slice(out, B)
         if out is None:
-            if self._np_batch_core is None:
-                self._np_batch_core = self._make_batch_core(np)
+            core = _np_program(key, lambda: _make_generic_batch_core(skey, np))
             tt, st, perm = sb.tt, sb.st, sb.perm
             if select is not None:
                 idx = np.asarray(select, dtype=np.int64)
                 tt, st, perm = tt[idx], st[idx], perm[idx]
-            out = self._np_batch_core(tt, st, perm)
+            out = core(tt, st, perm, self.shape_params())
         compute_cycles, total_trips, par, inst_at, tt_c, st_c, fans, rows = out
         return BatchTraffic(
             compute_cycles=np.asarray(compute_cycles),
@@ -1270,171 +1186,12 @@ class AnalysisContext:
     # the program tracks their max and the wrapper rejects the batch
     # (caller falls back to the scalar bound) beyond BATCH_EXACT_LIMIT.
     # ------------------------------------------------------------------ #
-    def _make_lb_core(self, xp, lax=None):
-        """Build the (tt, st, perm) -> (cycles[B], energy_pj[B], guard_max)
-        program: the exact vectorization of :meth:`signature_lower_bound`."""
-        sizes_row = np.asarray(self._size_tuple, dtype=np.int64)[None, None, :]
-        n = self.n_levels
-        D = len(self.dims)
-        mpc = self.macs_per_cycle
-        K = len(self._ds_rel_sets)
-        rel_stack = np.array(
-            [[j in rset for j in range(D)] for rset in self._ds_rel_sets], dtype=bool
-        )
-        wb_list = [wb for wb, _axes, _rel in self._ds_axes_idx]
-        ds_axes = [axes for _wb, axes, _rel in self._ds_axes_idx]
-        ds_out = [ds.is_output for ds, _rel in self.ds_rel]
-        e_base = self._lb_energy_base
-        dc = self._lb_dram_child
-        tre = self._top_read_e
-        twe = self._top_write_e
-        bw_levels = list(self._lb_bw_levels)
-        pos_seq = np.arange(n * D)
-
-        def ds_foot(ttf_lvl, k):
-            return batch_projection_footprint(ds_axes[k], ttf_lvl, xp)
-
-        def core(tt, st, perm):
-            B = tt.shape[0]
-            tt = xp.maximum(tt, 1)
-            st = xp.maximum(st, 1)
-            outer = xp.concatenate(
-                [xp.broadcast_to(xp.asarray(sizes_row), (B, 1, D)), st[:, :-1, :]],
-                axis=1,
-            )
-            trips = xp.maximum(outer // tt, 1)
-            tripsf = trips.astype(xp.float64)
-            total_trips = xp.prod(tripsf.reshape(B, n * D), axis=1)
-            leaf_macs = xp.prod(tt[:, -1, :].astype(xp.float64), axis=1)
-            cycles = total_trips * xp.ceil(leaf_macs / exact_divisor(xp, mpc))
-            # fractional energy addends are collected as (x, y) pairs and
-            # summed through ordered_pair_sum -- contraction-proof on the
-            # jitted path, plain left-associated adds on numpy
-            e_pairs = []
-            mx = xp.maximum(xp.maximum(total_trips, leaf_macs), cycles)
-
-            dc_boundary = None
-            if dc is not None:
-                # temporal loops of levels <= dc in effective emission order
-                # (order-major): enough to reproduce changes/unique exactly.
-                S = (dc + 1) * D
-                perm_pref = perm[:, : dc + 1, :]
-                tseqf = (
-                    xp.take_along_axis(trips[:, : dc + 1, :], perm_pref, axis=2)
-                    .reshape(B, S)
-                    .astype(xp.float64)
-                )
-                rel_seq = xp.asarray(rel_stack)[:, perm_pref.reshape(B, S)]  # [K,B,S]
-                present = (tseqf > 1.0)[None, :, :]
-                relm = rel_seq & present
-                irrm = (~rel_seq) & present
-                tseq_b = xp.broadcast_to(tseqf[None, :, :], (K, B, S))
-                unique = xp.prod(xp.where(relm, tseq_b, 1.0), axis=2)  # [K, B]
-                irrprod = xp.cumprod(xp.where(irrm, tseq_b, 1.0), axis=2)
-                # irrelevant-trip product at the LAST relevant loop: position
-                # itself is relevant, so the inclusive irrprod there equals
-                # the scalar path's exclusive ``lastrel_ip``; 1.0 when no
-                # relevant loop exists.
-                idx = xp.where(relm, pos_seq[None, None, :S], -1)
-                lastrel = xp.max(idx, axis=2)
-                gathered = xp.take_along_axis(
-                    irrprod, xp.maximum(lastrel, 0)[:, :, None], axis=2
-                )[:, :, 0]
-                changes = unique * xp.where(lastrel >= 0, gathered, 1.0)
-                ttf_dc = tt[:, dc, :].astype(xp.float64)
-                if dc > 0:
-                    fans_pref = xp.maximum(tt[:, :dc, :] // st[:, :dc, :], 1).astype(
-                        xp.float64
-                    )
-                dc_boundary = xp.zeros(B, dtype=xp.float64)
-                for k in range(K):
-                    foot = ds_foot(ttf_dc, k)
-                    if dc > 0:
-                        rel_sp = xp.prod(
-                            xp.where(
-                                xp.asarray(rel_stack[k])[None, None, :], fans_pref, 1.0
-                            ).reshape(B, dc * D),
-                            axis=1,
-                        )
-                    else:
-                        rel_sp = xp.ones(B, dtype=xp.float64)
-                    cf = changes[k] * foot
-                    mx = xp.maximum(mx, changes[k])
-                    t1 = cf * rel_sp * wb_list[k]
-                    mx = xp.maximum(mx, t1)
-                    if ds_out[k]:
-                        rmw = xp.maximum(changes[k] - unique[k], 0.0) * foot
-                        t2 = rmw * rel_sp * wb_list[k]
-                        mx = xp.maximum(mx, t2)
-                        e_pairs.append((t1 * twe, t2 * tre))
-                        dc_boundary = dc_boundary + (cf + rmw) * wb_list[k]
-                    else:
-                        # x + 0.0 is exact for the non-negative term, so the
-                        # pair form reproduces ``energy + t1 * tre``
-                        e_pairs.append((t1 * tre, 0.0))
-                        dc_boundary = dc_boundary + cf * wb_list[k]
-                mx = xp.maximum(mx, dc_boundary)
-            energy = ordered_pair_sum(
-                xp, xp.full((B,), e_base, dtype=xp.float64), e_pairs
-            )
-
-            for level, cyc_per_byte in bw_levels:
-                if level == dc:
-                    cycles = xp.maximum(cycles, dc_boundary * cyc_per_byte)
-                    continue
-                ttf_lvl = tt[:, level, :].astype(xp.float64)
-                # unique per ds: product of relevant trips of levels <= level
-                relprod_lvl = xp.prod(
-                    xp.where(
-                        xp.asarray(rel_stack)[:, None, None, :],
-                        tripsf[None, :, : level + 1, :],
-                        1.0,
-                    ).reshape(K, B, (level + 1) * D),
-                    axis=2,
-                )
-                b = xp.zeros(B, dtype=xp.float64)
-                for k in range(K):
-                    term = relprod_lvl[k] * ds_foot(ttf_lvl, k) * wb_list[k]
-                    mx = xp.maximum(mx, term)
-                    b = b + term
-                mx = xp.maximum(mx, b)
-                cycles = xp.maximum(cycles, b * cyc_per_byte)
-            return cycles, energy, xp.max(mx)
-
-        return core
-
-    def _run_jax_lb(self, sb: StackedBatch):
-        """Jitted lower-bound core over a device-resident StackedBatch; the
-        uploaded matrices stay on ``sb.dev`` for the scoring pass. Returns
-        numpy (cycles, energy, guard) or None after a device fault
-        (fallback to numpy)."""
-        if self._jax_failed:
-            return None
-        try:
-            jax = self._ensure_jax()
-            from jax import lax
-            import jax.numpy as jnp
-
-            if self._jax_lb_core is None:
-                # never donate here: the scoring pass reuses sb.dev
-                self._jax_lb_core = CompiledCore(self._make_lb_core(jnp, lax))
-            with x64():
-                tt, st, perm = self._jax_device_arrays(sb)
-                tt, st, perm, B = self._pad_pow2(tt, st, perm, jnp)
-                self.jax_dispatches += 1
-                _record_trace(("ctx-lb", id(self)), int(tt.shape[0]))
-                cyc, en, mx = self._jax_lb_core(tt, st, perm)
-            cyc, en, mx = host_pull((cyc, en, mx))
-        except device_faults():
-            self._jax_failed = True
-            return None
-        return require_float64(cyc)[:B], en[:B], mx
-
     def lower_bound_batch(
         self,
         sigs=None,
         backend: str = "numpy",
         stacked: Optional[StackedBatch] = None,
+        model=None,
     ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         """Stacked :meth:`signature_lower_bound`: float64 ``(cycles[B],
         energy_pj[B])`` arrays, bit-identical per candidate to the scalar
@@ -1442,7 +1199,12 @@ class AnalysisContext:
         guaranteed (any guarded integer quantity at/above
         :data:`BATCH_EXACT_LIMIT` -- the caller then falls back to the
         per-candidate bound). ``stacked`` shares an already-stacked batch
-        with the scoring pass (see :meth:`signature_traffic_batch`)."""
+        with the scoring pass (see :meth:`signature_traffic_batch`).
+
+        ``model`` is a cost model's :class:`BatchCostTerms`: its own
+        bound ``lb`` (when not None) replaces the hierarchical one over
+        the joined pack, and its calibration scale multiplies the cycles
+        last -- the operations the fused core runs, in its order."""
         sb = stacked
         if sb is None:
             if not sigs:
@@ -1450,16 +1212,30 @@ class AnalysisContext:
             sb = self.stacked_batch(sigs)
         if sb.size == 0:
             return None
+        skey = self.shape_class_key()
+        if model is None or model.lb is None:
+            key, build, pack = ("lb", skey), _make_generic_lb_core, None
+        else:
+            key, build = ("lb", skey, model.key), model.lb
+            pack = self.model_pack(model.params)
         out = None
         if backend == "jax":
-            out = self._run_jax_lb(sb)
+            run = self._run_jax(
+                key, lambda xp, lax: build(skey, xp, lax), sb, pack=pack
+            )
+            if run is not None:
+                (cyc, en, mx), B = run
+                out = require_float64(cyc)[:B], en[:B], mx
         if out is None:
-            if self._np_lb_core is None:
-                self._np_lb_core = self._make_lb_core(np)
-            out = self._np_lb_core(sb.tt, sb.st, sb.perm)
+            core = _np_program(key, lambda: build(skey, np))
+            if pack is None:
+                pack = self.shape_params()
+            out = core(sb.tt, sb.st, sb.perm, pack)
         cycles, energy, mx = out
         if not (float(mx) < BATCH_EXACT_LIMIT):
             return None
+        if model is not None:
+            cycles = cycles * model.params["calib_scale"]
         return np.asarray(cycles), np.asarray(energy)
 
     # ------------------------------------------------------------------ #
@@ -1469,131 +1245,20 @@ class AnalysisContext:
     # -- so one dispatch per miss-batch covers the whole pipeline and only
     # per-candidate scalars (plus small [B] breakdown arrays) ever return
     # to host. The numpy backend keeps the two-stage flow but runs the
-    # SAME terms array program per row, so values are bit-identical.
+    # SAME bound and terms programs per row, so values are bit-identical.
     # ------------------------------------------------------------------ #
-    def _metric_scalarize(self, metric: str, xp):
-        """Device-traceable twin of ``EvaluationEngine._scalarize_batch``:
-        identical float operations per element (the frequency divisor goes
-        through :func:`exact_divisor`), so on-device admit/reject decisions
-        are bit-identical to the host filter."""
-        freq = self.arch.frequency_hz
-        if metric == "latency":
-            return lambda cyc, en: cyc
-        if metric == "energy":
-            return lambda cyc, en: en
-        if metric == "edp":
-            return lambda cyc, en: (en * 1e-12) * (cyc / exact_divisor(xp, freq))
-        return lambda cyc, en: cyc * 0.0
-
-    def _make_fused_core(self, xp, lax, lb_builder, terms, metric: str):
-        """Build the (tt, st, perm, incumbent) -> (admit[B], lb_guard,
-        latency[B], energy[B], util[B], score_guard, extras) program.
-
-        ``lb_builder(xp, lax)`` yields the model's admission-bound core
-        (``CostModel.batch_admit_core_builder``); ``terms`` is the model's
-        cost-terms program (``CostModel.batch_cost_terms_fn``). Both guard
-        maxes come back so the host can fall back exactly where the
-        two-stage path would (lb guard -> scalar bound; score guard ->
-        scalar/numpy scoring of the admitted subset).
-        """
-        lb_core = lb_builder(xp, lax)
-        traffic_core = self._make_batch_core(xp, lax)
-        scalarize = self._metric_scalarize(metric, xp)
-
-        def core(tt, st, perm, incumbent):
-            lb_cyc, lb_en, lb_mx = lb_core(tt, st, perm)
-            admit = scalarize(lb_cyc, lb_en) < incumbent
-            out = traffic_core(tt, st, perm)
-            bt = BatchTraffic(
-                compute_cycles=out[0],
-                total_trips=out[1],
-                par=out[2],
-                inst_at=out[3],
-                tt=out[4],
-                st=out[5],
-                fans=out[6],
-                rows=tuple(DsTrafficBatch(*r) for r in out[7]),
-            )
-            latency, energy, util, score_mx, extras = terms(bt, xp)
-            return admit, lb_mx, latency, energy, util, score_mx, extras
-
-        return core
-
-    def build_fused_runner(self, lb_builder, terms, metric: str, cache_key=None):
-        """Jitted single-dispatch admit+score runner for one (model,
-        metric): ``run(sb, incumbent) -> (admit[B] bool, lb_guard float,
-        latency[B], energy[B], util[B], score_guard float, extras)`` as
-        host numpy, or None after a device fault (:func:`device_faults`;
-        the engine then keeps the two-stage flow). The stacked
-        batch is uploaded once and padded to a power of two (padding
-        repeats row 0, a real candidate, so neither guard can trip on
-        padding); only [B]-sized result arrays cross back to host.
-
-        ``cache_key`` (model store-key parts + metric, from the engine)
-        memoizes the runner on the context so repeated searches over the
-        same (problem, arch, model, metric) reuse the compiled program
-        instead of re-tracing per engine.
-        """
-        if self._jax_failed:
-            return None
-        if cache_key is not None:
-            cached = self._fused_runners.get(cache_key)
-            if cached is not None:
-                return cached
-        try:
-            jax = self._ensure_jax()
-        except device_faults():
-            self._jax_failed = True
-            return None
-        from jax import lax
-        import jax.numpy as jnp
-
-        # Donation mirrors the traffic core: XLA may reuse the batch
-        # matrices' device memory on accelerator backends (unsupported
-        # on CPU); the incumbent scalar (arg 3) is never donated.
-        donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-        core = CompiledCore(
-            self._make_fused_core(jnp, lax, lb_builder, terms, metric),
-            donate_argnums=donate,
-        )
-
-        def run(sb: StackedBatch, incumbent: float):
-            if self._jax_failed:
-                return None
-            try:
-                with x64():
-                    tt, st, perm, B = self._jax_device_padded(sb)
-                    inc = jnp.asarray(float(incumbent), dtype=jnp.float64)
-                    self.jax_dispatches += 1
-                    _record_trace(
-                        ("ctx-fused", id(self), cache_key), int(tt.shape[0])
-                    )
-                    out = core(tt, st, perm, inc)
-                if donate:
-                    sb.devp = None  # donated away; fallbacks re-upload
-                return _fused_to_host(out, B)
-            except device_faults():
-                self._jax_failed = True
-                return None
-
-        if cache_key is not None:
-            self._fused_runners[cache_key] = run
-        return run
-
-    def build_generic_fused_runner(self, generic, metric: str, cache_key=None):
-        """Shape-generic twin of :meth:`build_fused_runner`: the jitted
-        program is compiled ONCE per (shape class, model structure,
+    def build_fused_runner(self, model, metric: str, cache_key=None):
+        """Single-dispatch admit+score runner for one (model, metric): the
+        jitted program is compiled ONCE per (shape class, model structure,
         metric) process-wide (``_GENERIC_PROGRAMS``) and this context's
         values enter as a traced parameter pack, so content-different
         sweep points in one shape class share a single trace.
 
-        ``generic`` is ``CostModel.batch_cost_terms_generic`` output:
-        ``(model_struct_key, model_params, terms)`` with
-        ``terms(bt, xp, p)``. Returns a :class:`GenericFusedRunner`
-        (same call protocol as the per-context runner) or None after a
-        device fault (callers fall back exactly as for the per-context
-        builder). ``cache_key`` memoizes the runner on
-        the context as the lookup tier ABOVE the global program cache.
+        ``model`` is a :class:`BatchCostTerms`. Returns a
+        :class:`FusedRunner`, or None after a device fault (the engine
+        then keeps the two-stage flow). ``cache_key`` (model store-key
+        parts + metric, from the engine) memoizes the runner on the
+        context as the lookup tier ABOVE the global program cache.
         """
         if self._jax_failed:
             return None
@@ -1601,29 +1266,27 @@ class AnalysisContext:
             cached = self._fused_runners.get(cache_key)
             if cached is not None:
                 return cached
-        model_key, model_params, terms = generic
         try:
             jax = self._ensure_jax()
         except device_faults():
             self._jax_failed = True
             return None
-        from jax import lax
-        import jax.numpy as jnp
-
         skey = self.shape_class_key()
-        pkey = ("generic-fused", skey, model_key, metric)
-        entry = _GENERIC_PROGRAMS.get(pkey)
-        if entry is None:
-            donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-            core = CompiledCore(
-                _make_generic_fused_core(skey, terms, metric, jnp, lax),
-                donate_argnums=donate,
-            )
-            entry = (core, bool(donate))
-            _GENERIC_PROGRAMS[pkey] = entry
-        params = dict(self.shape_params())
-        params.update(model_params)
-        runner = GenericFusedRunner(self, entry[0], params, pkey, entry[1])
+        pkey = ("fused", skey, model.key, metric)
+        # Donation lets XLA reuse the batch matrices' device memory on
+        # accelerator backends (unsupported on CPU); the incumbent and the
+        # parameter pack are never donated.
+        donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
+        core = _jax_program(
+            pkey,
+            lambda xp, lax: _make_generic_fused_core(
+                skey, model.terms, model.lb, metric, xp, lax
+            ),
+            donate,
+        )
+        runner = FusedRunner(
+            self, core, self.model_pack(model.params), pkey, bool(donate)
+        )
         if cache_key is not None:
             self._fused_runners[cache_key] = runner
         return runner
@@ -1777,20 +1440,40 @@ def _tree_slice(out, B: int):
 
 
 # ---------------------------------------------------------------------- #
-# Shape-generic array programs. These are the per-context closures
-# (``_make_lb_core`` / ``_make_batch_core`` / the fused admit+score core)
-# re-derived from a structural ShapeClassKey plus a traced parameter pack
-# ``p`` (see ``AnalysisContext.shape_class_key`` / ``shape_params``): the
-# loop/branch/reshape STRUCTURE comes from the key, every VALUE from
-# ``p``. Because the float operations run in the identical order with
-# identical values, the per-row results are bit-identical to the
-# per-context closures -- but one compiled program now serves every
-# context in the shape class.
+# The array programs. Each is built from a structural ShapeClassKey plus a
+# parameter pack ``p`` (see ``AnalysisContext.shape_class_key`` /
+# ``shape_params``): the loop/branch/reshape STRUCTURE comes from the key,
+# every VALUE from ``p``, so one program serves every context in the shape
+# class. The same builder yields the numpy program (``xp=np``) and the
+# jitted one (``xp=jax.numpy``); both run the scalar path's float
+# operations in its order, so results are bit-identical per row.
 # ---------------------------------------------------------------------- #
+def _np_program(key, build):
+    """The process-wide numpy program under ``key``, built by ``build()``
+    on first use."""
+    key = ("numpy",) + key
+    program = _GENERIC_PROGRAMS.get(key)
+    if program is None:
+        program = _GENERIC_PROGRAMS[key] = build()
+    return program
+
+
+def _jax_program(key, build, donate_argnums=()) -> CompiledCore:
+    """The process-wide jitted program under ``key``: ``build(jnp, lax)``
+    wrapped in a :class:`CompiledCore` on first use."""
+    program = _GENERIC_PROGRAMS.get(key)
+    if program is None:
+        from jax import lax
+        import jax.numpy as jnp
+
+        program = CompiledCore(build(jnp, lax), donate_argnums=donate_argnums)
+        _GENERIC_PROGRAMS[key] = program
+    return program
+
+
 def _axes_coeff_layout(axes_struct):
     """Per ds/axis/term: ``(flat coeff index, dim index)`` -- the build
-    order of ``shape_params()['coeffs']``, so generic span math consumes
-    coefficients exactly where the closures baked them in."""
+    order of ``shape_params()['coeffs']``."""
     layout = []
     fi = 0
     for axes in axes_struct:
@@ -1805,22 +1488,13 @@ def _axes_coeff_layout(axes_struct):
     return layout
 
 
-def _generic_ds_foot(coeff_layout, k, ttf_lvl, xp, p):
-    """Generic :func:`batch_projection_footprint`: identical span math
-    over ``[..., D]`` tiles with traced coefficients."""
-    shape = ttf_lvl.shape[:-1]
-    foot = xp.ones(shape, dtype=xp.float64)
-    for ax in coeff_layout[k]:
-        span = xp.ones(shape, dtype=xp.float64)
-        for ci, j in ax:
-            span = span + p["coeffs"][ci] * (ttf_lvl[..., j] - 1.0)
-        foot = foot * span
-    return foot
-
-
 def _make_generic_lb_core(skey, xp, lax=None):
-    """Shape-generic ``_make_lb_core``: ``core(tt, st, perm, p) ->
-    (cycles[B], energy_pj[B], guard_max)``."""
+    """The hierarchical admission bound: ``core(tt, st, perm, p) ->
+    (cycles[B], energy_pj[B], guard_max)``, the exact vectorization of
+    :meth:`AnalysisContext.signature_lower_bound`. Every guarded quantity
+    is integer-valued; ``guard_max`` is their max, and the caller rejects
+    the batch (falling back to the scalar bound) at
+    :data:`BATCH_EXACT_LIMIT`."""
     n, D, K, _real_levels, _real_parent, ds_out, axes_struct, dc, bw_lvls = skey
     if dc < 0:
         dc = None
@@ -1874,7 +1548,9 @@ def _make_generic_lb_core(skey, xp, lax=None):
                 )
             dc_boundary = xp.zeros(B, dtype=xp.float64)
             for k in range(K):
-                foot = _generic_ds_foot(coeff_layout, k, ttf_dc, xp, p)
+                foot = batch_projection_footprint(
+                    coeff_layout[k], ttf_dc, xp, p["coeffs"]
+                )
                 if dc > 0:
                     rel_sp = xp.prod(
                         xp.where(
@@ -1920,7 +1596,9 @@ def _make_generic_lb_core(skey, xp, lax=None):
             for k in range(K):
                 term = (
                     relprod_lvl[k]
-                    * _generic_ds_foot(coeff_layout, k, ttf_lvl, xp, p)
+                    * batch_projection_footprint(
+                        coeff_layout[k], ttf_lvl, xp, p["coeffs"]
+                    )
                     * wb[k]
                 )
                 mx = xp.maximum(mx, term)
@@ -1933,8 +1611,12 @@ def _make_generic_lb_core(skey, xp, lax=None):
 
 
 def _make_generic_batch_core(skey, xp, lax=None):
-    """Shape-generic ``_make_batch_core``: ``core(tt, st, perm, p) ->``
-    the stacked-traffic 8-tuple."""
+    """The (tt, st, perm, p) -> stacked-traffic 8-tuple program: the exact
+    vectorization of :meth:`AnalysisContext.signature_traffic` (same
+    trip/fan derivation, same relevant/irrelevant prefix products -- the
+    order-dependent ``changes`` term uses a cummax over the last relevant
+    loop position -- same footprint spans). ``lax`` supplies ``cummax``
+    on the jax path."""
     n, D, K, real_levels, real_parent, ds_out, axes_struct, _dc, _bw = skey
     real_levels = list(real_levels)
     L = len(real_levels)
@@ -2002,7 +1684,9 @@ def _make_generic_batch_core(skey, xp, lax=None):
         ttf_real = tt[:, real_arr, :].astype(xp.float64)  # [B, L, D]
         rows = []
         for k in range(K):
-            foot = _generic_ds_foot(coeff_layout, k, ttf_real, xp, p)
+            foot = batch_projection_footprint(
+                coeff_layout[k], ttf_real, xp, p["coeffs"]
+            )
             cf = changes[k] * foot
             if ds_out[k]:
                 rmw = xp.maximum(changes[k] - unique[k], 0.0) * foot
@@ -2016,14 +1700,28 @@ def _make_generic_batch_core(skey, xp, lax=None):
 
 
 def generic_hierarchical_energy(real_levels, real_parent, K, bt, xp, p, hop=False):
-    """Shape-generic :func:`batch_hierarchical_energy`: the identical
-    level-walk float-operation sequence with energies / word widths /
-    precomputed innermost+MAC terms read from the parameter pack
+    """Shared level-walk energy accumulation of the hierarchical models'
+    cost terms (timeloop_like and maestro_like run the identical sequence
+    of float operations here; maestro additionally accumulates the NoC
+    delivery term, enabled via ``hop``). Energies, word widths and the
+    precomputed innermost+MAC terms come from the parameter pack
     (``lvl_read_e`` / ``lvl_write_e`` / ``wb`` / ``l1_terms`` /
-    ``mac_term`` / ``hop``). ``real_parent`` uses -1 for parentless.
-    Returns ``(energy[B], noc_energy[B] or None, mx)``."""
+    ``mac_term`` / ``hop``); ``real_parent`` uses -1 for parentless.
+
+    Returns ``(energy[B], noc_energy[B] or None, mx)``: ``energy`` already
+    includes the innermost-operand and MAC terms (the scalar paths add
+    them in exactly this order) and ``mx`` is an xp scalar holding the max
+    of every guarded integer-valued product. NoC energy is NOT folded into
+    ``energy`` -- maestro adds it after the MAC term, as its scalar path
+    does."""
     inst_at = bt.inst_at
     mx = xp.zeros(())
+    # The access-count products (t) are integer-valued and exact, but the
+    # per-byte energies are fractional: each ``t * energy`` product must be
+    # ROUNDED before it joins the accumulator, exactly as numpy does.
+    # Addends are collected and summed through :func:`ordered_sum`, whose
+    # scan structure stops XLA's LLVM backend from contracting
+    # ``acc + t * e`` into an FMA (one rounding instead of two).
     e_terms = []
     noc_terms = [] if hop else None
     for k in range(K):
@@ -2061,9 +1759,10 @@ def generic_hierarchical_energy(real_levels, real_parent, K, bt, xp, p, hop=Fals
 
 
 def _generic_scalarize(metric: str, xp):
-    """Shape-generic ``_metric_scalarize``: frequency comes from the
-    parameter pack (same exact-divisor barrier, so decisions stay
-    bit-identical to the host filter)."""
+    """Device-traceable twin of ``EvaluationEngine._scalarize_batch``:
+    identical float operations per element (the frequency divisor comes
+    from the pack through :func:`exact_divisor`), so on-device
+    admit/reject decisions are bit-identical to the host filter."""
     if metric == "latency":
         return lambda cyc, en, p: cyc
     if metric == "energy":
@@ -2075,21 +1774,23 @@ def _generic_scalarize(metric: str, xp):
     return lambda cyc, en, p: cyc * 0.0
 
 
-def _make_generic_fused_core(skey, terms, metric: str, xp, lax):
-    """Shape-generic fused admit+score core: ``core(tt, st, perm,
-    incumbent, p) -> (admit, lb_guard, latency, energy, util,
-    score_guard, extras)``.
+def _make_generic_fused_core(skey, terms, lb, metric: str, xp, lax):
+    """Fused admit+score core: ``core(tt, st, perm, incumbent, p) ->
+    (admit, lb_guard, latency, energy, util, score_guard, extras)``.
 
-    The calibration scale enters as the traced ``p['calib_scale']``
-    parameter (1.0 when uncalibrated -- ``x * 1.0`` is bit-exact, so the
-    uncalibrated program matches the unscaled per-context path and ONE
-    compiled program serves every calibration value). Extras additionally
-    carry the raw admission-bound arrays (``lb_cycles`` / ``lb_energy``,
-    already calibrated) and the scalarized ``metric_score`` so
-    device-resident loops can replay admission and selection host-side
-    without a second dispatch.
+    ``terms`` and ``lb`` come from ``CostModel.batch_cost_terms`` (``lb``
+    None: the hierarchical bound). Both guard maxes come back so the host
+    can fall back exactly where the two-stage path would (lb guard ->
+    scalar bound; score guard -> numpy/scalar scoring of the admitted
+    subset). The calibration scale enters as the traced
+    ``p['calib_scale']`` (1.0 when uncalibrated -- ``x * 1.0`` is
+    bit-exact, so ONE compiled program serves every calibration value).
+    Extras additionally carry the admission-bound arrays (``lb_cycles`` /
+    ``lb_energy``, already calibrated) and the scalarized
+    ``metric_score`` so device-resident loops can replay admission and
+    selection host-side without a second dispatch.
     """
-    lb_core = _make_generic_lb_core(skey, xp, lax)
+    lb_core = (lb or _make_generic_lb_core)(skey, xp, lax)
     traffic_core = _make_generic_batch_core(skey, xp, lax)
     scalarize = _generic_scalarize(metric, xp)
 
@@ -2119,17 +1820,15 @@ def _make_generic_fused_core(skey, terms, metric: str, xp, lax):
     return core
 
 
-class GenericFusedRunner:
+class FusedRunner:
     """Dispatch handle for one (context, model, metric) over a SHARED
-    shape-generic compiled program: the program lives in the process-wide
+    compiled fused program: the program lives in the process-wide
     ``_GENERIC_PROGRAMS`` cache keyed by (shape class, model structure,
     metric); this object carries the context's parameter pack (uploaded
-    to device once, lazily) and implements the same ``(sb, incumbent) ->
-    7-tuple or None`` protocol as ``build_fused_runner``'s closures, plus
-    the device-resident extensions the search loops use
-    (:meth:`dispatch_device`, :meth:`is_traced`)."""
-
-    supports_precompute = True
+    to device once, lazily). ``runner(sb, incumbent)`` returns the host
+    7-tuple (see :func:`_fused_to_host`) or None after a device fault;
+    :meth:`dispatch_device` and :meth:`is_traced` serve the
+    device-resident search loops and the engine's warmup."""
 
     def __init__(self, ctx, core, params, pkey, donates: bool) -> None:
         self._ctx = ctx
@@ -2139,10 +1838,6 @@ class GenericFusedRunner:
         self._donates = donates
         self._dev_params = None
         self._dev_inf = None  # cached device scalar for incumbent=inf
-
-    @property
-    def program_key(self):
-        return self._pkey
 
     def is_traced(self, padded_batch: int) -> bool:
         """Whether the shared program has already been traced at this
@@ -2223,10 +1918,11 @@ def _fused_to_host(out, B: int):
 # equal cost-relevant content produce bit-identical analyses), so the many
 # content-equal instances a figure sweep builds -- dnn_layers() re-invoked
 # per benchmark, repeated accelerator constructors -- all alias ONE
-# context, sharing its numpy cores, jitted programs, fused runners and
-# footprint memos instead of re-tracing per instance. Digests are memoized
-# on the objects themselves (falling back to recomputation for immutable
-# types).
+# context, sharing its parameter packs (and their device copies), fused
+# runners and footprint memos instead of rebuilding them per instance;
+# the array programs themselves are shared wider still, per shape class
+# (``_GENERIC_PROGRAMS``). Digests are memoized on the objects themselves
+# (falling back to recomputation for immutable types).
 # ---------------------------------------------------------------------- #
 _CTX_CACHE: "OrderedDict[Tuple[int, int], Tuple[Problem, Architecture, AnalysisContext]]" = (
     OrderedDict()
@@ -2301,85 +1997,6 @@ def hierarchical_lower_bound(
     if sig is None:
         sig = mapping_signature(mapping, ctx.dims)
     return ctx.signature_lower_bound(sig)
-
-
-def batch_hierarchical_energy(
-    ctx: AnalysisContext,
-    arch: Architecture,
-    problem: Problem,
-    bt: BatchTraffic,
-    hop_pj_byte: Optional[float] = None,
-    xp=np,
-):
-    """Shared level-walk energy accumulation for the hierarchical models'
-    ``evaluate_signature_batch`` (timeloop_like and maestro_like run the
-    identical sequence of float operations here; maestro additionally
-    accumulates the NoC delivery term, enabled via ``hop_pj_byte``).
-
-    ``xp`` selects the array stack: numpy for host-side scoring, jax.numpy
-    when the walk runs inside the fused single-dispatch jitted core (the
-    per-element float-operation order is identical either way).
-
-    Returns ``(energy[B], noc_energy[B] or None, mac_term, mx)`` where
-    ``energy`` already includes the innermost-operand and MAC terms (the
-    scalar paths add them in exactly this order) and ``mx`` is an xp
-    scalar holding the max of every guarded integer-valued product (the
-    caller folds it into its BATCH_EXACT_LIMIT check host-side). NoC
-    energy is NOT folded into ``energy`` -- maestro adds it after the MAC
-    term, as its scalar path does.
-    """
-    clusters = arch.clusters
-    real_levels = ctx.real_levels
-    real_parent = ctx.real_parent
-    leaf = clusters[-1]
-    inst_at = bt.inst_at
-    mx = xp.zeros(())
-    # The access-count products (t) are integer-valued and exact, but the
-    # per-byte energies are fractional: each ``t * energy`` product must be
-    # ROUNDED before it joins the accumulator, exactly as numpy does.
-    # Addends are collected and summed through :func:`ordered_sum`, whose
-    # scan structure stops XLA's LLVM backend from contracting
-    # ``acc + t * e`` into an FMA (one rounding instead of two) on the
-    # fused jitted path.
-    e_terms = []
-    noc_terms = [] if hop_pj_byte is not None else None
-    for k, ds in enumerate(problem.data_spaces):
-        wb = ds.word_bytes
-        r = bt.rows[k]
-        for pos, i in enumerate(real_levels):
-            cl = clusters[i]
-            t = r.fills[:, pos] * inst_at[:, i] * wb
-            mx = xp.maximum(mx, xp.max(t))
-            e_terms.append(t * cl.write_energy)
-            t = r.drains[:, pos] * inst_at[:, i] * wb
-            mx = xp.maximum(mx, xp.max(t))
-            e_terms.append(t * cl.read_energy)
-            parent_idx = real_parent[i]
-            if parent_idx is not None:
-                parent = clusters[parent_idx]
-                n_parent = inst_at[:, parent_idx]
-                t = r.parent_reads[:, pos] * n_parent * wb
-                mx = xp.maximum(mx, xp.max(t))
-                e_terms.append(t * parent.read_energy)
-                t = r.parent_writes[:, pos] * n_parent * wb
-                mx = xp.maximum(mx, xp.max(t))
-                e_terms.append(t * parent.write_energy)
-                if noc_terms is not None:
-                    # every DELIVERED copy pays a NoC hop (multicast reads
-                    # the parent once; see maestro_like)
-                    t = (r.fills[:, pos] + r.drains[:, pos]) * inst_at[:, i] * wb
-                    mx = xp.maximum(mx, xp.max(t))
-                    noc_terms.append(t * hop_pj_byte)
-        e_terms.append(ctx.l1_reads[ds.name] * wb * leaf.read_energy)
-    mac_term = problem.macs * leaf.mac_energy
-    e_terms.append(mac_term)
-    energy = ordered_sum(xp, xp.zeros_like(bt.compute_cycles), e_terms)
-    noc_energy = (
-        ordered_sum(xp, xp.zeros_like(energy), noc_terms)
-        if noc_terms is not None
-        else None
-    )
-    return energy, noc_energy, mac_term, mx
 
 
 def boundary_bytes_per_instance(

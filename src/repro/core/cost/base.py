@@ -3,11 +3,15 @@
 from __future__ import annotations
 
 import abc
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from repro.core.architecture import Architecture
+from repro.core.cost.analysis import BATCH_EXACT_LIMIT, get_context
 from repro.core.mapping import Mapping
 from repro.core.problem import Problem
 
@@ -62,20 +66,19 @@ class CostModel(abc.ABC):
     multiplies every latency prediction by that scale as the FINAL
     operation of EVERY path -- scalar (``evaluate``,
     ``evaluate_signature``, the ``lower_bound*`` family) and vectorized
-    (``lower_bound_batch_fn``, ``batch_admit_core_builder``,
-    ``batch_cost_terms_fn``, ``evaluate_signature_batch``,
-    ``batch_cost_terms_generic``) alike. A uniform positive final
-    multiply keeps the admission invariant (bound <= evaluate, since
-    IEEE multiply by the same positive factor is monotone) and never
-    changes which mapping is argmin; and because the batch paths apply
-    the IDENTICAL final ``latency * scale`` per element, the calibrated
-    batch results stay bit-identical to the calibrated scalar path
-    (same two float64 operands, same single rounding). The shape-generic
-    path traces the scale as a parameter (1.0 when uncalibrated --
-    ``x * 1.0`` is IEEE-exact), so one compiled program serves every
-    calibration value. ``store_key_parts()`` includes
-    ``calibration_key_parts()``, so calibrated and raw results never
-    alias in a ResultStore.
+    (``lower_bound_batch_fn``, ``evaluate_signature_batch`` and the fused
+    jax core, all driven by :meth:`batch_cost_terms`) alike. A uniform
+    positive final multiply keeps the admission invariant (bound <=
+    evaluate, since IEEE multiply by the same positive factor is
+    monotone) and never changes which mapping is argmin; and because the
+    batch paths apply the IDENTICAL final ``latency * scale`` per
+    element, the calibrated batch results stay bit-identical to the
+    calibrated scalar path (same two float64 operands, same single
+    rounding). The batch paths read the scale from the parameter pack
+    (``calib_scale``, 1.0 when uncalibrated -- ``x * 1.0`` is
+    IEEE-exact), so one compiled program serves every calibration value.
+    ``store_key_parts()`` includes ``calibration_key_parts()``, so
+    calibrated and raw results never alias in a ResultStore.
     """
 
     name: str = "base"
@@ -175,15 +178,20 @@ class CostModel(abc.ABC):
         return None
 
     def lower_bound_batch_fn(self, problem: Problem, arch: Architecture):
-        """Optional vectorized admission bound: a closure
-        ``(sigs, backend=..., stacked=...) -> Optional[(cycles[B],
-        energy_pj[B]))`` producing, for every signature of a stacked batch,
-        exactly the values ``lower_bound_fn`` produces per candidate (the
-        engine admits a whole miss-batch with one masked array program).
-        Implementations MUST return None whenever bit-identity with the
-        scalar bound cannot be guaranteed (the engine then falls back to
-        the per-candidate bound). None when unsupported."""
-        return None
+        """Vectorized admission bound: a closure ``(sigs, backend=...,
+        stacked=...) -> Optional[(cycles[B], energy_pj[B])]`` producing,
+        for every signature of a stacked batch, exactly the values
+        ``lower_bound_fn`` produces per candidate (the engine admits a
+        whole miss-batch with one masked array program). It runs the
+        bound of :meth:`batch_cost_terms` over the model's parameter pack
+        and returns None whenever bit-identity with the scalar bound
+        cannot be guaranteed (the engine then falls back to the
+        per-candidate bound). None when the model has no such hook."""
+        model = self.batch_cost_terms(problem, arch)
+        if model is None:
+            return None
+        ctx = get_context(problem, arch)
+        return functools.partial(ctx.lower_bound_batch, model=model)
 
     def evaluate_signature(
         self, problem: Problem, arch: Architecture, sig
@@ -208,62 +216,63 @@ class CostModel(abc.ABC):
         ``evaluate``) would produce for every signature in ``sigs``,
         computed as one array program over the stacked batch.
 
-        ``backend`` selects the array stack (``"numpy"`` or ``"jax"``).
-        ``stacked``/``select`` let the evaluation engine share the
-        admission stage's already-stacked (device-resident, on jax)
-        ``StackedBatch`` and score only the admitted row indices; ``sigs``
-        must then be the corresponding subset, in ``select`` order.
-        Return None when unsupported OR when exactness cannot be
-        guaranteed for this batch (values beyond the float64-exact integer
-        range) -- the engine then falls back to per-candidate evaluation.
-        Implementations MUST be bit-identical to the scalar path whenever
-        they return a result."""
-        return None
+        ``backend`` selects the traffic program (``"numpy"`` or ``"jax"``);
+        the cost terms of :meth:`batch_cost_terms` then run with numpy over
+        the model's parameter pack. ``stacked``/``select`` let the
+        evaluation engine share the admission stage's already-stacked
+        (device-resident, on jax) ``StackedBatch`` and score only the
+        admitted row indices; ``sigs`` must then be the corresponding
+        subset, in ``select`` order. Returns None when the model has no
+        such hook OR when exactness cannot be guaranteed for this batch
+        (values beyond the float64-exact integer range) -- the engine then
+        falls back to per-candidate evaluation. Bit-identical to the
+        scalar path whenever it returns a result."""
+        model = self.batch_cost_terms(problem, arch)
+        if model is None:
+            return None
+        ctx = get_context(problem, arch)
+        bt = ctx.signature_traffic_batch(
+            sigs, backend=backend, stacked=stacked, select=select
+        )
+        if bt is None:
+            return None
+        p = ctx.model_pack(model.params)
+        latency, energy, util, mx, extras = model.terms(bt, np, p)
+        if not (float(mx) < BATCH_EXACT_LIMIT):
+            return None
+        latency = latency * p["calib_scale"]
+        return self.costs_from_batch(problem, arch, latency, energy, util, extras)
 
-    def batch_admit_core_builder(self, problem: Problem, arch: Architecture):
-        """Optional traceable admission-bound core builder for the fused
-        single-dispatch pipeline: an ``(xp, lax=None) -> core`` callable
-        where ``core(tt, st, perm) -> (cycles[B], energy_pj[B], guard)``
-        reproduces ``lower_bound_fn`` per row bit-identically (``guard``
-        is the running max of every guarded integer-valued quantity; the
-        host rejects the dispatch at BATCH_EXACT_LIMIT). The hierarchical
-        models return ``AnalysisContext._make_lb_core``; None disables the
-        fused path for this model."""
-        return None
+    def batch_cost_terms(self, problem: Problem, arch: Architecture):
+        """This model's array program, an ``analysis.BatchCostTerms``, or None
+        when it has none (the engine then scores per candidate and keeps
+        the two-stage flow). Its fields:
 
-    def batch_cost_terms_fn(self, problem: Problem, arch: Architecture):
-        """Optional array-program cost terms: a traceable closure
-        ``terms(bt: BatchTraffic, xp) -> (latency[B], energy_pj[B],
-        util[B], guard, extras)`` accumulating this model's latency/energy
-        over the stacked traffic with ``xp`` ops only (numpy host-side,
-        jax.numpy inside the fused jitted core -- the per-row float-op
-        order must equal ``evaluate_signature``'s). ``guard`` is an xp
-        scalar (max of guarded integer-valued products, checked host-side
-        against BATCH_EXACT_LIMIT); ``extras`` is a str->array[B] dict
-        carrying whatever :meth:`costs_from_batch` needs to rebuild
-        breakdown dicts. None when unsupported (disables both the shared
-        numpy scoring program and the fused jax path)."""
-        return None
-
-    def batch_cost_terms_generic(self, problem: Problem, arch: Architecture):
-        """Optional SHAPE-GENERIC cost terms for the process-wide trace
-        cache: ``(model_struct_key, model_params, terms)`` or None.
-
-        ``model_struct_key`` is a hashable tuple of every STRUCTURAL
-        property the terms program branches on (it joins the
-        ``AnalysisContext.shape_class_key()`` in the compiled-program
-        key); ``model_params`` is a dict of numpy arrays/scalars merged
-        into the context's ``shape_params()`` pack and passed as a traced
-        argument; ``terms(bt, xp, p)`` mirrors
-        :meth:`batch_cost_terms_fn`'s closure but reads every VALUE from
-        ``p`` instead of Python closure constants, so one jitted program
-        serves every (problem, arch) pair with equal keys (the closure of
-        the FIRST such pair gets traced; it must not capture values that
-        can differ within the key class). ``model_params`` must include
-        ``calib_scale`` (1.0 when uncalibrated) -- the generic fused core
-        applies it as the final latency multiply. None when unsupported;
-        the engine then falls back to the per-context
-        :meth:`batch_cost_terms_fn` pipeline."""
+        * ``key`` -- a hashable tuple of every STRUCTURAL property
+          ``terms`` and ``lb`` branch on; it joins the
+          ``AnalysisContext.shape_class_key()`` in the program key, so one
+          compiled program serves every (problem, arch) pair with equal
+          keys (the closures of the FIRST such pair get traced: they must
+          not capture values that can differ within the key class);
+        * ``params`` -- a dict of numpy arrays/scalars joined with
+          the context's ``shape_params()`` into the parameter pack ``p``;
+          it must include ``calib_scale`` (1.0 when uncalibrated), which
+          every batch path applies as the final latency multiply;
+        * ``terms(bt: BatchTraffic, xp, p) -> (latency[B], energy_pj[B],
+          util[B], guard, extras)`` -- the model's latency/energy
+          accumulation over the stacked traffic with ``xp`` ops only
+          (numpy host-side, jax.numpy inside the fused jitted core), in
+          ``evaluate_signature``'s per-row float-op order and before the
+          calibration multiply. ``guard`` is an xp scalar (max of guarded
+          integer-valued products, checked host-side against
+          BATCH_EXACT_LIMIT); ``extras`` is a str->array[B] dict carrying
+          whatever :meth:`costs_from_batch` needs to rebuild breakdowns;
+        * ``lb`` -- None when the admission bound is the hierarchical one
+          (``lower_bound_fn`` is ``AnalysisContext.signature_lower_bound``);
+          otherwise a builder ``lb(skey, xp, lax) -> core`` whose
+          ``core(tt, st, perm, p) -> (cycles[B], energy_pj[B], guard)``
+          reproduces ``lower_bound_fn`` per row, before calibration.
+        """
         return None
 
     def costs_from_batch(
@@ -277,7 +286,8 @@ class CostModel(abc.ABC):
         indices=None,
     ) -> List[Cost]:
         """Materialize Cost objects (scalar-path breakdown layout
-        included) from :meth:`batch_cost_terms_fn` output arrays --
+        included) from the output arrays of :meth:`batch_cost_terms`
+        (latency already calibrated) --
         ``indices`` restricts materialization to the given rows (the
         engine's fused path builds Costs only for ADMITTED candidates)."""
         raise NotImplementedError

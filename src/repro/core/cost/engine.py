@@ -94,7 +94,7 @@ class _FusedOutcome(NamedTuple):
 
 
 class PrecomputedScores:
-    """Host-materialized results of one mega-batch generic-fused dispatch
+    """Host-materialized results of one mega-batch fused dispatch
     (see ``repro.core.device_loop``): per-row admission-bound and score
     arrays for one :class:`GenomeBatch`, in row order. ``_serve_order``
     consumes them in place of a dispatch -- admission is recomputed
@@ -909,36 +909,22 @@ class EvaluationEngine:
 
     def _get_fused_runner(self):
         """Lazily build (and memoize) the single-dispatch jitted
-        admit+score runner for this (model, metric). None when the model
-        does not provide traceable bound/terms programs or JAX cannot
-        deliver float64 -- the engine then keeps the two-stage flow."""
+        admit+score runner for this (model, metric): one process-wide
+        compiled program serves every (problem, arch) of this shape class,
+        so engines after the first trace nothing at all. None when the
+        model has no ``batch_cost_terms`` or JAX failed -- the engine then
+        keeps the two-stage flow."""
         if self._fused_failed:
             return None
         if self._fused_runner is None:
-            cache_key = (repr(self.cost_model.store_key_parts()), self.metric)
-            # shape-generic first: one process-wide compiled program serves
-            # every (problem, arch) of this shape class, so engines after
-            # the first trace nothing at all
-            generic = self.cost_model.batch_cost_terms_generic(
-                self.problem, self.arch
-            )
-            if generic is not None:
-                runner = self._ctx.build_generic_fused_runner(
-                    generic, self.metric, cache_key=cache_key
+            model = self.cost_model.batch_cost_terms(self.problem, self.arch)
+            runner = None
+            if model is not None:
+                runner = self._ctx.build_fused_runner(
+                    model,
+                    self.metric,
+                    cache_key=(repr(self.cost_model.store_key_parts()), self.metric),
                 )
-                if runner is not None:
-                    self._fused_runner = runner
-                    return runner
-            terms = self.cost_model.batch_cost_terms_fn(self.problem, self.arch)
-            lb_builder = self.cost_model.batch_admit_core_builder(
-                self.problem, self.arch
-            )
-            if terms is None or lb_builder is None:
-                self._fused_failed = True
-                return None
-            runner = self._ctx.build_fused_runner(
-                lb_builder, terms, self.metric, cache_key=cache_key
-            )
             if runner is None:
                 self._fused_failed = True
                 return None
@@ -972,16 +958,14 @@ class EvaluationEngine:
                 if b and int(b) >= _BATCH_MIN
             }
         )
-        # shape-generic runners consult the process-wide trace registry:
         # a bucket already traced for this shape class (by this engine, a
         # prior engine, or a prior warmup) is skipped -- one warmup covers
         # the whole class
-        is_traced = getattr(runner, "is_traced", None)
         done = 0
         before = global_trace_count()
         try:
             for b in buckets:
-                if is_traced is not None and is_traced(b):
+                if runner.is_traced(b):
                     continue
                 tt = np.ones((b, n, D), dtype=np.int64)
                 st = np.ones((b, n, D), dtype=np.int64)

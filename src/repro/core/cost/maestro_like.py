@@ -20,15 +20,13 @@ bracket reality, which is exactly why Union makes them swappable):
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import numpy as np
 
 from repro.core.architecture import Architecture
 from repro.core.cost.analysis import (
-    BATCH_EXACT_LIMIT,
+    BatchCostTerms,
     analyze,
-    batch_hierarchical_energy,
     boundary_bytes_per_instance,
     exact_divisor,
     generic_hierarchical_energy,
@@ -74,118 +72,17 @@ class MaestroLikeModel(CostModel):
             fn(chain_list, orders)
         )
 
-    def lower_bound_batch_fn(self, problem: Problem, arch: Architecture):
-        fn = get_context(problem, arch).lower_bound_batch
-        if self.calibration is None:
-            return fn
-        # same final multiply as the scalar ``_calibrate_bound`` per
-        # element, so calibrated batch admission stays bit-identical
-        s = float(self.calibration.scale)
-
-        def calibrated(*args, **kwargs):
-            out = fn(*args, **kwargs)
-            if out is None:
-                return None
-            cyc, en = out
-            return cyc * s, en
-
-        return calibrated
-
-    def batch_admit_core_builder(self, problem: Problem, arch: Architecture):
-        builder = get_context(problem, arch)._make_lb_core
-        if self.calibration is None:
-            return builder
-        s = float(self.calibration.scale)
-
-        def calibrated_builder(xp, lax=None):
-            core = builder(xp, lax)
-
-            def calibrated_core(tt, st, perm):
-                cyc, en, mx = core(tt, st, perm)
-                return cyc * s, en, mx
-
-            return calibrated_core
-
-        return calibrated_builder
-
     def store_key_parts(self):
         return (self.name, self.etab) + self.calibration_key_parts()
 
-    def batch_cost_terms_fn(self, problem: Problem, arch: Architecture):
-        """Array-program twin of ``evaluate_signature``'s latency/energy
-        accumulation (double-buffered schedule + startup + NoC delivery
-        term): same float-op order per row with numpy or jax.numpy. A
-        calibration scale is applied as the final latency multiply,
-        exactly as ``apply_calibration`` does on the scalar path. See
-        ``CostModel.batch_cost_terms_fn``."""
-        if not self.conformable(problem):
-            return None
-        cal_s = (
-            float(self.calibration.scale) if self.calibration is not None else None
-        )
-        ctx = get_context(problem, arch)
-        freq = arch.frequency_hz
-        clusters = arch.clusters
-        real_levels = ctx.real_levels
-        spaces = problem.data_spaces
-        num_pes = ctx.num_pes
-        hop = self.etab.noc_hop_pj_byte
-
-        def terms(bt, xp):
-            cc = bt.compute_cycles
-            # par is guarded too: utilization must match the scalar path's
-            # exact-int parallelism bit for bit
-            mx = xp.maximum(
-                xp.maximum(xp.max(cc), xp.max(bt.total_trips)), xp.max(bt.par)
-            )
-            latency = cc
-            startup = xp.zeros_like(cc)
-            extras = {"compute_cycles": cc}
-            for pos, i in enumerate(real_levels):
-                if i == 0:
-                    continue
-                cl = clusters[i]
-                if math.isinf(cl.fill_bandwidth):
-                    continue
-                total_fill = xp.zeros_like(cc)
-                tile_bytes = xp.zeros_like(cc)
-                for k, ds in enumerate(spaces):
-                    r = bt.rows[k]
-                    t = (r.fills[:, pos] + r.drains[:, pos]) * ds.word_bytes
-                    mx = xp.maximum(mx, xp.max(t))
-                    total_fill = total_fill + t
-                    tile_bytes = tile_bytes + r.foot[:, pos] * ds.word_bytes
-                mx = xp.maximum(mx, xp.max(tile_bytes))
-                valid = total_fill > 0
-                bw = exact_divisor(xp, cl.fill_bandwidth)
-                fill_cycles = total_fill * freq / bw
-                startup = startup + xp.where(
-                    valid, tile_bytes * freq / bw, 0.0
-                )
-                extras[f"fill_cycles::{i}"] = fill_cycles
-                extras[f"fill_valid::{i}"] = valid
-                latency = xp.where(valid, xp.maximum(latency, fill_cycles), latency)
-            latency = latency + startup
-            energy, noc_energy, _mac, e_mx = batch_hierarchical_energy(
-                ctx, arch, problem, bt, hop_pj_byte=hop, xp=xp
-            )
-            mx = xp.maximum(mx, e_mx)
-            energy = energy + noc_energy
-            extras["startup_cycles"] = startup
-            extras["noc_energy_pj"] = noc_energy
-            util = bt.par / exact_divisor(xp, num_pes)
-            if cal_s is not None:
-                latency = latency * cal_s
-            return latency, energy, util, mx, extras
-
-        return terms
-
-    def batch_cost_terms_generic(self, problem: Problem, arch: Architecture):
-        """Shape-generic twin of :meth:`batch_cost_terms_fn` (see
-        ``CostModel.batch_cost_terms_generic``): structure = which real
-        levels carry a finite-bandwidth fill/startup term; bandwidths,
-        energies, the NoC hop cost and the calibration scale ride in the
-        parameter pack."""
+    def batch_cost_terms(self, problem: Problem, arch: Architecture):
+        """``evaluate_signature``'s latency/energy accumulation
+        (double-buffered schedule + startup + NoC delivery term) as an
+        array program (see ``CostModel.batch_cost_terms``): structure =
+        which real levels carry a finite-bandwidth fill/startup term;
+        bandwidths, energies, the NoC hop cost and the calibration scale
+        ride in the parameter pack. The admission bound is the
+        hierarchical one."""
         if not self.conformable(problem):
             return None
         ctx = get_context(problem, arch)
@@ -227,6 +124,8 @@ class MaestroLikeModel(CostModel):
 
         def terms(bt, xp, p):
             cc = bt.compute_cycles
+            # par is guarded too: utilization must match the scalar path's
+            # exact-int parallelism bit for bit
             mx = xp.maximum(
                 xp.maximum(xp.max(cc), xp.max(bt.total_trips)), xp.max(bt.par)
             )
@@ -263,7 +162,7 @@ class MaestroLikeModel(CostModel):
             util = bt.par / exact_divisor(xp, p["num_pes"])
             return latency, energy, util, mx, extras
 
-        return model_key, model_params, terms
+        return BatchCostTerms(model_key, model_params, terms, None)
 
     def costs_from_batch(
         self, problem, arch, latency, energy, util, extras, indices=None
@@ -292,8 +191,8 @@ class MaestroLikeModel(CostModel):
             breakdown["startup_cycles"] = float(startup[b])
             breakdown["noc_energy_pj"] = float(noc[b])
             if cal_s is not None:
-                # latency is already scaled inside the terms program; the
-                # breakdown records the scale exactly like apply_calibration
+                # latency arrives calibrated; the breakdown records the
+                # scale exactly like apply_calibration
                 breakdown["calibration_scale"] = cal_s
             out.append(
                 Cost(
@@ -383,40 +282,6 @@ class MaestroLikeModel(CostModel):
             frequency_hz=freq,
             breakdown=breakdown,
         ))
-
-    def evaluate_signature_batch(
-        self,
-        problem: Problem,
-        arch: Architecture,
-        sigs,
-        backend: str = "numpy",
-        stacked=None,
-        select=None,
-    ):
-        """Vectorized ``evaluate_signature`` over a whole miss-batch (same
-        float-operation order per candidate; bit-identical results, with a
-        BATCH_EXACT_LIMIT guard that falls back to the scalar path). The
-        latency/energy accumulation is the SAME array program the fused
-        jitted single-dispatch path traces (``batch_cost_terms_fn``), run
-        here with numpy over the admitted subset. ``stacked``/``select``
-        reuse the engine's admission-stage StackedBatch (see
-        ``CostModel.evaluate_signature_batch``)."""
-        if not self.conformable(problem):
-            raise ValueError(
-                f"{self.name} only supports operations {_SUPPORTED_OPS}, "
-                f"got {problem.operation!r} (unit op {problem.unit_op!r})"
-            )
-        ctx = get_context(problem, arch)
-        bt = ctx.signature_traffic_batch(
-            sigs, backend=backend, stacked=stacked, select=select
-        )
-        if bt is None:
-            return None
-        terms = self.batch_cost_terms_fn(problem, arch)
-        latency, energy, util, mx, extras = terms(bt, np)
-        if not (float(mx) < BATCH_EXACT_LIMIT):
-            return None  # exactness not guaranteed: use the scalar path
-        return self.costs_from_batch(problem, arch, latency, energy, util, extras)
 
     def evaluate(self, problem: Problem, mapping: Mapping, arch: Architecture) -> Cost:
         if not self.conformable(problem):

@@ -23,13 +23,13 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, Optional
+from typing import Dict
 
 import numpy as np
 
 from repro.core.architecture import Architecture, TPU_V5E
 from repro.core.cost.analysis import (
-    BATCH_EXACT_LIMIT,
+    BatchCostTerms,
     analyze,
     batch_projection_footprint,
     boundary_bytes_per_instance,
@@ -159,101 +159,26 @@ class TPURooflineModel(CostModel):
         energy = problem.macs * arch.clusters[-1].mac_energy
         return self._calibrate_bound((cycles, energy))
 
-    def batch_admit_core_builder(self, problem: Problem, arch: Architecture):
-        """Traceable form of the roofline admission bound (perfect chip
-        scaling + compulsory VMEM traffic): an ``(xp, lax=None) -> core``
-        builder whose core reproduces ``lower_bound`` per row bit-for-bit
-        with numpy or inside the fused jitted program. A calibration scale
-        is applied to the cycles as the same final multiply the scalar
-        ``_calibrate_bound`` performs."""
-        cal_s = (
-            float(self.calibration.scale) if self.calibration is not None else None
-        )
+    def batch_cost_terms(self, problem: Problem, arch: Architecture):
+        """``evaluate``'s three-term roofline as an array program, with
+        its own admission bound (perfect chip scaling + compulsory VMEM
+        traffic, as ``lower_bound``); see ``CostModel.batch_cost_terms``.
+        VMEM boundary traffic comes from the shared batch analysis, chip
+        utilization and collective terms from the stacked fan/tile
+        matrices. Structure -- the mesh levels, whether VMEM is a real
+        level and its position, the reduction and relevant dims, the
+        output flags -- goes in the key; peak, HBM and link bandwidth,
+        the chip count, the MAC energy term, the PE count and the
+        compute-time constant ride in the parameter pack."""
         ctx = get_context(problem, arch)
         peak = float(arch.attrs.get("peak_bf16_flops", TPU_V5E["peak_bf16_flops"]))
-        hbm_bw = float(arch.attrs.get("hbm_bw", TPU_V5E["hbm_bw"]))
-        chips = 1
-        for cl in arch.clusters:
-            if cl.dimension in MESH_AXES and cl.fanout > 1:
-                chips *= cl.fanout
-        compute_s = 2.0 * problem.macs / max(1, chips) / peak
-        vmem_level = arch.n_levels - 1
-        vmem_real = vmem_level in ctx.real_levels
-        freq = arch.frequency_hz
-        energy_const = problem.macs * arch.clusters[-1].mac_energy
-        axes_info = ctx.ds_projection_axes
-
-        def build(xp, lax=None):
-            def core(tt, st, perm):
-                B = tt.shape[0]
-                mx = xp.zeros(())
-                memory_s = xp.zeros(B, dtype=xp.float64)
-                if vmem_real:
-                    ttf = xp.maximum(tt[:, vmem_level, :], 1).astype(xp.float64)
-                    total = xp.zeros(B, dtype=xp.float64)
-                    for wb, axes, _rel in axes_info:
-                        t = batch_projection_footprint(axes, ttf, xp) * wb
-                        mx = xp.maximum(mx, xp.max(t))
-                        total = total + t
-                    memory_s = total / exact_divisor(xp, hbm_bw)
-                cycles = xp.maximum(compute_s, memory_s) * freq
-                if cal_s is not None:
-                    cycles = cycles * cal_s
-                return cycles, xp.full(B, energy_const, dtype=xp.float64), mx
-
-            return core
-
-        return build
-
-    def lower_bound_batch_fn(self, problem: Problem, arch: Architecture):
-        """Vectorized ``lower_bound``: one array program reproduces the
-        scalar bound (perfect chip scaling + compulsory VMEM traffic) for
-        a whole stacked batch, bit-identically -- or returns None beyond
-        the float64-exact range so the engine falls back per candidate.
-        Runs the same core the fused jitted path traces, with numpy (the
-        admit core already carries the calibration multiply)."""
-        ctx = get_context(problem, arch)
-        core = self.batch_admit_core_builder(problem, arch)(np)
-
-        def lb_batch(sigs=None, backend: str = "numpy", stacked=None):
-            sb = stacked
-            if sb is None:
-                if not sigs:
-                    return None
-                sb = ctx.stacked_batch(sigs)
-            if sb.size == 0:
-                return None
-            cycles, energy, mx = core(sb.tt, sb.st, sb.perm)
-            if not (float(mx) < BATCH_EXACT_LIMIT):
-                return None
-            return cycles, energy
-
-        return lb_batch
-
-    def batch_cost_terms_fn(self, problem: Problem, arch: Architecture):
-        """Array-program twin of ``evaluate``'s three-term roofline: VMEM
-        boundary traffic from the shared batch analysis, chip utilization
-        and collective terms from the stacked fan/tile matrices. Same
-        float-operation order per row with numpy or jax.numpy; a
-        calibration scale is applied as the final latency multiply, exactly
-        as ``apply_calibration`` does on the scalar path. See
-        ``CostModel.batch_cost_terms_fn``."""
-        cal_s = (
-            float(self.calibration.scale) if self.calibration is not None else None
-        )
-        ctx = get_context(problem, arch)
-        peak = float(arch.attrs.get("peak_bf16_flops", TPU_V5E["peak_bf16_flops"]))
-        hbm_bw = float(arch.attrs.get("hbm_bw", TPU_V5E["hbm_bw"]))
-        link_bw = float(arch.attrs.get("ici_link_bw", TPU_V5E["ici_link_bw"]))
-        freq = arch.frequency_hz
-        mac_term = problem.macs * arch.clusters[-1].mac_energy
-        num_pes = max(1, arch.num_pes)
         chips = 1
         mesh_levels = []
         for i, cl in enumerate(arch.clusters):
             if cl.dimension in MESH_AXES and cl.fanout > 1:
                 chips *= cl.fanout
                 mesh_levels.append(i)
+        mesh_levels = tuple(mesh_levels)
         vmem_level = arch.n_levels - 1
         vmem_real = vmem_level in ctx.real_levels
         pos_v = ctx.real_levels.index(vmem_level) if vmem_real else -1
@@ -261,11 +186,52 @@ class TPURooflineModel(CostModel):
         red_idx = np.asarray(
             [j for j, d in enumerate(ctx.dims) if d in red], dtype=np.int64
         )
-        axes_info = ctx.ds_projection_axes
-        ds_out = [ds.is_output for ds in problem.data_spaces]
-        word_bytes = [ds.word_bytes for ds in problem.data_spaces]
+        rel_idx = tuple(rel for _wb, _axes, rel in ctx.ds_projection_axes)
+        ds_out = tuple(ds.is_output for ds in problem.data_spaces)
+        K = len(ds_out)
+        layout = ctx.coeff_layout()
+        cal = self.calibration
+        model_key = (
+            self.name, mesh_levels, vmem_real, pos_v, tuple(red_idx), rel_idx, ds_out
+        )
+        model_params = {
+            "rl_peak": np.float64(peak),
+            "rl_hbm_bw": np.float64(
+                arch.attrs.get("hbm_bw", TPU_V5E["hbm_bw"])
+            ),
+            "rl_link_bw": np.float64(
+                arch.attrs.get("ici_link_bw", TPU_V5E["ici_link_bw"])
+            ),
+            "rl_chips": np.float64(chips),
+            "rl_flops": np.float64(2.0 * problem.macs),
+            # the bound's compute term, as the scalar bound computes it
+            "rl_compute_s": np.float64(2.0 * problem.macs / max(1, chips) / peak),
+            "mac_term": np.float64(problem.macs * arch.clusters[-1].mac_energy),
+            "num_pes": np.float64(max(1, arch.num_pes)),
+            "calib_scale": np.float64(1.0 if cal is None else cal.scale),
+        }
 
-        def terms(bt, xp):
+        def lb(_skey, xp, lax=None):
+            def core(tt, st, perm, p):
+                B = tt.shape[0]
+                mx = xp.zeros(())
+                memory_s = xp.zeros(B, dtype=xp.float64)
+                if vmem_real:
+                    ttf = xp.maximum(tt[:, vmem_level, :], 1).astype(xp.float64)
+                    total = xp.zeros(B, dtype=xp.float64)
+                    for k in range(K):
+                        t = batch_projection_footprint(
+                            layout[k], ttf, xp, p["coeffs"]
+                        ) * p["wb"][k]
+                        mx = xp.maximum(mx, xp.max(t))
+                        total = total + t
+                    memory_s = total / exact_divisor(xp, p["rl_hbm_bw"])
+                cycles = xp.maximum(p["rl_compute_s"], memory_s) * p["freq"]
+                return cycles, xp.full(B, p["mac_term"], dtype=xp.float64), mx
+
+            return core
+
+        def terms(bt, xp, p):
             B = bt.compute_cycles.shape[0]
             # par is guarded too: utilization must match the scalar path's
             # exact-int parallelism bit for bit
@@ -277,18 +243,18 @@ class TPURooflineModel(CostModel):
             for i in mesh_levels:
                 if i > 0:
                     used_chips = used_chips * lvl_par[:, i - 1]
-            used_chips = xp.maximum(1.0, xp.minimum(float(chips), used_chips))
-            flops_per_chip = 2.0 * problem.macs / used_chips
-            compute_s = flops_per_chip / exact_divisor(xp, peak)
+            used_chips = xp.maximum(1.0, xp.minimum(p["rl_chips"], used_chips))
+            flops_per_chip = p["rl_flops"] / used_chips
+            compute_s = flops_per_chip / exact_divisor(xp, p["rl_peak"])
 
             hbm_bytes = xp.zeros(B)
             if vmem_real:
-                for k in range(len(axes_info)):
+                for k in range(K):
                     r = bt.rows[k]
-                    t = (r.fills[:, pos_v] + r.drains[:, pos_v]) * word_bytes[k]
+                    t = (r.fills[:, pos_v] + r.drains[:, pos_v]) * p["wb"][k]
                     mx = xp.maximum(mx, xp.max(t))
                     hbm_bytes = hbm_bytes + t
-            memory_s = hbm_bytes / exact_divisor(xp, hbm_bw)
+            memory_s = hbm_bytes / exact_divisor(xp, p["rl_hbm_bw"])
 
             coll_bytes = xp.zeros(B)
             for i in mesh_levels:
@@ -304,33 +270,31 @@ class TPURooflineModel(CostModel):
                     else xp.zeros(B, dtype=bool)
                 )
                 stf = bt.st[:, lvl, :].astype(xp.float64)
-                for k, (wb, axes, rel_idx) in enumerate(axes_info):
-                    shard = xp.ones(B)
-                    for ax in axes:
-                        span = xp.ones(B)
-                        for coeff, j in ax:
-                            span = span + coeff * (stf[:, j] - 1.0)
-                        shard = shard * span
+                for k in range(K):
+                    shard = batch_projection_footprint(layout[k], stf, xp, p["coeffs"])
                     mx = xp.maximum(mx, xp.max(shard))
                     if ds_out[k]:
                         cond = has_split & split_red
-                        term = 2.0 * (n_arr - 1.0) / n_arr * shard * wb
+                        term = 2.0 * (n_arr - 1.0) / n_arr * shard * p["wb"][k]
                     else:
+                        rel = np.asarray(rel_idx[k], dtype=np.int64)
                         split_rel = (
-                            xp.any(f[:, np.asarray(rel_idx, dtype=np.int64)] > 1, axis=1)
-                            if rel_idx
+                            xp.any(f[:, rel] > 1, axis=1)
+                            if rel.size
                             else xp.zeros(B, dtype=bool)
                         )
                         cond = has_split & ~split_rel
-                        term = (n_arr - 1.0) / n_arr * shard * wb
+                        term = (n_arr - 1.0) / n_arr * shard * p["wb"][k]
                     coll_bytes = coll_bytes + xp.where(cond, term, 0.0)
-            collective_s = coll_bytes / exact_divisor(xp, link_bw)
+            collective_s = coll_bytes / exact_divisor(xp, p["rl_link_bw"])
 
             latency_s = xp.maximum(compute_s, xp.maximum(memory_s, collective_s))
             energy_pj = (
-                hbm_bytes * used_chips * 7.0 + coll_bytes * used_chips * 2.0 + mac_term
+                hbm_bytes * used_chips * 7.0
+                + coll_bytes * used_chips * 2.0
+                + p["mac_term"]
             )
-            util = bt.par / exact_divisor(xp, num_pes)
+            util = bt.par / exact_divisor(xp, p["num_pes"])
             bound_idx = xp.argmax(
                 xp.stack([compute_s, memory_s, collective_s]), axis=0
             )
@@ -340,12 +304,9 @@ class TPURooflineModel(CostModel):
                 "collective_s": collective_s,
                 "bound": bound_idx,
             }
-            latency = latency_s * freq
-            if cal_s is not None:
-                latency = latency * cal_s
-            return latency, energy_pj, util, mx, extras
+            return latency_s * p["freq"], energy_pj, util, mx, extras
 
-        return terms
+        return BatchCostTerms(model_key, model_params, terms, lb)
 
     def costs_from_batch(
         self, problem, arch, latency, energy, util, extras, indices=None
@@ -364,8 +325,8 @@ class TPURooflineModel(CostModel):
                 "bound": float(extras["bound"][b]),
             }
             if cal_s is not None:
-                # latency is already scaled inside the terms program; the
-                # breakdown records the scale exactly like apply_calibration
+                # latency arrives calibrated; the breakdown records the
+                # scale exactly like apply_calibration
                 breakdown["calibration_scale"] = cal_s
             out.append(
                 Cost(
@@ -378,34 +339,6 @@ class TPURooflineModel(CostModel):
                 )
             )
         return out
-
-    def evaluate_signature_batch(
-        self,
-        problem: Problem,
-        arch: Architecture,
-        sigs,
-        backend: str = "numpy",
-        stacked=None,
-        select=None,
-    ):
-        """Vectorized ``evaluate`` over a miss-batch of signatures: the
-        SAME array program the fused jitted single-dispatch path traces
-        (``batch_cost_terms_fn``), run here with numpy over the admitted
-        subset. Same float-operation order per candidate as ``evaluate``
-        (bit-identical; BATCH_EXACT_LIMIT guard falls back to the scalar
-        path). ``stacked``/``select`` reuse the engine's admission-stage
-        StackedBatch (see ``CostModel.evaluate_signature_batch``)."""
-        ctx = get_context(problem, arch)
-        bt = ctx.signature_traffic_batch(
-            sigs, backend=backend, stacked=stacked, select=select
-        )
-        if bt is None:
-            return None
-        terms = self.batch_cost_terms_fn(problem, arch)
-        latency, energy, util, mx, extras = terms(bt, np)
-        if not (float(mx) < BATCH_EXACT_LIMIT):
-            return None  # exactness not guaranteed: use the scalar path
-        return self.costs_from_batch(problem, arch, latency, energy, util, extras)
 
     def evaluate(self, problem: Problem, mapping: Mapping, arch: Architecture) -> Cost:
         prof = analyze(problem, mapping, arch)

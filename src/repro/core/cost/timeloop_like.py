@@ -12,15 +12,13 @@ Energy:  per-level access counts x per-byte access energies + MAC energy.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import numpy as np
 
 from repro.core.architecture import Architecture
 from repro.core.cost.analysis import (
-    BATCH_EXACT_LIMIT,
+    BatchCostTerms,
     analyze,
-    batch_hierarchical_energy,
     boundary_bytes_per_instance,
     exact_divisor,
     generic_hierarchical_energy,
@@ -66,107 +64,15 @@ class TimeloopLikeModel(CostModel):
             fn(chain_list, orders)
         )
 
-    def lower_bound_batch_fn(self, problem: Problem, arch: Architecture):
-        fn = get_context(problem, arch).lower_bound_batch
-        if self.calibration is None:
-            return fn
-        # same final multiply as the scalar ``_calibrate_bound`` per
-        # element, so calibrated batch admission stays bit-identical
-        s = float(self.calibration.scale)
-
-        def calibrated(*args, **kwargs):
-            out = fn(*args, **kwargs)
-            if out is None:
-                return None
-            cyc, en = out
-            return cyc * s, en
-
-        return calibrated
-
-    def batch_admit_core_builder(self, problem: Problem, arch: Architecture):
-        builder = get_context(problem, arch)._make_lb_core
-        if self.calibration is None:
-            return builder
-        s = float(self.calibration.scale)
-
-        def calibrated_builder(xp, lax=None):
-            core = builder(xp, lax)
-
-            def calibrated_core(tt, st, perm):
-                cyc, en, mx = core(tt, st, perm)
-                return cyc * s, en, mx
-
-            return calibrated_core
-
-        return calibrated_builder
-
     def store_key_parts(self):
         return (self.name, self.unit_op) + self.calibration_key_parts()
 
-    def batch_cost_terms_fn(self, problem: Problem, arch: Architecture):
-        """Array-program twin of ``evaluate_signature``'s latency/energy
-        accumulation: same float-operation order per row, runnable with
-        numpy (host scoring) or jax.numpy (inside the fused jitted
-        core). A calibration scale is applied as the final latency
-        multiply, exactly as ``apply_calibration`` does on the scalar
-        path. See ``CostModel.batch_cost_terms_fn``."""
-        if not self.conformable(problem):
-            return None
-        cal_s = (
-            float(self.calibration.scale) if self.calibration is not None else None
-        )
-        ctx = get_context(problem, arch)
-        freq = arch.frequency_hz
-        clusters = arch.clusters
-        real_levels = ctx.real_levels
-        spaces = problem.data_spaces
-        num_pes = ctx.num_pes
-
-        def terms(bt, xp):
-            cc = bt.compute_cycles
-            # par is guarded too: utilization must match the scalar path's
-            # exact-int parallelism bit for bit
-            mx = xp.maximum(
-                xp.maximum(xp.max(cc), xp.max(bt.total_trips)), xp.max(bt.par)
-            )
-            worst = xp.zeros_like(cc)
-            extras = {"compute_cycles": cc}
-            for pos, i in enumerate(real_levels):
-                cl = clusters[i]
-                # the scalar path computes bts before skipping these levels
-                # but never uses it; skipping first is value-identical (the
-                # fills/drains factors are exactness-guarded in the energy
-                # walk below)
-                if i == 0 or math.isinf(cl.fill_bandwidth):
-                    continue
-                bts = xp.zeros_like(cc)
-                for k, ds in enumerate(spaces):
-                    t = (
-                        bt.rows[k].fills[:, pos] + bt.rows[k].drains[:, pos]
-                    ) * ds.word_bytes
-                    mx = xp.maximum(mx, xp.max(t))
-                    bts = bts + t
-                cyc = bts * freq / exact_divisor(xp, cl.fill_bandwidth)
-                extras[f"bw_cycles::{i}"] = cyc
-                extras[f"bw_bytes::{i}"] = bts
-                worst = xp.maximum(worst, xp.where(bts > 0, cyc, 0.0))
-            latency = xp.maximum(cc, worst)
-            energy, _noc, _mac, e_mx = batch_hierarchical_energy(
-                ctx, arch, problem, bt, xp=xp
-            )
-            mx = xp.maximum(mx, e_mx)
-            util = bt.par / exact_divisor(xp, num_pes)
-            if cal_s is not None:
-                latency = latency * cal_s
-            return latency, energy, util, mx, extras
-
-        return terms
-
-    def batch_cost_terms_generic(self, problem: Problem, arch: Architecture):
-        """Shape-generic twin of :meth:`batch_cost_terms_fn` (see
-        ``CostModel.batch_cost_terms_generic``): structure = which real
-        levels carry a finite-bandwidth fill term; every value (bandwidths,
-        energies, word widths, calibration) rides in the parameter pack."""
+    def batch_cost_terms(self, problem: Problem, arch: Architecture):
+        """``evaluate_signature``'s latency/energy accumulation as an array
+        program (see ``CostModel.batch_cost_terms``): structure = which
+        real levels carry a finite-bandwidth fill term; every value
+        (bandwidths, energies, word widths, calibration) rides in the
+        parameter pack. The admission bound is the hierarchical one."""
         if not self.conformable(problem):
             return None
         ctx = get_context(problem, arch)
@@ -209,11 +115,16 @@ class TimeloopLikeModel(CostModel):
 
         def terms(bt, xp, p):
             cc = bt.compute_cycles
+            # par is guarded too: utilization must match the scalar path's
+            # exact-int parallelism bit for bit
             mx = xp.maximum(
                 xp.maximum(xp.max(cc), xp.max(bt.total_trips)), xp.max(bt.par)
             )
             worst = xp.zeros_like(cc)
             extras = {"compute_cycles": cc}
+            # the scalar path computes bts for the levels bw_levels leaves
+            # out but never uses it; skipping them is value-identical (the
+            # fills/drains factors are exactness-guarded in the energy walk)
             for t, (pos, i) in enumerate(bw_levels):
                 bts = xp.zeros_like(cc)
                 for k in range(K):
@@ -234,7 +145,7 @@ class TimeloopLikeModel(CostModel):
             util = bt.par / exact_divisor(xp, p["num_pes"])
             return latency, energy, util, mx, extras
 
-        return model_key, model_params, terms
+        return BatchCostTerms(model_key, model_params, terms, None)
 
     def costs_from_batch(
         self, problem, arch, latency, energy, util, extras, indices=None
@@ -261,8 +172,8 @@ class TimeloopLikeModel(CostModel):
                     breakdown[f"bw_cycles_{name}"] = float(cyc[b])
             breakdown["energy_mac_pj"] = mac_term
             if cal_s is not None:
-                # latency is already scaled inside the terms program; the
-                # breakdown records the scale exactly like apply_calibration
+                # latency arrives calibrated; the breakdown records the
+                # scale exactly like apply_calibration
                 breakdown["calibration_scale"] = cal_s
             out.append(
                 Cost(
@@ -338,41 +249,6 @@ class TimeloopLikeModel(CostModel):
             frequency_hz=freq,
             breakdown=breakdown,
         ))
-
-    def evaluate_signature_batch(
-        self,
-        problem: Problem,
-        arch: Architecture,
-        sigs,
-        backend: str = "numpy",
-        stacked=None,
-        select=None,
-    ):
-        """Vectorized ``evaluate_signature`` over a whole miss-batch: same
-        float-operation order per candidate, so results are bit-identical
-        whenever every integer-valued product stays float64-exact (checked
-        against BATCH_EXACT_LIMIT; returns None otherwise). The latency/
-        energy accumulation is the SAME array program the fused jitted
-        single-dispatch path traces (``batch_cost_terms_fn``), run here
-        with numpy over the admitted subset. ``stacked``/``select`` reuse
-        the engine's admission-stage StackedBatch (see
-        ``CostModel.evaluate_signature_batch``)."""
-        if not self.conformable(problem):
-            raise ValueError(
-                f"{self.name} configured with unit op {self.unit_op!r} cannot "
-                f"evaluate problem with unit op {problem.unit_op!r}"
-            )
-        ctx = get_context(problem, arch)
-        bt = ctx.signature_traffic_batch(
-            sigs, backend=backend, stacked=stacked, select=select
-        )
-        if bt is None:
-            return None
-        terms = self.batch_cost_terms_fn(problem, arch)
-        latency, energy, util, mx, extras = terms(bt, np)
-        if not (float(mx) < BATCH_EXACT_LIMIT):
-            return None  # exactness not guaranteed: use the scalar path
-        return self.costs_from_batch(problem, arch, latency, energy, util, extras)
 
     def evaluate(self, problem: Problem, mapping: Mapping, arch: Architecture) -> Cost:
         if not self.conformable(problem):

@@ -1,4 +1,4 @@
-"""Device-resident search loops over the shape-generic fused program.
+"""Device-resident search loops over the engine's fused program.
 
 The evaluation engine's per-batch jax path round-trips host<->device every
 miss-batch: stack, upload, dispatch, materialize, commit. For the search
@@ -13,7 +13,7 @@ Two primitives, both strictly RESULT-PRESERVING:
 
 ``device_precompute(engine, batches)``
     Scores a window of pre-generated :class:`GenomeBatch` chunks as one
-    fused dispatch of the shape-generic runner and hands each chunk its
+    dispatch of the engine's fused runner and hands each chunk its
     row-slice of the results as a :class:`PrecomputedScores`. The engine
     then replays each chunk through ``evaluate_batch(precomputed=...)``:
     dedup, memo/store probes, admission against the CURRENT incumbent and
@@ -33,9 +33,10 @@ Two primitives, both strictly RESULT-PRESERVING:
     generations is observationally equivalent).
 
 Every primitive degrades to ``None``/host-loop behavior when the runner
-is unavailable (numpy backend, no generic terms, a device fault
-mid-flight) or an exactness guard trips -- callers fall through to the unchanged
-per-batch path, and results are identical either way.
+is unavailable (numpy backend, a model without ``batch_cost_terms``, a
+device fault mid-flight) or an exactness guard trips -- callers fall
+through to the unchanged per-batch path, and results are identical
+either way.
 
 Each point that reads device results (a window, a generation's guards
 and fitness, a flush) pulls them in ONE transfer
@@ -99,14 +100,11 @@ def device_loop_enabled(engine: EvaluationEngine) -> bool:
 
 
 def _precompute_runner(engine: EvaluationEngine):
-    """The engine's fused runner iff it supports precompute (the
-    shape-generic runner does; per-context closures do not)."""
+    """The engine's fused runner, or None when the device loops are off
+    or the engine has none."""
     if not device_loop_enabled(engine):
         return None
-    runner = engine._get_fused_runner()
-    if runner is None or not getattr(runner, "supports_precompute", False):
-        return None
-    return runner
+    return engine._get_fused_runner()
 
 
 def _materialize(out, B: int) -> Optional[PrecomputedScores]:
@@ -184,7 +182,7 @@ class DeviceGAScorer:
     sees the exact host-loop offer sequence.
 
     ``score`` returns None once the device path is unavailable (no
-    generic runner, guard trip, device fault); buffered generations are
+    fused runner, guard trip, device fault); buffered generations are
     replayed first -- falling back to plain engine evaluation if their
     device buffers can no longer be read -- so no offer is ever lost and
     the caller can continue with the host loop mid-search."""
@@ -211,12 +209,8 @@ class DeviceGAScorer:
     def score(self, gb: GenomeBatch) -> Optional[np.ndarray]:
         if self._runner is None:
             return None
-        runner = self._runner
-        if getattr(runner, "dispatch_device", None) is None:
-            self._disable()
-            return None
         with span("map.sync"):
-            out, fitness = self._dispatch(runner, gb)
+            out, fitness = self._dispatch(self._runner, gb)
         if fitness is None:
             self._disable()
             return None

@@ -479,8 +479,7 @@ def test_calibrated_store_key_differs_and_rescales(model_cls):
     # a final multiply inside the batch programs) and match the calibrated
     # scalar path bit for bit
     assert cal.lower_bound_batch_fn(problem, arch) is not None
-    assert cal.batch_admit_core_builder(problem, arch) is not None
-    assert cal.batch_cost_terms_fn(problem, arch) is not None
+    assert cal.batch_cost_terms(problem, arch) is not None
     from repro.core.cost.analysis import get_context
     from repro.core.mapping import mapping_signature
 

@@ -136,6 +136,34 @@ def test_random_device_loop_matches_host(monkeypatch, patience):
     _assert_memos_equal(eng_dev, eng_np)
 
 
+def test_roofline_takes_the_device_loop(monkeypatch):
+    """The roofline model has the same fused runner as the hierarchical
+    models, so its jax random search runs the device loop too -- and
+    matches the numpy search down to the memo contents."""
+    pytest.importorskip("jax")
+    from repro.core.architecture import tpu_v5e_pod
+    from repro.core.cost import TPURooflineModel
+
+    arch = tpu_v5e_pod(1, 2, 2)
+    space = MapSpace(GEMM, arch)
+
+    def run(backend):
+        engine = EvaluationEngine(
+            TPURooflineModel(), GEMM, arch, metric="edp", backend=backend
+        )
+        mapper = RandomMapper(samples=192, seed=3, batch_size=32, probe=8)
+        res = mapper.search(space, engine.cost_model, metric="edp", engine=engine)
+        return res, engine
+
+    monkeypatch.setenv("UNION_DEVICE_LOOP", "1")
+    res_dev, eng_dev = run("jax")
+    assert not eng_dev._ctx._jax_failed
+    assert res_dev.device_syncs >= 1
+    res_np, eng_np = run("numpy")
+    _assert_results_equal(res_dev, res_np, same_backend=False)
+    _assert_memos_equal(eng_dev, eng_np)
+
+
 def test_random_device_sync_cadence(monkeypatch):
     """Chunks per host sync == UNION_DEVICE_K: 10 chunks at K=3 is
     exactly ceil(10/3) = 4 mega dispatches."""

@@ -1,10 +1,9 @@
-"""Shape-generic fused cores: the ONE-program-per-shape-class machinery
-must be bit-identical to the per-context closures it replaces, and the
+"""Fused cores: the ONE-program-per-shape-class core must reproduce the
+scalar path bit for bit (costs, bounds and admission decisions), and the
 process-wide program cache must actually be shared -- a second problem in
 the same shape class adds zero traces and zero warmup buckets."""
 
 import math
-import random
 
 import numpy as np
 import pytest
@@ -15,6 +14,7 @@ from repro.core.cost import (
     EvaluationEngine,
     MaestroLikeModel,
     TimeloopLikeModel,
+    TPURooflineModel,
 )
 from repro.core.cost.analysis import (
     _make_generic_fused_core,
@@ -31,49 +31,60 @@ GEMM = Problem.gemm(64, 32, 16, word_bytes=1)
 # content (dim sizes, word widths) -- the sharing tests hinge on this pair
 GEMM_B = Problem.gemm(128, 64, 48, word_bytes=2)
 CONV = Problem.conv2d(2, 8, 8, 7, 7, 3, 3, stride=2, name="conv_t", word_bytes=1)
-MODELS = [TimeloopLikeModel, MaestroLikeModel]
+MODELS = [TimeloopLikeModel, MaestroLikeModel, TPURooflineModel]
 
 
-def _stacked(problem, arch, seed, B=24):
+def _batch(problem, arch, seed, B=24):
     space = MapSpace(problem, arch)
-    gb = random_genome_batch(space, np.random.default_rng(seed), B)
-    return gb.stacked()
+    return random_genome_batch(space, np.random.default_rng(seed), B)
 
 
-def _generic_out(cm, problem, arch, sb, metric, incumbent=math.inf):
-    """Run the shape-generic fused core with xp=numpy (no jax involved:
-    this isolates the generic ALGEBRA from the jit machinery)."""
+def _fused_out(cm, problem, arch, gb, metric, incumbent=math.inf, core=None):
+    """Run the fused core with xp=numpy (no jax involved: this isolates
+    the core's ALGEBRA from the jit machinery). ``core`` overrides the
+    program built from this problem's own entry."""
     ctx = get_context(problem, arch)
-    generic = cm.batch_cost_terms_generic(problem, arch)
-    assert generic is not None, f"{cm.name} lost its generic terms hook"
-    model_key, model_params, terms = generic
-    p = dict(ctx.shape_params())
-    p.update(model_params)
-    core = _make_generic_fused_core(ctx.shape_class_key(), terms, metric, np, None)
-    return core(sb.tt, sb.st, sb.perm, incumbent, p)
+    model = cm.batch_cost_terms(problem, arch)
+    assert model is not None, f"{cm.name} lost its batch_cost_terms hook"
+    _key, model_params, terms, lb = model
+    if core is None:
+        core = _make_generic_fused_core(
+            ctx.shape_class_key(), terms, lb, metric, np, None
+        )
+    sb = gb.stacked()
+    return core(sb.tt, sb.st, sb.perm, incumbent, ctx.model_pack(model_params))
 
 
-def _context_out(cm, problem, arch, sb, metric, incumbent=math.inf):
-    """The per-context fused core (the pre-generic path) on numpy."""
-    ctx = get_context(problem, arch)
-    lb_builder = cm.batch_admit_core_builder(problem, arch)
-    terms = cm.batch_cost_terms_fn(problem, arch)
-    assert lb_builder is not None and terms is not None
-    core = ctx._make_fused_core(np, None, lb_builder, terms, metric)
-    return core(sb.tt, sb.st, sb.perm, incumbent)
+def _score(metric, cyc, en, freq):
+    """The engine's host scalarization of a (cycles, energy) pair."""
+    if metric == "latency":
+        return cyc
+    if metric == "energy":
+        return en
+    return (en * 1e-12) * (cyc / freq)
 
 
-def _assert_fused_equal(g, c):
-    g_admit, g_lbmx, g_lat, g_en, g_ut, g_smx, g_extras = g
-    c_admit, c_lbmx, c_lat, c_en, c_ut, c_smx, c_extras = c
-    assert np.array_equal(np.asarray(g_admit), np.asarray(c_admit))
-    assert np.array_equal(np.asarray(g_lat), np.asarray(c_lat))
-    assert np.array_equal(np.asarray(g_en), np.asarray(c_en))
-    assert np.array_equal(np.asarray(g_ut), np.asarray(c_ut))
-    # extras shared by both paths must agree bit for bit too (the generic
-    # core ADDS lb_cycles/lb_energy/metric_score on top)
-    for k in set(g_extras) & set(c_extras):
-        assert np.array_equal(np.asarray(g_extras[k]), np.asarray(c_extras[k])), k
+def _assert_matches_scalar(out, cm, problem, arch, gb, metric, incumbent=math.inf):
+    """Every row of a fused output == the scalar reference: the Cost of
+    ``evaluate_signature`` (``evaluate`` where the model has no signature
+    path), the bound of ``lower_bound_fn`` and the admit bit the host
+    filter derives from that bound."""
+    admit, _lbmx, lat, en, ut, _smx, extras = out
+    lb_fn = cm.lower_bound_fn(problem, arch)
+    freq = arch.frequency_hz
+    for b in range(len(gb)):
+        sig = gb.signature(b)
+        cost = cm.evaluate_signature(problem, arch, sig)
+        if cost is None:
+            cost = cm.evaluate(problem, gb.genome(b).to_mapping(), arch)
+        assert lat[b] == cost.latency_cycles, b
+        assert en[b] == cost.energy_pj, b
+        assert ut[b] == cost.utilization, b
+        assert extras["metric_score"][b] == cost.metric(metric), b
+        lb_cyc, lb_en = lb_fn(sig)
+        assert extras["lb_cycles"][b] == lb_cyc, b
+        assert extras["lb_energy"][b] == lb_en, b
+        assert bool(admit[b]) == (_score(metric, lb_cyc, lb_en, freq) < incumbent), b
 
 
 @pytest.mark.parametrize("problem", [GEMM, CONV], ids=["gemm", "conv"])
@@ -83,30 +94,26 @@ def _assert_fused_equal(g, c):
 )
 @pytest.mark.parametrize("metric", ["edp", "latency", "energy"])
 def test_generic_core_bit_identical_to_per_context(problem, model_cls, mk_arch, metric):
-    """Generic fused core (values as a parameter pack) == per-context
-    fused core (values baked into the closure), bit for bit, on numpy --
-    across randomized candidate batches and a real finite incumbent."""
+    """Fused core (values as a parameter pack) == the scalar path, bit for
+    bit, on numpy -- across randomized candidate batches and a real
+    finite incumbent."""
     arch = mk_arch()
     cm = model_cls()
     for seed in (0, 7, 23):
-        sb = _stacked(problem, arch, seed)
-        g = _generic_out(cm, problem, arch, sb, metric)
-        c = _context_out(cm, problem, arch, sb, metric)
-        _assert_fused_equal(g, c)
+        gb = _batch(problem, arch, seed)
+        g = _fused_out(cm, problem, arch, gb, metric)
+        _assert_matches_scalar(g, cm, problem, arch, gb, metric)
         # admission compares the LOWER-BOUND scores against the incumbent,
         # so a median lb score makes the admit bits non-trivial
-        lb_cyc = np.asarray(g[6]["lb_cycles"])
-        lb_en = np.asarray(g[6]["lb_energy"])
-        if metric == "latency":
-            lb_scores = lb_cyc
-        elif metric == "energy":
-            lb_scores = lb_en
-        else:
-            lb_scores = (lb_en * 1e-12) * (lb_cyc / arch.frequency_hz)
+        lb_scores = _score(
+            metric,
+            np.asarray(g[6]["lb_cycles"]),
+            np.asarray(g[6]["lb_energy"]),
+            arch.frequency_hz,
+        )
         inc = float(np.median(lb_scores))
-        g2 = _generic_out(cm, problem, arch, sb, metric, incumbent=inc)
-        c2 = _context_out(cm, problem, arch, sb, metric, incumbent=inc)
-        _assert_fused_equal(g2, c2)
+        g2 = _fused_out(cm, problem, arch, gb, metric, incumbent=inc)
+        _assert_matches_scalar(g2, cm, problem, arch, gb, metric, incumbent=inc)
         admit = np.asarray(g2[0])
         assert not admit.all(), "median lb incumbent should reject some rows"
         if np.unique(lb_scores).size > 1:
@@ -116,40 +123,35 @@ def test_generic_core_bit_identical_to_per_context(problem, model_cls, mk_arch, 
 @pytest.mark.parametrize("model_cls", MODELS)
 def test_generic_core_calibrated_scale_bit_identical(model_cls):
     """With a (non-power-of-two) calibration attached, the traced
-    ``calib_scale`` parameter reproduces the per-context calibrated path
-    bit for bit -- the same program serves every calibration value."""
+    ``calib_scale`` parameter reproduces the calibrated scalar path bit
+    for bit -- the same program serves every calibration value."""
     arch = cloud_accelerator()
     cm = model_cls().set_calibration(CalibrationScale(1.7, 1, "test"))
-    sb = _stacked(GEMM, arch, 3)
-    g = _generic_out(cm, GEMM, arch, sb, "edp")
-    c = _context_out(cm, GEMM, arch, sb, "edp")
-    _assert_fused_equal(g, c)
+    gb = _batch(GEMM, arch, 3)
+    g = _fused_out(cm, GEMM, arch, gb, "edp")
+    _assert_matches_scalar(g, cm, GEMM, arch, gb, "edp")
     # and the scale really is in effect: raw model differs
-    raw = _generic_out(model_cls(), GEMM, arch, sb, "edp")
+    raw = _fused_out(model_cls(), GEMM, arch, gb, "edp")
     assert not np.array_equal(np.asarray(g[2]), np.asarray(raw[2]))
 
 
 @pytest.mark.parametrize("model_cls", MODELS)
 def test_one_generic_program_serves_the_shape_class(model_cls):
-    """GEMM and GEMM_B share a shape class; ONE generic core object fed
-    each problem's parameter pack must reproduce each problem's own
-    per-context core bit for bit."""
+    """GEMM and GEMM_B share a shape class; ONE fused core object fed
+    each problem's parameter pack must reproduce each problem's scalar
+    path bit for bit."""
     arch = cloud_accelerator()
     cm = model_cls()
     ctx_a = get_context(GEMM, arch)
-    ctx_b = get_context(GEMM_B, arch)
     skey = ctx_a.shape_class_key()
-    assert skey == ctx_b.shape_class_key()
-    _key, _params_a, terms_a = cm.batch_cost_terms_generic(GEMM, arch)
-    core = _make_generic_fused_core(skey, terms_a, "edp", np, None)
-    for problem, ctx in ((GEMM, ctx_a), (GEMM_B, ctx_b)):
-        _mk, model_params, _t = cm.batch_cost_terms_generic(problem, arch)
-        p = dict(ctx.shape_params())
-        p.update(model_params)
-        sb = _stacked(problem, arch, 11)
-        g = core(sb.tt, sb.st, sb.perm, math.inf, p)
-        c = _context_out(cm, problem, arch, sb, "edp")
-        _assert_fused_equal(g, c)
+    assert skey == get_context(GEMM_B, arch).shape_class_key()
+    model_a = cm.batch_cost_terms(GEMM, arch)
+    assert model_a[0] == cm.batch_cost_terms(GEMM_B, arch)[0]
+    core = _make_generic_fused_core(skey, model_a[2], model_a[3], "edp", np, None)
+    for problem in (GEMM, GEMM_B):
+        gb = _batch(problem, arch, 11)
+        g = _fused_out(cm, problem, arch, gb, "edp", core=core)
+        _assert_matches_scalar(g, cm, problem, arch, gb, "edp")
 
 
 # ------------------------------------------------------------------ #
@@ -207,13 +209,41 @@ def test_calibrated_jax_engine_matches_numpy(model_cls):
     assert all("calibration_scale" in c.breakdown for c in costs_jx)
 
 
-def test_second_problem_in_class_adds_zero_traces():
-    """After GEMM traces the generic program, a content-different problem
+def _two_stage_jax(problem, arch):
+    """The two-stage jax programs (admission bound, then traffic) over one
+    batch, each checked bit for bit against its numpy program."""
+    ctx = get_context(problem, arch)
+    sb = _batch(problem, arch, 5, B=32).stacked()
+    lb_np = ctx.lower_bound_batch(stacked=sb, backend="numpy")
+    bt_np = ctx.signature_traffic_batch(stacked=sb, backend="numpy")
+    lb_jx = ctx.lower_bound_batch(stacked=sb, backend="jax")
+    bt_jx = ctx.signature_traffic_batch(stacked=sb, backend="jax")
+    assert not ctx._jax_failed
+    for a, b in zip(lb_np, lb_jx):
+        assert np.array_equal(a, b)
+    for a, b in zip(bt_np[:7], bt_jx[:7]):
+        assert np.array_equal(a, b)
+    for rn, rj in zip(bt_np.rows, bt_jx.rows):
+        for a, b in zip(rn, rj):
+            assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("path", ["fused", "two_stage"])
+def test_second_problem_in_class_adds_zero_traces(path):
+    """After GEMM traces the class's programs, a content-different problem
     in the SAME shape class (GEMM_B) dispatches with ZERO new traces --
-    one compiled program per (shape class, model, metric)."""
+    one compiled program per (shape class, model, metric) on the fused
+    path, and one per shape class for the two-stage bound and traffic
+    programs."""
     pytest.importorskip("jax")
     reset_trace_registry()
     arch = cloud_accelerator()
+    if path == "two_stage":
+        _two_stage_jax(GEMM, arch)
+        assert global_trace_count() == 2  # the bound and the traffic program
+        _two_stage_jax(GEMM_B, arch)
+        assert global_trace_count() == 2
+        return
     eng_a, _ = _engine_costs(TimeloopLikeModel(), GEMM, arch, "jax", B=32)
     assert not eng_a._ctx._jax_failed
     assert eng_a.stats.n_traces >= 1

@@ -1,6 +1,9 @@
 """Flash attention (causal / bidirectional, GQA-native) Pallas TPU kernel.
 
-Layout: q (B, Hq, Sq, D), k/v (B, Hkv, Skv, D). Grid = (B, Hq, Sq/bq,
+Layout: q (B, Hq, Sq, D), k/v (B, Hkv, Skv, D), or k/v stacked over
+layers (L, B, Hkv, Skv, D) with a traced ``layer``: the decode cache is
+such a stack, and the K/V BlockSpecs pick the layer's blocks straight out
+of it, so no layer is copied out before the call. Grid = (B, Hq, Sq/bq,
 Skv/bk) with the KV axis innermost; online-softmax running max / sum /
 accumulator live in VMEM scratch across the KV sweep.
 
@@ -11,14 +14,18 @@ models/layers.py).
 
 Causality is handled two ways, in Union mapping terms both at the C2
 grid level: fully-masked KV blocks are skipped via ``pl.when`` (no MXU
-work), and only diagonal blocks pay the element mask. A (1,1) SMEM
-``kv_len`` input masks the valid cache prefix for decode.
+work), and only diagonal blocks pay the element mask. One scalar-prefetch
+operand ``[kv_len, q_offset, layer]`` (int32, in SMEM before the grid
+runs) masks the valid cache prefix for decode and feeds the K/V index
+maps the layer.
 
 Each call records, as it is built (at trace time, never per step), the
 bytes its grid and BlockSpecs move between HBM and VMEM: the pipeline
 copies a block in at the first grid step and again at each step whose
 block index differs from the step before, and writes an output block back
-likewise. ``kernel_record()`` returns them by call shape.
+likewise. ``kernel_record()`` returns them by call shape. The layer is
+constant within a call, so a stacked call moves what the same call handed
+one layer moves.
 """
 
 from __future__ import annotations
@@ -48,6 +55,7 @@ class FlashCall(NamedTuple):
     head_dim: int
     bq: int
     bk: int
+    kv_layers: int  # depth of the K/V operand's layer axis; 0: one layer
 
 
 _RECORD_LOCK = threading.Lock()
@@ -86,9 +94,11 @@ def block_copies(grid: Tuple[int, ...], index_map, chunk: int = 1 << 20) -> int:
 
 def _record(call: FlashCall, grid, specs) -> None:
     """Record the bytes moved per call for ``specs``: {name: (BlockSpec,
-    dtype)}."""
+    dtype)}. The index maps take the scalar-prefetch operand last; the
+    copies are counted with the layer fixed, as it is within a call."""
+    meta = np.zeros(3, np.int64)
     moved = {
-        name: block_copies(grid, spec.index_map)
+        name: block_copies(grid, lambda *g, f=spec.index_map: f(*g, meta))
         * math.prod(spec.block_shape) * jnp.dtype(dtype).itemsize
         for name, (spec, dtype) in specs.items()
     }
@@ -97,10 +107,10 @@ def _record(call: FlashCall, grid, specs) -> None:
 
 
 def _fa_kernel(
-    kvlen_ref,  # (1, 2) SMEM: [valid KV prefix, q position offset]
+    meta_ref,  # (3,) SMEM: [valid KV prefix, q position offset, layer]
     q_ref,  # (1, 1, bq, d)
-    k_ref,  # (1, 1, bk, d)
-    v_ref,  # (1, 1, bk, dv)
+    k_ref,  # (1, 1, 1, bk, d)
+    v_ref,  # (1, 1, 1, bk, dv)
     o_ref,  # (1, 1, bq, dv)
     m_ref,  # (bq, 128) f32 scratch -- running max (broadcast over lanes)
     l_ref,  # (bq, 128) f32 scratch -- running denominator
@@ -120,8 +130,8 @@ def _fa_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_len = kvlen_ref[0, 0]
-    q_offset = kvlen_ref[0, 1]
+    kv_len = meta_ref[0]
+    q_offset = meta_ref[1]
     q_pos0 = q_offset + i * bq  # global position of this q block's first row
 
     # Skip KV blocks that are entirely masked: block start beyond both the
@@ -132,8 +142,8 @@ def _fa_kernel(
     @pl.when(live)
     def _body():
         q = q_ref[0, 0]  # (bq, d)
-        k = k_ref[0, 0]  # (bk, d)
-        v = v_ref[0, 0]  # (bk, dv)
+        k = k_ref[0, 0, 0]  # (bk, d)
+        v = v_ref[0, 0, 0]  # (bk, dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
@@ -167,19 +177,23 @@ def _fa_kernel(
 
 def flash_attention_pallas(
     q: jnp.ndarray,  # (B, Hq, Sq, D)
-    k: jnp.ndarray,  # (B, Hkv, Skv, D)
-    v: jnp.ndarray,  # (B, Hkv, Skv, Dv)
+    k: jnp.ndarray,  # (B, Hkv, Skv, D), or (L, B, Hkv, Skv, D) with layer
+    v: jnp.ndarray,  # (B, Hkv, Skv, Dv), or (L, B, Hkv, Skv, Dv) with layer
     *,
     causal: bool,
     scale: float,
     q_offset=0,  # int or traced scalar: global position of q[0]
     kv_len: Optional[jnp.ndarray] = None,  # scalar int32; None => Skv
+    layer=None,  # int or traced scalar: the layer of stacked k/v to read
     bq: int = 512,
     bk: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    kv_layers = 0 if layer is None else k.shape[0]
+    if layer is None:  # one layer is a stack of one: a free reshape
+        k, v, layer = k[None], v[None], 0
     B, Hq, Sq, D = q.shape
-    _, Hkv, Skv, Dv = v.shape
+    _, _, Hkv, Skv, Dv = v.shape
     assert Hq % Hkv == 0, f"GQA heads {Hq} % {Hkv} != 0"
     group = Hq // Hkv
     bq = min(bq, Sq)
@@ -188,12 +202,13 @@ def flash_attention_pallas(
         f"seq ({Sq},{Skv}) not divisible by blocks ({bq},{bk}); pad in ops"
     )
     grid = (B, Hq, Sq // bq, Skv // bk)
-    kvl = jnp.stack(
+    meta = jnp.stack(
         [
             jnp.asarray(Skv if kv_len is None else kv_len, jnp.int32),
             jnp.asarray(q_offset, jnp.int32),
+            jnp.asarray(layer, jnp.int32),
         ]
-    ).reshape(1, 2)
+    )
     kernel = functools.partial(
         _fa_kernel,
         scale=scale,
@@ -202,24 +217,29 @@ def flash_attention_pallas(
         bk=bk,
         n_kv=grid[3],
     )
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
-    k_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // group, j, 0))
-    v_spec = pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j: (b, h // group, j, 0))
-    o_spec = pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0))
-    _record(FlashCall(B, Hq, Hkv, Sq, Skv, D, bq, bk), grid,
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, m: (b, h, i, 0))
+    k_spec = pl.BlockSpec((1, 1, 1, bk, D),
+                          lambda b, h, i, j, m: (m[2], b, h // group, j, 0))
+    v_spec = pl.BlockSpec((1, 1, 1, bk, Dv),
+                          lambda b, h, i, j, m: (m[2], b, h // group, j, 0))
+    o_spec = pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j, m: (b, h, i, 0))
+    _record(FlashCall(B, Hq, Hkv, Sq, Skv, D, bq, bk, kv_layers), grid,
             {"q": (q_spec, q.dtype), "k": (k_spec, k.dtype),
              "v": (v_spec, v.dtype), "o": (o_spec, q.dtype)})
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, k_spec, v_spec],
-        out_specs=o_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[q_spec, k_spec, v_spec],
+            out_specs=o_spec,
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
-        ],
         interpret=interpret,
         name="union_flash_attention",
-    )(kvl, q, k, v)
+    )(meta, q, k, v)

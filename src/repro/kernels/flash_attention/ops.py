@@ -115,9 +115,11 @@ def plan_blocks(Sq: int, Skv: int, D: int) -> Tuple[int, int]:
 
 
 # ------------------------------------------------------------------ #
-# custom-vjp core over the padded (B, H, S, D) layout.  ``meta`` is a
-# float32 (2,) array [kv_len, q_offset] so traced decode positions stay
-# differentiable-dtype (zero cotangent) without being static.
+# custom-vjp core over the padded (B, H, S, D) layout, or K/V stacked over
+# layers (L, B, H, S, D).  ``meta`` is a float32 (3,) array [kv_len,
+# q_offset, layer] so traced decode positions stay differentiable-dtype
+# (zero cotangent) without being static; the layer is read only when K/V
+# are stacked.
 # ------------------------------------------------------------------ #
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _fa(q, k, v, meta, causal, scale, blocks, interpret):
@@ -126,6 +128,7 @@ def _fa(q, k, v, meta, causal, scale, blocks, interpret):
         q, k, v,
         causal=causal, scale=scale,
         q_offset=meta[1].astype(jnp.int32), kv_len=meta[0].astype(jnp.int32),
+        layer=meta[2].astype(jnp.int32) if k.ndim == 5 else None,
         bq=bq, bk=bk, interpret=interpret,
     )
 
@@ -138,8 +141,12 @@ def _fa_bwd(causal, scale, blocks, interpret, res, g):
     q, k, v, meta = res
     kvl = meta[0].astype(jnp.int32)
     qo = meta[1].astype(jnp.int32)
+    layer = meta[2].astype(jnp.int32)
 
     def f(q, k, v):
+        if k.ndim == 5:  # stacked: only the layer read gets a cotangent
+            k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                    for a in (k, v))
         return attention_ref(
             q, k, v, causal=causal, scale=scale, q_offset=qo, kv_len=kvl
         )
@@ -164,18 +171,31 @@ def flash_attention(
     blocks: Optional[Tuple[int, int]] = None,
     interpret: Optional[bool] = None,
     kv_heads_major: bool = False,
+    layer=None,
 ) -> jnp.ndarray:
     """Drop-in for models.layers.mha's math on TPU. Handles GQA natively
     and pads Sq/Skv up to the block grid (padded KV is masked via kv_len).
     K and V already in the kernel's (b, h, S, d) layout, as the decode cache
-    holds them, pass with ``kv_heads_major`` and are read without a swap."""
+    holds them, pass with ``kv_heads_major`` and are read without a swap.
+
+    With ``layer`` (int or traced scalar), K and V are that layout stacked
+    over layers, (L, b, hkv, Skv, d), as the decode cache's scanned layers
+    hold them: the kernel reads the layer's blocks out of the stack, and
+    nothing is copied, when Skv fills whole KV blocks. Otherwise the layer
+    is sliced out and padded, as a single layer is."""
     interpret = _cfg.interpret_default() if interpret is None else interpret
     b, Sq, hq, d = q.shape
-    Skv = v.shape[2] if kv_heads_major else v.shape[1]
+    Skv = v.shape[-2] if kv_heads_major else v.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     bq, bk = blocks or plan_blocks(round_up(Sq, 128), round_up(Skv, 128), d)
     bq, bk = min(bq, round_up(Sq, 8)), min(bk, round_up(Skv, 8))
     Sqp, Skvp = round_up(Sq, bq), round_up(Skv, bk)
+    if layer is not None:
+        assert kv_heads_major, "a stacked cache is held heads-major"
+        if Skvp != Skv:  # padding the stack would copy every layer
+            k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                    for a in (k, v))
+            layer = None
     qt = jnp.swapaxes(q, 1, 2)  # (b, hq, Sq, d)
     if kv_heads_major:
         kt, vt = k, v
@@ -190,6 +210,7 @@ def flash_attention(
         [
             jnp.asarray(Skv if kv_len is None else kv_len, jnp.float32),
             jnp.asarray(q_offset, jnp.float32),
+            jnp.asarray(0 if layer is None else layer, jnp.float32),
         ]
     )
     out = _fa(qt, kt, vt, meta, causal, scale, (bq, bk), interpret)

@@ -113,6 +113,7 @@ def mha(
     q_chunk: Optional[int] = None,
     sm_scale: Optional[float] = None,
     kv_heads_major: bool = False,  # k/v in the decode cache's layout
+    layer=None,  # k/v stacked over layers (the decode cache): this layer
 ) -> jnp.ndarray:
     b, Sq, hq, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
@@ -121,8 +122,10 @@ def mha(
         from repro.kernels.flash_attention import flash_attention
         return flash_attention(
             q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-            sm_scale=scale, kv_heads_major=kv_heads_major,
+            sm_scale=scale, kv_heads_major=kv_heads_major, layer=layer,
         )
+    if layer is not None:
+        k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False) for a in (k, v))
     if kv_heads_major:
         k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
     _, Skv, hkv, dv = v.shape
@@ -172,22 +175,19 @@ def mha(
 # decode cache writes
 # --------------------------------------------------------------------- #
 def cache_write(c: jnp.ndarray, new: jnp.ndarray, pos, seq_axis: int,
-                layer=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                layer=None) -> jnp.ndarray:
     """Write ``new`` into the cache leaf ``c`` at ``pos`` along its sequence
-    axis; returns (updated leaf, this layer's cache). ``c`` is one layer's
-    leaf, or with ``layer`` the stack of every scanned layer's leaves, whose
-    leading axis is the layer: the token is then written into the stack,
-    which the donated decode step updates in place, and the layer is read
-    out of it."""
+    axis; returns the updated leaf. ``c`` is one layer's leaf, or with
+    ``layer`` the stack of every scanned layer's leaves, whose leading axis
+    is the layer: the token is then written into the stack, which the
+    donated decode step updates in place."""
     new = new.astype(c.dtype)
     idx = [0] * c.ndim
     if layer is None:
         idx[seq_axis] = pos
-        c = jax.lax.dynamic_update_slice(c, new, idx)
-        return c, c
+        return jax.lax.dynamic_update_slice(c, new, idx)
     idx[0], idx[seq_axis + 1] = layer, pos
-    c = jax.lax.dynamic_update_slice(c, new[None], idx)
-    return c, jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+    return jax.lax.dynamic_update_slice(c, new[None], idx)
 
 
 # --------------------------------------------------------------------- #
@@ -232,12 +232,13 @@ def attention_apply(
     new_cache = None
     if cache is not None:
         # decode: write new k/v at cache_len, attend over the whole cache,
-        # which is kept in the flash kernel's (b, hkv, S, hd) layout
-        ck, k_all = cache_write(cache["k"], jnp.swapaxes(k, 1, 2), cache_len, 2, layer)
-        cv, v_all = cache_write(cache["v"], jnp.swapaxes(v, 1, 2), cache_len, 2, layer)
+        # which is kept in the flash kernel's (b, hkv, S, hd) layout; a
+        # stacked cache goes to mha whole, with the layer to read
+        ck = cache_write(cache["k"], jnp.swapaxes(k, 1, 2), cache_len, 2, layer)
+        cv = cache_write(cache["v"], jnp.swapaxes(v, 1, 2), cache_len, 2, layer)
         new_cache = {"k": ck, "v": cv}
-        out = mha(q, k_all, v_all, causal=False, q_offset=cache_len,
-                  kv_len=cache_len + S, kv_heads_major=True)
+        out = mha(q, ck, cv, causal=False, q_offset=cache_len,
+                  kv_len=cache_len + S, kv_heads_major=True, layer=layer)
     else:
         out = mha(q, k, v, causal=not cfg.encoder_only, q_offset=0)
     y = dense(p["wo"], out.reshape(b, S, hq * hd))
@@ -283,9 +284,13 @@ def mla_apply(
 
     new_cache = None
     if cache is not None:
-        c_ckv, ckv = cache_write(cache["ckv"], ckv, cache_len, 1, layer)
-        c_kr, kr = cache_write(cache["krope"], k_rope.reshape(b, S, dr), cache_len, 1, layer)
+        c_ckv = cache_write(cache["ckv"], ckv, cache_len, 1, layer)
+        c_kr = cache_write(cache["krope"], k_rope.reshape(b, S, dr), cache_len, 1, layer)
         new_cache = {"ckv": c_ckv, "krope": c_kr}
+        ckv, kr = c_ckv, c_kr
+        if layer is not None:
+            ckv, kr = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                       for a in (c_ckv, c_kr))
         k_rope = kr.reshape(b, -1, 1, dr)
         kv_len = cache_len + S
         q_offset = cache_len

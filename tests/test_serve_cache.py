@@ -73,3 +73,53 @@ def test_the_cache_is_stored_in_the_flash_kernels_layout():
     want = (n_units, SLOTS, cfg.n_kv_heads, MAX_LEN, cfg.head_dim)
     for leaf in ("k", "v"):
         assert cache["units"]["b0"][leaf].shape == want
+
+
+def test_the_kernel_reads_the_stacked_cache_as_the_sliced_layer(monkeypatch):
+    """With Pallas on (interpret mode), the donated step hands the flash
+    kernel the whole stacked cache and the layer; its logits over a run of
+    steps equal, bit for bit, those of the per-layer path, in which each
+    layer is sliced out of the stack and the kernel handed that one layer."""
+    import dataclasses
+    from functools import partial
+
+    from repro import kernels
+    from repro.kernels.flash_attention import ops
+    from repro.models import decode_step, layers
+
+    cfg = dataclasses.replace(get_config("qwen3-0.6b_smoke"), n_layers=3)  # GQA group 2
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (12, SLOTS, 1), 0, cfg.vocab)
+    n_units = cfg.n_layers // len(cfg.block_pattern)
+
+    def run(donate):
+        step = jax.jit(partial(decode_step, cfg), donate_argnums=(1,) if donate else ())
+        cache = init_cache(cfg, SLOTS, MAX_LEN)
+        out = []
+        for pos, tok in enumerate(toks):
+            logits, cache = step(params, cache, tok, jnp.int32(pos))
+            out.append(np.asarray(logits))
+        return np.stack(out)
+
+    prior = kernels.pallas_enabled(), kernels.interpret_default()
+    kernels.enable_pallas(True, interpret=True)
+    try:
+        ops.reset_kernel_record()
+        stacked = run(donate=True)
+        calls = list(ops.kernel_record())
+        mha = layers.mha
+
+        def per_layer(q, k, v, *, layer=None, **kw):
+            if layer is not None:
+                k, v = k[layer], v[layer]
+            return mha(q, k, v, **kw)
+
+        monkeypatch.setattr(layers, "mha", per_layer)
+        ops.reset_kernel_record()
+        sliced = run(donate=False)
+        sliced_calls = list(ops.kernel_record())
+    finally:
+        kernels.enable_pallas(prior[0], interpret=prior[1])
+    np.testing.assert_array_equal(stacked, sliced)
+    assert [c.kv_layers for c in calls] == [n_units]
+    assert [c.kv_layers for c in sliced_calls] == [0]

@@ -141,19 +141,27 @@ def test_shape_generic_engine_core_compiles_under_x64(one_chip):
     assert "f64" in compiled.as_text()
 
 
-def test_serve_step_updates_the_full_width_cache_in_place(one_chip):
-    """qwen3-0.6b's donated serve step at the serving cell's widths (32
-    slots, max_len 1024, Pallas on): its output aliases the whole cache, it
-    needs no cache-sized temporary, and nothing but an in-place
-    dynamic-update-slice writes a whole stacked cache leaf."""
+@pytest.mark.parametrize("name,slots,n_layers", [
+    ("qwen3-0.6b", 32, None),  # serve_chat: 28 layers
+    ("codeqwen1.5-7b", 64, 8),  # serve_code: one 8-layer pipeline stage
+], ids=["qwen3-0.6b", "codeqwen1.5-7b"])
+def test_serve_step_updates_the_full_width_cache_in_place(one_chip, name, slots, n_layers):
+    """A serving cell's donated step at its widths (max_len 1024, Pallas
+    on): its output aliases the whole cache, nothing but an in-place
+    dynamic-update-slice writes a whole stacked cache leaf, and the flash
+    kernel reads K and V out of the stack itself: no instruction produces
+    one layer's K or V, and the step needs no temporary of that size."""
+    import dataclasses
     import re
 
     from repro import kernels
     from repro.launch.steps import make_serve_step
     from repro.models import init_cache, init_params
 
-    cfg = get_config("qwen3-0.6b")
-    B, max_len = 32, 1024
+    cfg = get_config(name)
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    B, max_len = slots, 1024
 
     def on_chip(tree):
         return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), tree)
@@ -172,16 +180,24 @@ def test_serve_step_updates_the_full_width_cache_in_place(one_chip):
     stats = compiled.memory_analysis()
     cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert stats.alias_size_in_bytes == cache_bytes
-    assert stats.temp_size_in_bytes < 150e6
+    assert stats.temp_size_in_bytes < 8e6  # one layer's K or V is 67 MB
+
+    def hlo_shape(dims):
+        return "bf16[" + ",".join(map(str, dims)) + "]"
 
     leaf = jax.tree.leaves(cache["units"])[0]
-    shape = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+    stack, layer = hlo_shape(leaf.shape), hlo_shape(leaf.shape[1:])
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
     writers = re.findall(
-        r"%\S+ = " + re.escape(shape) + r"\{[^}]*\} ([\w-]+)\(", text
+        r"%\S+ = " + re.escape(stack) + r"\{[^}]*\} ([\w-]+)\(", text
     )
     assert "dynamic-update-slice" in writers
     # parameters and loop-tuple elements pass the leaf through; nothing
     # else (a copy, a fusion restacking it) may produce it
     assert set(writers) <= {"parameter", "get-tuple-element", "dynamic-update-slice"}
+    assert not re.search(r"= " + re.escape(layer) + r"\{", text)
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    args = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")
+    shapes = dict(re.findall(r"(%\S+) = (bf16\[[\d,]*\])", text))
+    # [kv_len, q_offset, layer], q, then K and V: the stacks themselves
+    assert [shapes.get(a) for a in args[2:]] == [stack, stack]

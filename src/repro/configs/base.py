@@ -14,6 +14,19 @@ from typing import Callable, Dict, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling, as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type "yarn" in its config.json)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | hybrid | ssm | vlm | audio
@@ -29,6 +42,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[YarnScaling] = None
     tie_embeddings: bool = False
     encoder_only: bool = False
 
@@ -40,6 +54,11 @@ class ModelConfig:
     first_k_dense: int = 0  # leading dense layers (DeepSeek style)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True  # renormalize the top-k gates to sum to 1
+    # this chip's share under expert parallelism: it holds the routed
+    # experts first_expert .. first_expert + experts_held - 1 (0: all)
+    experts_held: int = 0
+    first_expert: int = 0
 
     # MLA (DeepSeek-V2)
     use_mla: bool = False
@@ -72,6 +91,10 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // self.n_heads)
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_routed_experts
 
     @property
     def n_units(self) -> int:
@@ -199,6 +222,8 @@ class ModelConfig:
             n_routed_experts=min(self.n_routed_experts, 8),
             n_shared_experts=min(self.n_shared_experts, 2),
             top_k=min(self.top_k, 2),
+            experts_held=0,
+            first_expert=0,
             d_expert=32 if self.d_expert else 0,
             first_k_dense=min(self.first_k_dense, 1),
             kv_lora_rank=32 if self.kv_lora_rank else 0,

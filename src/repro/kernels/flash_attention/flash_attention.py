@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import functools
 import math
-import threading
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -40,6 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime.records import TraceRecord
 
 NEG_INF = -1e30
 
@@ -58,20 +59,17 @@ class FlashCall(NamedTuple):
     kv_layers: int  # depth of the K/V operand's layer axis; 0: one layer
 
 
-_RECORD_LOCK = threading.Lock()
-_RECORD: Dict[FlashCall, Dict[str, int]] = {}
+_RECORD = TraceRecord()
 
 
 def kernel_record() -> Dict[FlashCall, Dict[str, int]]:
     """Bytes one call moves, by call shape: ``q``, ``k``, ``v`` copied in
     and ``o`` written back, for every call shape built in this process."""
-    with _RECORD_LOCK:
-        return {k: dict(v) for k, v in _RECORD.items()}
+    return _RECORD.snapshot()
 
 
 def reset_kernel_record() -> None:
-    with _RECORD_LOCK:
-        _RECORD.clear()
+    _RECORD.reset()
 
 
 def block_copies(grid: Tuple[int, ...], index_map, chunk: int = 1 << 20) -> int:
@@ -102,8 +100,7 @@ def _record(call: FlashCall, grid, specs) -> None:
         * math.prod(spec.block_shape) * jnp.dtype(dtype).itemsize
         for name, (spec, dtype) in specs.items()
     }
-    with _RECORD_LOCK:
-        _RECORD[call] = moved
+    _RECORD.put(call, moved)
 
 
 def _fa_kernel(

@@ -12,12 +12,13 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnScaling
+from repro.runtime.records import TraceRecord
 
 Params = Dict[str, jnp.ndarray]
 DTYPE = jnp.bfloat16
@@ -54,11 +55,44 @@ def act_fn(name: str):
 # --------------------------------------------------------------------- #
 # RoPE
 # --------------------------------------------------------------------- #
-def rope_cos_sin(positions: jnp.ndarray, dim: int, theta: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """positions: (..., S) int32 -> cos/sin (..., S, dim//2) f32."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, ys: YarnScaling) -> jnp.ndarray:
+    """YaRN's inverse frequencies: rope's own above the fast correction
+    dim, divided by ``factor`` below the slow one, and a linear ramp
+    between (DeepSeek-V2's ``yarn_find_correction_range`` and
+    ``yarn_linear_ramp_mask``)."""
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(ys.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(ys.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(ys.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    return extra / ys.factor * ramp + extra * (1 - ramp)
+
+
+def rope_cos_sin(positions: jnp.ndarray, dim: int, theta: float,
+                 scaling: Optional[YarnScaling] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """positions: (..., S) int32 -> cos/sin (..., S, dim//2) f32; with
+    ``scaling``, YaRN's frequencies and its cos/sin magnitude."""
+    if scaling is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+        m = 1.0
+    else:
+        inv = yarn_inv_freq(dim, theta, scaling)
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
@@ -225,7 +259,7 @@ def attention_apply(
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.rms_eps)
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
-    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta, cfg.rope_scaling)
     if not cfg.encoder_only:
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -248,6 +282,37 @@ def attention_apply(
 # --------------------------------------------------------------------- #
 # MLA attention (DeepSeek-V2): latent-compressed KV cache
 # --------------------------------------------------------------------- #
+class MlaCall(NamedTuple):
+    """The shape of one traced MLA call that reads the decode cache."""
+
+    batch: int
+    q_seq: int
+    cache_len: int
+
+
+_MLA_RECORD = TraceRecord()
+
+
+def mla_record() -> Dict[MlaCall, Dict[str, int]]:
+    """Per traced decode call: ``upproj_rows``, the latent rows the call
+    up-projects to per-head keys and values."""
+    return _MLA_RECORD.snapshot()
+
+
+def reset_mla_record() -> None:
+    _MLA_RECORD.reset()
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(q/k head dim), times YaRN's mscale^2 where the config scales
+    rope (DeepSeek-V2's ``softmax_scale``)."""
+    scale = 1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
 def init_mla(key, cfg: ModelConfig) -> Params:
     d, h = cfg.d_model, cfg.n_heads
     r, dr, dn, dv = cfg.kv_lora_rank, cfg.rope_head_dim, cfg.nope_head_dim, cfg.v_head_dim
@@ -278,7 +343,7 @@ def mla_apply(
     down = dense(p["kv_down"], x)
     ckv, k_rope = down[..., :r], down[..., r:]
     ckv = rms_norm(ckv, p["latent_norm"], cfg.rms_eps)
-    cos, sin = rope_cos_sin(positions, dr, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, dr, cfg.rope_theta, cfg.rope_scaling)
     q_rope = apply_rope(q_rope, cos, sin)
     k_rope = apply_rope(k_rope.reshape(b, S, 1, dr), cos, sin)
 
@@ -292,6 +357,7 @@ def mla_apply(
             ckv, kr = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
                        for a in (c_ckv, c_kr))
         k_rope = kr.reshape(b, -1, 1, dr)
+        _MLA_RECORD.put(MlaCall(b, S, ckv.shape[1]), {"upproj_rows": b * ckv.shape[1]})
         kv_len = cache_len + S
         q_offset = cache_len
         causal = False
@@ -306,7 +372,7 @@ def mla_apply(
     k_full = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, ckv.shape[1], h, dr))], axis=-1)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
     out = mha(q_full, k_full, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-              sm_scale=1.0 / math.sqrt(dn + dr))
+              sm_scale=mla_softmax_scale(cfg))
     y = dense(p["wo"], out.reshape(b, S, h * dv))
     return y, new_cache
 

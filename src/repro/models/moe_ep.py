@@ -35,12 +35,15 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import act_fn, dense
+from repro.models.moe import top_k_gates
 from repro.sharding import hints as hints_mod
 
 Params = Dict[str, jnp.ndarray]
 
 
 def ep_available(cfg: ModelConfig, x: jnp.ndarray) -> bool:
+    if cfg.n_experts_held != cfg.n_routed_experts:  # a chip's share: no mesh here
+        return False
     st = hints_mod._STATE
     if not (st.get("enabled") and st.get("tp") and st.get("mesh") is not None):
         return False
@@ -106,8 +109,7 @@ def moe_apply_ep(
         xt = x_blk.reshape(T, d)
         logits = (xt @ wr_).astype(jnp.float32)  # (T, e) real experts only
         probs = jax.nn.softmax(logits, axis=-1)
-        gates, eidx = jax.lax.top_k(probs, k)  # (T, k) over REAL experts
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        gates, eidx = top_k_gates(cfg, probs)  # (T, k) over REAL experts
 
         # aux loss over the GLOBAL batch (pmean across every mesh axis)
         onehot_top1 = jax.nn.one_hot(eidx[:, 0], e, dtype=jnp.float32)

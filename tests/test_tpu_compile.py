@@ -201,3 +201,41 @@ def test_serve_step_updates_the_full_width_cache_in_place(one_chip, name, slots,
     shapes = dict(re.findall(r"(%\S+) = (bf16\[[\d,]*\])", text))
     # [kv_len, q_offset, layer], q, then K and V: the stacks themselves
     assert [shapes.get(a) for a in args[2:]] == [stack, stack]
+
+
+def test_serve_step_compiles_at_deepseek_v2_lite_widths(one_chip):
+    """``deepseek-v2-lite.serve_mla_chat``'s donated step at published
+    widths, 8 of 64 experts held, 16 slots, max_len 1024, Pallas on: the
+    flash kernel takes MLA's q/k head dim of 192 and value head dim of 128
+    (the prefix layer's call and the scanned layers' call), the output
+    aliases the whole latent cache, and weights, cache and temporaries fit
+    the chip."""
+    import dataclasses
+
+    from repro import kernels
+    from repro.launch.steps import make_serve_step
+    from repro.models import init_cache, init_params
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"), experts_held=8)
+    B, max_len = 16, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, B, max_len)))
+    tok = _spec((B, 1), jnp.int32, one_chip)
+    pos = _spec((), jnp.int32, one_chip)
+    prior = kernels.pallas_enabled(), kernels.interpret_default()
+    kernels.enable_pallas(True, interpret=False)
+    try:
+        step = jax.jit(make_serve_step(cfg), donate_argnums=(1,))
+        compiled = step.lower(params, cache, tok, pos).compile()
+    finally:
+        kernels.enable_pallas(prior[0], interpret=prior[1])
+    stats = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert cache_bytes == B * max_len * 27 * (512 + 64) * 2
+    assert stats.alias_size_in_bytes == cache_bytes
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 16 * 2**30
+    assert compiled.as_text().count("tpu_custom_call") == 2
